@@ -189,7 +189,7 @@ void Runner::load(const std::string& model_dir, const std::string& plugin) {
   check(api->PJRT_Plugin_Initialize(&pi), "plugin init");
 
   // plugin-specific client options: standard libtpu/CPU plugins need
-  // none; bespoke plugins (e.g. proxy/tunnel backends) read NamedValues.
+  // none; a plugin that takes create options reads NamedValues.
   // Sourced from $PTPU_PJRT_CREATE_OPTIONS (JSON object of str|int),
   // mirroring how jax passes plugin options at register time.
   std::vector<PJRT_NamedValue> nvs;

@@ -13,10 +13,33 @@ Structure:
   paddle_tpu.utils     profiler, flags, misc runtime utilities
 """
 
-from . import fluid  # noqa: F401
-from . import parallel  # noqa: F401
-from . import resilience  # noqa: F401
-from . import utils  # noqa: F401
-from . import native  # noqa: F401
+import os as _os
+
+
+def _place_compile_cache() -> None:
+    """The program's ONE compile cache is JAX's persistent compilation
+    cache, placed here because every entry point (and every child:
+    supervised gateways, fleet replicas, launch workers) imports this
+    package before it compiles anything.  Where the environment names a
+    directory (``JAX_COMPILATION_CACHE_DIR``) JAX reads it itself and
+    nothing is set in code; otherwise the cache lives at a FIXED path
+    next to the package — the path is part of the cache key's lookup, so
+    a directory built from a tempdir, a pid or the time never hits."""
+    if _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    checkout = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
+    jax.config.update("jax_compilation_cache_dir",
+                      _os.path.join(checkout, ".jax_cache"))
+
+
+_place_compile_cache()
+
+from . import fluid  # noqa: F401,E402
+from . import parallel  # noqa: F401,E402
+from . import resilience  # noqa: F401,E402
+from . import utils  # noqa: F401,E402
+from . import native  # noqa: F401,E402
 
 __version__ = "0.1.0"

@@ -110,8 +110,11 @@ _DEVICE_KIND_TO_SPEC = (
 
 def get_chip(spec=None) -> ChipSpec:
     """Resolve a chip spec: an explicit ChipSpec/name wins, then the
-    ``PADDLE_TPU_CHIP`` env flag, then the attached device kind, then
-    v5e (the committed-bench generation)."""
+    ``PADDLE_TPU_CHIP`` env flag, then the attached TPU's device kind.
+    A host with no TPU plans for v5e (the committed-bench generation:
+    static analysis is a model of a target chip, and runs on CPU hosts);
+    an ATTACHED TPU that is not in the table is an error — its numbers
+    would be another chip's."""
     if isinstance(spec, ChipSpec):
         return spec
     name = spec or os.environ.get("PADDLE_TPU_CHIP")
@@ -121,16 +124,18 @@ def get_chip(spec=None) -> ChipSpec:
         except KeyError:
             raise ValueError(f"unknown chip spec {name!r}; one of "
                              f"{sorted(CHIP_SPECS)}") from None
-    try:
-        import jax
+    import jax
 
-        kind = jax.devices()[0].device_kind
-        for prefix, key in _DEVICE_KIND_TO_SPEC:
-            if kind.startswith(prefix):
-                return CHIP_SPECS[key]
-    except Exception:
-        pass
-    return CHIP_SPECS["v5e"]
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return CHIP_SPECS["v5e"]
+    for prefix, key in _DEVICE_KIND_TO_SPEC:
+        if dev.device_kind.startswith(prefix):
+            return CHIP_SPECS[key]
+    raise ValueError(
+        f"attached TPU {dev.device_kind!r} has no entry in CHIP_SPECS; "
+        f"add one, or name the chip to plan for (spec= / PADDLE_TPU_CHIP, "
+        f"one of {sorted(CHIP_SPECS)})")
 
 
 # ---------------------------------------------------------------------------

@@ -108,13 +108,18 @@ def serialize_compiled(compiled) -> Optional[bytes]:
         return None
 
 
-def deserialize_compiled(blob: bytes):
+def deserialize_compiled(blob: bytes, devices):
     """Load a ``serialize_compiled`` blob back into a callable
-    ``jax.stages.Compiled`` bound to the current backend."""
+    ``jax.stages.Compiled`` bound to ``devices`` — the devices the entry
+    was compiled for (one device, or the mesh's).  jax defaults
+    ``execution_devices`` to EVERY device of the backend, so an
+    executable compiled for one device fails its first call on a
+    multi-device host ("Expected args ... to have N shards, got: [1]")."""
     from jax.experimental import serialize_executable as _se
 
     payload, in_tree, out_tree = pickle.loads(blob)
-    return _se.deserialize_and_load(payload, in_tree, out_tree)
+    return _se.deserialize_and_load(payload, in_tree, out_tree,
+                                    execution_devices=list(devices))
 
 
 def _canon(obj):
@@ -182,10 +187,11 @@ class CompileCache:
                       if n.endswith(_SUFFIX))
 
     # -- load ----------------------------------------------------------------
-    def load(self, key: str):
-        """Deserialize entry ``key`` into a live executable, or None on
-        miss / integrity failure (the corrupt entry is deleted so the
-        following store overwrites it cleanly)."""
+    def load(self, key: str, devices):
+        """Deserialize entry ``key`` into a live executable on
+        ``devices`` (those it was compiled for), or None on miss /
+        integrity failure (the corrupt entry is deleted so the following
+        store overwrites it cleanly)."""
         path = self._path(key)
         t0 = time.perf_counter()
         try:
@@ -211,7 +217,7 @@ class CompileCache:
                 pass
             return None
         try:
-            compiled = deserialize_compiled(blob)
+            compiled = deserialize_compiled(blob, devices)
         except Exception:
             # a salt collision can't produce this (the salt is in the
             # key), but a PJRT refusing its own bytes can — degrade

@@ -471,7 +471,7 @@ class Executor:
         return _cc.default_cache()
 
     def _aot_compile(self, mem_key, step, example_args,
-                     in_shardings=None):
+                     in_shardings=None, mesh=None):
         """Resolve one executable for ``step`` at ``example_args``'
         signature, consulting the persistent AOT tier between the
         in-memory cache (already missed) and XLA:
@@ -507,7 +507,12 @@ class Executor:
         akey = aot.entry_key((mem_key, ("donate", False)))
         read0 = aot._stats["bytes_read"]
         t0 = time.perf_counter()
-        loaded = aot.load(akey)
+        # the devices the entry was compiled for: the mesh's under SPMD
+        # (mem_key carries the mesh, so the entry cannot be another
+        # mesh's), else the one default device an unsharded jit targets
+        devices = (jax.devices()[:1] if mesh is None
+                   else list(mesh.devices.flat))
+        loaded = aot.load(akey, devices)
         if loaded is not None:
             pstats["hits"] += 1
             pstats["bytes"] += aot._stats["bytes_read"] - read0
@@ -854,6 +859,51 @@ class Executor:
                              + out["temp_bytes"] - out["alias_bytes"])
         return out
 
+    def compiled_hlo(self, program: Optional[Program] = None,
+                     feed: Optional[Dict[str, Any]] = None,
+                     fetch_list: Optional[Sequence] = None,
+                     scope: Optional[Scope] = None,
+                     mode: str = "train") -> str:
+        """Optimized HLO text of the step ``run()`` dispatches for this
+        call — lowered with run()'s exact input shardings under the
+        active mesh (feeds batch-sharded, persistables per their desc
+        annotations) and compiled, WITHOUT executing.  What the device
+        was really given: which custom calls (a Mosaic kernel is a
+        ``tpu_custom_call``), which collectives, which aliases."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from ..parallel import mesh as _pmesh
+
+        mesh = _pmesh.current_mesh()
+        program = program or default_main_program()
+        feed, state_vals, step = self._prepare_step(program, feed,
+                                                    fetch_list, scope, mode)
+        kwargs = {}
+        if mesh is not None:
+            block = program.desc.global_block()
+            feed_sh = {n: _pmesh.feed_sharding(mesh, v)
+                       for n, v in feed.items()}
+            state_sh = {
+                n: _pmesh.state_sharding(
+                    mesh, v,
+                    block.vars[n].sharding if n in block.vars else None)
+                for n, v in state_vals.items()}
+            kwargs["in_shardings"] = (
+                feed_sh, state_sh, NamedSharding(mesh, PartitionSpec()))
+            # run()'s re-layout rule: state whose current placement
+            # disagrees with its annotation (e.g. loaded replicated)
+            # moves first, or lowering rejects the arg/sharding mismatch
+            for n, target in state_sh.items():
+                v = state_vals[n]
+                cur = getattr(v, "sharding", None)
+                if cur is not None and not isinstance(v, SeqArray) \
+                        and cur != target:
+                    state_vals[n] = jax.device_put(v, target)
+        # fixed rng bits: analysis must not advance the scope's rng counter
+        lowered = jax.jit(step, donate_argnums=(1,), **kwargs).lower(
+            feed, state_vals, np.zeros(2, np.int32))
+        return lowered.compile().as_text()
+
     # HLO element-type byte widths for collective payload accounting
     _HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
                   "f8e5m2": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -875,57 +925,46 @@ class Executor:
         ``total_payload_bytes`` (sum of per-shard operand bytes) and the
         mesh shape; {} without an active mesh (no partitioner, no
         collectives).  Lowering only — nothing executes."""
-        import re
-
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec
-
         from ..parallel import mesh as _pmesh
 
         mesh = _pmesh.current_mesh()
         if mesh is None:
             return {}
-        program = program or default_main_program()
-        block = program.desc.global_block()
-        feed, state_vals, step = self._prepare_step(program, feed,
-                                                    fetch_list, scope, mode)
-        feed_sh = {n: _pmesh.feed_sharding(mesh, v)
-                   for n, v in feed.items()}
-        state_sh = {
-            n: _pmesh.state_sharding(
-                mesh, v,
-                block.vars[n].sharding if n in block.vars else None)
-            for n, v in state_vals.items()}
-        in_sh = (feed_sh, state_sh, NamedSharding(mesh, PartitionSpec()))
-        # run()'s re-layout rule: state whose current placement disagrees
-        # with its annotation (e.g. loaded replicated) moves first, or
-        # lowering rejects the arg/sharding mismatch
-        for n, target in state_sh.items():
-            v = state_vals[n]
-            cur = getattr(v, "sharding", None)
-            if cur is not None and not isinstance(v, SeqArray) \
-                    and cur != target:
-                state_vals[n] = jax.device_put(v, target)
-        lowered = jax.jit(step, donate_argnums=(1,),
-                          in_shardings=in_sh).lower(
-            feed, state_vals, np.zeros(2, np.int32))
-        hlo = lowered.compile().as_text()
+        per_kind, total = self.collectives_in_hlo(
+            self.compiled_hlo(program, feed, fetch_list, scope, mode))
+        return {
+            "per_kind": per_kind,
+            "total_payload_bytes": total,
+            "mesh_axes": {str(a): int(s) for a, s in mesh.shape.items()},
+        }
+
+    @classmethod
+    def collectives_in_hlo(cls, hlo: str):
+        """({kind: {count, payload_bytes}}, total bytes) of the
+        collective instructions in optimized HLO text, payload = bytes
+        of each instruction's result shape(s)."""
+        import re
+
         kinds = ("all-reduce", "all-gather", "reduce-scatter",
                  "all-to-all", "collective-permute")
+        # result shape(s), then the op.  The shape text is "anything up
+        # to the op name": TPU layouts carry tiling and memory-space
+        # annotations (f32[8,128]{1,0:T(8,128)S(1)}), and XLA numbers
+        # long tuples with /*index=N*/ comments — exactly the combined
+        # gradient all-reduce of a dp step (stripped below)
         head = re.compile(
-            r"=\s+(\(?[a-z0-9\[\],{}\s/]*\)?)\s+(" + "|".join(kinds)
-            + r")(?:-start)?\(")
+            r"=\s+(\S.*?)\s+(" + "|".join(kinds) + r")(?:-start)?\(")
         shape = re.compile(r"([a-z]\d*[a-z0-9]*)\[([0-9,]*)\]")
         per_kind: Dict[str, Dict[str, float]] = {}
         total = 0.0
         for line in hlo.splitlines():
-            m = head.search(line)
+            m = head.search(re.sub(r"/\*.*?\*/", "", line))
             if not m:
                 continue
             result, kind = m.group(1), m.group(2)
             payload = 0.0
             for dt, dims in shape.findall(result):
-                width = self._HLO_BYTES.get(dt)
+                width = cls._HLO_BYTES.get(dt)
                 if width is None:
                     continue
                 n = 1
@@ -938,11 +977,7 @@ class Executor:
             d["count"] += 1
             d["payload_bytes"] += payload
             total += payload
-        return {
-            "per_kind": per_kind,
-            "total_payload_bytes": total,
-            "mesh_axes": {str(a): int(s) for a, s in mesh.shape.items()},
-        }
+        return per_kind, total
 
     def device_time_per_step(self, program: Optional[Program] = None,
                              feed: Optional[Dict[str, Any]] = None,
@@ -953,8 +988,8 @@ class Executor:
         """Seconds per step with ``iters`` steps CHAINED inside one jit
         (a lax.fori_loop carrying the state dict) — pure DEVICE time.
         Per-call ``run`` timing includes one host dispatch per step,
-        which on a remote/tunneled device can dwarf the chip (the analog
-        of wall-clocking each Session call instead of profiling the
+        which for a small step can dwarf the chip (the analog of
+        wall-clocking each Session call instead of profiling the
         kernels).  The chained number is the profiler-grade ms/batch.
         The scope is NOT updated (the chained states are discarded)."""
         feed, state_vals, step = self._prepare_step(program, feed,
@@ -1105,7 +1140,7 @@ class Executor:
             compiled = self._aot_compile(
                 key, step,
                 (feed, state_vals, np.zeros(2, np.int32)),
-                in_shardings=in_sh)
+                in_shardings=in_sh, mesh=mesh)
             self._store_executable(key, (compiled, state_sh
                                          if mesh is not None else None,
                                          feed_sh))
@@ -1317,9 +1352,9 @@ class Executor:
         The real version of ``device_time_per_step``'s chained-steps
         trick: the per-step function is wrapped in a ``lax.scan`` over
         the stacked feed batches (carrying the state dict), so k
-        optimizer steps cost one host dispatch instead of k — on a
-        tunneled/remote device that's the difference between paying the
-        RTT per step and per k steps.  Unlike the timing helper this is
+        optimizer steps cost one host dispatch instead of k — for
+        small steps the difference between paying the dispatch latency
+        per step and per k steps.  Unlike the timing helper this is
         a first-class execution mode: the scope's rng advances exactly
         as k ``run()`` calls would, the final state is written back, and
         every step's fetches are returned (list over steps of fetch

@@ -168,16 +168,26 @@ def ragged_decode_attention(ctx, q, pool, page_table, lengths, q_base,
     kernels/flash_attention.ragged_decode_attention (q [B, C, H, D],
     pool [H, R, page_size, D], page_table [B, P] int32 logical pages,
     lengths [B], optional q_base [B] for causal chunk queries, optional
-    Scales [1, R, page_size] fp32 block scales for an int8 pool)."""
-    from ...kernels.flash_attention import ragged_decode_attention as _ra
+    Scales [1, R, page_size] fp32 block scales for an int8 pool).  Under
+    an active mesh (tensor-parallel serving) the kernel maps over the
+    mesh's batch and head axes."""
+    from ...kernels.flash_attention import (
+        ragged_decode_attention as _ra,
+        ragged_decode_attention_sharded as _ra_sharded)
+    from ...parallel import mesh as _pmesh
 
-    return _ra(q, pool, page_table, lengths, q_base,
-               layer=int(ctx.attr("layer", 0)),
-               n_layer=int(ctx.attr("n_layer", 1)),
-               causal=bool(ctx.attr("causal", True)),
-               sm_scale=ctx.attr("sm_scale", None),
-               impl=ctx.attr("impl", None),
-               scales=scales)
+    kw = dict(layer=int(ctx.attr("layer", 0)),
+              n_layer=int(ctx.attr("n_layer", 1)),
+              causal=bool(ctx.attr("causal", True)),
+              sm_scale=ctx.attr("sm_scale", None),
+              impl=ctx.attr("impl", None), scales=scales)
+    mesh = _pmesh.current_mesh()
+    if mesh is not None:
+        b_ax, h_ax = _pmesh.kernel_axes(mesh, batch=q.shape[0],
+                                        heads=q.shape[2])
+        return _ra_sharded(mesh, q, pool, page_table, lengths, q_base,
+                           batch_axis=b_ax, head_axis=h_ax, **kw)
+    return _ra(q, pool, page_table, lengths, q_base, **kw)
 
 
 def _page_copy_rows(src, dst, n_layer):

@@ -320,9 +320,12 @@ def fused_attention(ctx, q, k, v, bias):
     sequence axis, routes to a sequence-parallel strategy chosen by the
     sp_impl attr: ring attention over the ICI (kernels/ring_attention.py,
     default) or Ulysses all-to-all (kernels/ulysses_attention.py) —
-    sequence parallelism the 2018 reference had no analog for.
+    sequence parallelism the 2018 reference had no analog for.  Under any
+    other mesh (data / tensor parallel) the kernel maps over the mesh's
+    batch and head axes (flash_attention_sharded).
     """
     from ...kernels import flash_attention as _flash
+    from ...kernels import flash_attention_sharded as _flash_sharded
     from ...kernels import ring_attention_sharded as _ring
     from ...kernels import ulysses_attention_sharded as _ulysses
 
@@ -361,6 +364,15 @@ def fused_attention(ctx, q, k, v, bias):
         if layout == "blhd":
             out = jnp.transpose(out, (0, 2, 1, 3))
         return out
+    if mesh is not None:
+        b_ax, h_ax = _pmesh.kernel_axes(
+            mesh, batch=q.shape[0],
+            heads=q.shape[2 if layout == "blhd" else 1])
+        return _flash_sharded(mesh, q, k, v, bias, batch_axis=b_ax,
+                              head_axis=h_ax, causal=causal,
+                              sm_scale=sm_scale, impl=impl,
+                              dropout_rate=rate, dropout_seed=seed,
+                              layout=layout)
     return _flash(q, k, v, bias=bias, causal=causal, sm_scale=sm_scale,
                   impl=impl, dropout_rate=rate, dropout_seed=seed,
                   layout=layout)

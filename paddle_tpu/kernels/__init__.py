@@ -12,10 +12,11 @@ variable-length-efficiency machinery (LoD batching,
 RecurrentGradientMachine).  These are the Pallas kernels.
 """
 
-from .flash_attention import flash_attention
+from .flash_attention import flash_attention, flash_attention_sharded
 from .ring_attention import ring_attention, ring_attention_sharded
 from .ulysses_attention import (ulysses_attention,
                                 ulysses_attention_sharded)
 
-__all__ = ["flash_attention", "ring_attention", "ring_attention_sharded",
-           "ulysses_attention", "ulysses_attention_sharded"]
+__all__ = ["flash_attention", "flash_attention_sharded", "ring_attention",
+           "ring_attention_sharded", "ulysses_attention",
+           "ulysses_attention_sharded"]

@@ -36,17 +36,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
-# jax renamed TPUCompilerParams -> CompilerParams across versions; accept
-# either so interpret-mode tests run on every toolchain in the fleet
-_COMPILER_PARAMS_CLS = None if pltpu is None else (
-    getattr(pltpu, "CompilerParams", None)
-    or getattr(pltpu, "TPUCompilerParams", None))
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 LANES = 128   # stat tiles are [block, LANES] so no sublane transposes occur
@@ -59,8 +50,15 @@ LANES = 128   # stat tiles are [block, LANES] so no sublane transposes occur
 # Tests monkeypatch this to 0 to exercise the kernels at tiny shapes.
 PALLAS_BWD_MIN_L = 1024
 
-__all__ = ["flash_attention", "decode_attention", "ragged_decode_attention",
-           "paged_kv_rows"]
+__all__ = ["flash_attention", "flash_attention_sharded", "decode_attention",
+           "ragged_decode_attention", "ragged_decode_attention_sharded",
+           "paged_kv_rows", "default_impl"]
+
+
+def default_impl() -> str:
+    """The kernels' ``impl=None`` choice: the Pallas kernels where Mosaic
+    can compile them (a TPU backend), the blockwise-XLA path elsewhere."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def decode_attention(q, k_cache, v_cache, lengths,
@@ -185,9 +183,12 @@ def _ragged_kernel(krows_ref, vrows_ref, meta_ref, q_ref, k_ref, v_ref,
     block table drives the k/v index maps) with an online softmax.
     q rides head-major [B, h*C, d]; scratch rows j*C..(j+1)*C hold head
     j's running stats.  ks_ref/vs_ref (present for an int8 pool) carry
-    this page-row's [1, ps] fp32 block scales; dequant happens here in
-    VMEM — the page DMA moved int8 bytes, halving-again the decode read
-    stream vs bf16."""
+    this page-row's [1, ps] fp32 block scales.  They apply to the score /
+    probability COLUMNS — (q·k_i8ᵀ)·s == q·(k_i8·s)ᵀ and
+    (p·s)·v_i8 == p·(v_i8·s) — where ps already sits on the lane axis,
+    so dequant is a [C, ps] multiply and the scale row never needs a
+    lane->sublane relayout.  The page DMA moved int8 bytes,
+    halving-again the decode read stream vs bf16."""
     b = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -205,18 +206,17 @@ def _ragged_kernel(krows_ref, vrows_ref, meta_ref, q_ref, k_ref, v_ref,
         q = q_ref[0]                       # [h*C, d]
         k = k_ref[:, 0]                    # [h, ps, d]
         v = v_ref[:, 0]
-        if ks_ref is not None:             # in-register dequant (int8 pool)
-            k = k.astype(jnp.float32) * ks_ref[0][None, :, None]
-            v = v.astype(jnp.float32) * vs_ref[0][None, :, None]
-        elif k.dtype != q.dtype:           # bf16 pool: VMEM-level upcast
-            k = k.astype(q.dtype)          # (the DMA moved bf16 bytes;
-            v = v.astype(q.dtype)          # lax.dot_general won't promote)
+        if k.dtype != q.dtype:             # bf16/int8 pool: VMEM-level
+            k = k.astype(q.dtype)          # upcast (the DMA moved narrow
+            v = v.astype(q.dtype)          # bytes; dot_general won't promote)
         p0 = p * ps
         for j in range(h):                 # static head loop
             qj = q[j * c:(j + 1) * c]      # [C, d]
             s = jax.lax.dot_general(qj, k[j], (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = s * sm_scale
+            if ks_ref is not None:
+                s = s * ks_ref[0]          # [1, ps] over the key columns
             s = _ragged_mask(s, length, base, p0, ps, causal, c)
             m_prev = m_scr[j * c:(j + 1) * c]              # [C, LANES]
             l_prev = l_scr[j * c:(j + 1) * c]
@@ -229,6 +229,8 @@ def _ragged_kernel(krows_ref, vrows_ref, meta_ref, q_ref, k_ref, v_ref,
                 jnp.sum(pr, axis=1)[:, None], l_prev.shape)
             m_scr[j * c:(j + 1) * c] = m_new
             l_scr[j * c:(j + 1) * c] = l_new
+            if vs_ref is not None:
+                pr = pr * vs_ref[0]
             pv = jax.lax.dot_general(pr.astype(v.dtype), v[j],
                                      (((1,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
@@ -272,15 +274,19 @@ def _ragged_pallas(q, pool, page_table, lengths, q_base, layer, n_layer,
     ]
     args = [qk, pool, pool]
     if have_scales:
-        # [R, ps] fp32 block scales; each grid step DMAs the one [1, ps]
-        # scale row matching the k/v page row it just fetched
-        sc = scales.reshape(scales.shape[-2], scales.shape[-1])
-        in_specs.append(pl.BlockSpec((1, ps),
+        # block scales viewed [R, 1, ps]: each grid step DMAs the one
+        # [1, ps] scale row matching the k/v page row it just fetched.
+        # The unit middle dim makes the block's trailing dims the array's
+        # FULL (1, ps); a (1, ps) block of the [R, ps] view has a
+        # second-minor extent of 1 — neither R nor a multiple of 8 —
+        # which the TPU lowering refuses.
+        sc = scales.reshape(scales.shape[-2], 1, scales.shape[-1])
+        in_specs.append(pl.BlockSpec((1, 1, ps),
                                      lambda bi, pi, kr, vr, mt:
-                                     (kr[bi, pi], 0)))
-        in_specs.append(pl.BlockSpec((1, ps),
+                                     (kr[bi, pi], 0, 0)))
+        in_specs.append(pl.BlockSpec((1, 1, ps),
                                      lambda bi, pi, kr, vr, mt:
-                                     (vr[bi, pi], 0)))
+                                     (vr[bi, pi], 0, 0)))
         args += [sc, sc]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -309,11 +315,20 @@ def _ragged_pallas(q, pool, page_table, lengths, q_base, layer, n_layer,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h * c, d), q.dtype),
-        compiler_params=_COMPILER_PARAMS_CLS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(k_rows, v_rows, meta, *args)
     return jnp.transpose(out.reshape(b, h, c, d), (0, 2, 1, 3))
+
+
+def _resolve_q_base(q, q_base, causal: bool):
+    if q_base is not None:
+        return q_base
+    if causal:
+        raise ValueError("ragged_decode_attention: causal masking needs "
+                         "q_base (global position of the first query)")
+    return jnp.zeros(q.shape[0], jnp.int32)
 
 
 def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
@@ -341,14 +356,9 @@ def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
     lane never touched are never read on the Pallas path."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    if causal and q_base is None:
-        raise ValueError("ragged_decode_attention: causal masking needs "
-                         "q_base (global position of the first query)")
-    if q_base is None:
-        q_base = jnp.zeros(q.shape[0], jnp.int32)
+    q_base = _resolve_q_base(q, q_base, causal)
     if impl is None:
-        impl = "pallas" if (pltpu is not None and
-                            jax.default_backend() == "tpu") else "xla"
+        impl = default_impl()
     if impl in ("pallas", "pallas_interpret"):
         return _ragged_pallas(q, pool, page_table, lengths, q_base, layer,
                               n_layer, causal, float(sm_scale),
@@ -356,6 +366,48 @@ def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
                               scales=scales)
     return _ragged_xla(q, pool, page_table, lengths, q_base, layer, n_layer,
                        causal, float(sm_scale), scales=scales)
+
+
+def ragged_decode_attention_sharded(mesh: Mesh, q, pool, page_table,
+                                    lengths, q_base=None, *,
+                                    batch_axis: Optional[str],
+                                    head_axis: Optional[str], layer: int,
+                                    n_layer: int, causal: bool = True,
+                                    sm_scale: Optional[float] = None,
+                                    impl: Optional[str] = None,
+                                    scales=None) -> jax.Array:
+    """``ragged_decode_attention`` inside a jit that spans ``mesh``.
+
+    XLA partitions the gather path by itself, but a Mosaic kernel
+    "cannot be automatically partitioned", so the Pallas impls map the
+    call over the mesh: lanes (q, tables, lengths) split on
+    ``batch_axis``, heads (q's and the pool's head dim) on ``head_axis``;
+    either may be None (that dim stays whole on every device).  The
+    int8 scale sidecar is one scale per (row, slot) for ALL heads, so it
+    rides replicated — each shard pages its own head slice of the pool
+    against the same block tables."""
+    impl = impl or default_impl()
+    kw = dict(layer=layer, n_layer=n_layer, causal=causal,
+              sm_scale=sm_scale, impl=impl)
+    if impl == "xla":
+        return ragged_decode_attention(q, pool, page_table, lengths, q_base,
+                                       scales=scales, **kw)
+    q_spec = P(batch_axis, None, head_axis, None)
+    args = [q, pool, page_table, lengths,
+            _resolve_q_base(q, q_base, causal)]
+    specs = [q_spec, P(head_axis, None, None, None), P(batch_axis, None),
+             P(batch_axis), P(batch_axis)]
+    if scales is not None:
+        args.append(scales)
+        specs.append(P(None, None, None))
+
+    def local(q_, pool_, table_, lengths_, base_, *scales_):
+        return ragged_decode_attention(
+            q_, pool_, table_, lengths_, base_,
+            scales=scales_[0] if scales_ else None, **kw)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=q_spec, check_vma=False)(*args)
 
 
 def keep_scale(seed_u32, bh, rows, cols, rate):
@@ -395,6 +447,34 @@ def seed_to_carrier(bits) -> jax.Array:
 
 def _carrier_to_u32(seed_f: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(seed_f, jnp.uint32)
+
+
+def dropout_carrier(dropout_rate: float, dropout_seed) -> jax.Array:
+    """The f32 seed operand of an attention call: the packed seed when
+    dropout is on (a seed is then required), a zero placeholder when it
+    is off."""
+    if dropout_rate > 0.0:
+        if dropout_seed is None:
+            raise ValueError("dropout_rate > 0 requires dropout_seed")
+        return seed_to_carrier(dropout_seed)
+    return jnp.zeros((), jnp.float32)
+
+
+def shard_dropout_seed(seed_f: jax.Array, batch_axis: Optional[str],
+                       head_axis: Optional[str]) -> jax.Array:
+    """uint32 dropout seed for THIS shard of a shard_map'd attention
+    call: the in-kernel hash keys on the shard-LOCAL (batch*head) index,
+    so shards that split the batch or the heads would otherwise draw the
+    same masks — fold their mesh coordinates into the seed.  (Sequence
+    shards need nothing: the hash keys on global positions.)"""
+    s = _carrier_to_u32(seed_f)
+    if batch_axis:
+        s = s ^ (jax.lax.axis_index(batch_axis).astype(jnp.uint32)
+                 * jnp.uint32(0x27D4EB2F))
+    if head_axis:
+        s = s ^ (jax.lax.axis_index(head_axis).astype(jnp.uint32)
+                 * jnp.uint32(0x165667B1))
+    return s
 
 
 def offsets_carrier(row_off, col_off) -> jax.Array:
@@ -502,7 +582,7 @@ def _compiler_params():
     scoped-VMEM ceiling: v5e has far more physical VMEM than the default
     16 MiB scope, and 1024-blocks (the measured fwd+bwd winner at L >= 1k)
     need ~17-23 MiB once dropout's keep-mask tile joins s/p/dp."""
-    return _COMPILER_PARAMS_CLS(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=64 * 1024 * 1024)
 
@@ -1269,17 +1349,11 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     if block_k is None:
         block_k = _default_block(lk)
     if impl is None:
-        impl = "pallas" if (pltpu is not None and
-                            jax.default_backend() == "tpu") else "xla"
+        impl = default_impl()
     if bias is not None and bias.ndim != 4:
         raise ValueError(f"bias must be 4-d, got {bias.shape}")
     dropout_rate = float(dropout_rate)
-    if dropout_rate > 0.0:
-        if dropout_seed is None:
-            raise ValueError("dropout_rate > 0 requires dropout_seed")
-        seed = seed_to_carrier(dropout_seed)
-    else:
-        seed = jnp.zeros((), jnp.float32)
+    seed = dropout_carrier(dropout_rate, dropout_seed)
     use_offsets = block_offsets is not None
     if use_offsets:
         offsets = offsets_carrier(*block_offsets)
@@ -1314,3 +1388,51 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     return _flash(q, k, v, bias, seed, offsets, float(sm_scale),
                   bool(causal), int(block_q), int(block_k), impl,
                   dropout_rate, kv_len, layout, use_offsets)
+
+
+def flash_attention_sharded(mesh: Mesh, q, k, v,
+                            bias: Optional[jax.Array] = None, *,
+                            batch_axis: Optional[str],
+                            head_axis: Optional[str],
+                            causal: bool = False,
+                            sm_scale: Optional[float] = None,
+                            impl: Optional[str] = None,
+                            dropout_rate: float = 0.0, dropout_seed=None,
+                            layout: str = "bhld") -> jax.Array:
+    """``flash_attention`` inside a jit that spans ``mesh`` (data- or
+    tensor-parallel training: no sequence axis — that is
+    ring/ulysses_attention_sharded's job).
+
+    The XLA impl is left to the SPMD partitioner.  The Pallas impls are
+    Mosaic custom calls, which "cannot be automatically partitioned":
+    they map over the mesh with the batch dim split on ``batch_axis``
+    and the head dim on ``head_axis`` (either may be None — that dim
+    stays whole on every device); attention needs nothing from another
+    batch row or head, so no collective appears.  Dropout masks are
+    decorrelated across shards (``shard_dropout_seed``)."""
+    impl = impl or default_impl()
+    dropout_rate = float(dropout_rate)
+    kw = dict(causal=causal, sm_scale=sm_scale, impl=impl,
+              dropout_rate=dropout_rate, layout=layout)
+    if impl == "xla":
+        return flash_attention(q, k, v, bias=bias,
+                               dropout_seed=dropout_seed, **kw)
+    seed = dropout_carrier(dropout_rate, dropout_seed)
+    qkv_spec = (P(batch_axis, head_axis, None, None) if layout == "bhld"
+                else P(batch_axis, None, head_axis, None))
+    args = [q, k, v, seed]
+    specs = [qkv_spec] * 3 + [P()]
+    if bias is not None:
+        args.append(bias)
+        specs.append(P(batch_axis if bias.shape[0] > 1 else None,
+                       head_axis if bias.shape[1] > 1 else None,
+                       None, None))
+
+    def local(q_, k_, v_, seed_, *bias_):
+        return flash_attention(
+            q_, k_, v_, bias=bias_[0] if bias_ else None,
+            dropout_seed=(shard_dropout_seed(seed_, batch_axis, head_axis)
+                          if dropout_rate > 0.0 else None), **kw)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=qkv_spec, check_vma=False)(*args)

@@ -36,8 +36,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 from .flash_attention import (DEFAULT_MASK_VALUE, LANES, _default_block,
                               _pallas_backward, _pallas_forward,
                               _xla_backward, _xla_forward, bh_grid,
-                              keep_scale, offsets_carrier, pltpu,
-                              seed_to_carrier)
+                              default_impl, dropout_carrier, keep_scale,
+                              offsets_carrier, seed_to_carrier,
+                              shard_dropout_seed)
 
 __all__ = ["ring_attention", "ring_attention_sharded"]
 
@@ -307,8 +308,7 @@ def ring_attention(q, k, v, bias: Optional[jax.Array] = None,
         seed_u = jax.lax.bitcast_convert_type(
             seed_to_carrier(dropout_seed), jnp.uint32)
     if impl is None:
-        impl = "pallas" if (pltpu is not None and
-                            jax.default_backend() == "tpu") else "xla"
+        impl = default_impl()
 
     if bias is not None:
         return _ring_xla_bias(q, k, v, bias, causal, float(sm_scale),
@@ -334,12 +334,7 @@ def sp_sharded_call(inner_fn, mesh: Mesh, q, k, v, bias, causal,
         raise ValueError(f"mesh {names} has no sequence axis {sp_axis!r}")
     qkv_spec = P(dp, mp, sp_axis, None)
     dropout_rate = float(dropout_rate)
-    if dropout_rate > 0.0:
-        if dropout_seed is None:
-            raise ValueError("dropout_rate > 0 requires dropout_seed")
-        seed = seed_to_carrier(dropout_seed)
-    else:
-        seed = jnp.zeros((), jnp.float32)
+    seed = dropout_carrier(dropout_rate, dropout_seed)
 
     fn = functools.partial(inner_fn, causal=causal, sm_scale=sm_scale,
                            axis_name=sp_axis, dropout_rate=dropout_rate,
@@ -348,14 +343,7 @@ def sp_sharded_call(inner_fn, mesh: Mesh, q, k, v, bias, causal,
     def local_seed(s_):
         if dropout_rate == 0.0:
             return None
-        s = jax.lax.bitcast_convert_type(s_, jnp.uint32)
-        if dp:
-            s = s ^ (jax.lax.axis_index(dp).astype(jnp.uint32)
-                     * jnp.uint32(0x27D4EB2F))
-        if mp:
-            s = s ^ (jax.lax.axis_index(mp).astype(jnp.uint32)
-                     * jnp.uint32(0x165667B1))
-        return s
+        return shard_dropout_seed(s_, dp, mp)
 
     if bias is None:
         mapped = jax.shard_map(

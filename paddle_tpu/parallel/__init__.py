@@ -21,7 +21,7 @@ This package replaces ALL FOUR of the reference's distribution backends
 """
 
 from .mesh import (Mesh, current_mesh, make_mesh, mesh_guard, set_mesh,
-                   feed_sharding, state_sharding)
+                   feed_sharding, kernel_axes, state_sharding)
 from .distributed import init_distributed
 from .moe import switch_moe_call
 from .pipeline import gpipe_call
@@ -32,7 +32,8 @@ from .coordinator import (CoordinatorServer, MembershipView, PodClient,
                           PodCoordinator, StaleGeneration, agree_verdicts)
 
 __all__ = ["Mesh", "make_mesh", "mesh_guard", "set_mesh", "current_mesh",
-           "feed_sharding", "state_sharding", "init_distributed",
+           "feed_sharding", "kernel_axes", "state_sharding",
+           "init_distributed",
            "DistributeTranspiler", "Task", "TaskQueue", "master_reader",
            "MasterClient", "MasterServer", "gpipe_call",
            "switch_moe_call", "CoordinatorServer", "MembershipView",

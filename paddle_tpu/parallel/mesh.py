@@ -11,7 +11,8 @@ them; XLA's SPMD partitioner does the splitting and inserts the collectives.
 from __future__ import annotations
 
 import contextlib
-from typing import Dict, Optional, Sequence
+import warnings
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -20,7 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from ..fluid.core.lod import SeqArray
 
 __all__ = ["Mesh", "make_mesh", "set_mesh", "current_mesh", "mesh_guard",
-           "feed_sharding", "state_sharding"]
+           "feed_sharding", "state_sharding", "kernel_axes"]
 
 _current_mesh: Optional[Mesh] = None
 
@@ -60,14 +61,39 @@ def mesh_guard(mesh: Mesh):
         set_mesh(old)
 
 
+# batch-sharding axes: 'dp' (training) or 'batch' (the serving
+# batch × model mesh)
+_BATCH_AXES = ("dp", "batch")
+# axes that never carry attention heads: batch, sequence, expert, stage
+_NON_HEAD_AXES = _BATCH_AXES + ("sp", "ep", "pp")
+
+
 def _dp_axes(mesh: Mesh):
-    """Axes used for batch sharding: 'dp' (training) or 'batch' (the
-    serving batch × model mesh), whichever is present, else none."""
-    return [a for a in ("dp", "batch") if a in mesh.axis_names]
+    """Axes used for batch sharding, whichever is present, else none."""
+    return [a for a in _BATCH_AXES if a in mesh.axis_names]
+
+
+def kernel_axes(mesh: Mesh, batch: int,
+                heads: int) -> Tuple[Optional[str], Optional[str]]:
+    """(batch_axis, head_axis) a shard_map'd attention kernel splits
+    over on ``mesh``: the data axis ('dp' / 'batch') when it divides
+    ``batch``, and the tensor-parallel axis — 'mp' in training, 'model'
+    (any other name) on the serving mesh — when it divides ``heads``.
+    None for a dim no axis divides: shard_map needs even splits, so that
+    dim stays whole on every device (what the partitioner does for the
+    XLA path)."""
+    dp = _dp_axes(mesh)
+    b_ax = dp[0] if dp and batch % mesh.shape[dp[0]] == 0 else None
+    h_ax = next((a for a in mesh.axis_names
+                 if a not in _NON_HEAD_AXES and mesh.shape[a] > 1
+                 and heads % mesh.shape[a] == 0), None)
+    return b_ax, h_ax
 
 
 def feed_sharding(mesh: Mesh, value):
-    """Sharding tree for one feed value: batch (dim 0) over 'dp'."""
+    """Sharding tree for one feed value: batch (dim 0) over 'dp'.  A
+    batch the axis does not divide cannot be split — it replicates, and
+    says so: every device then computes the whole batch."""
     dp = _dp_axes(mesh)
 
     def leaf(v):
@@ -76,8 +102,17 @@ def feed_sharding(mesh: Mesh, value):
         # device feeds are exactly the multi-host fast path
         s = getattr(v, "shape", None)   # () is a valid (0-d) shape — no `or`
         shape = tuple(s) if s is not None else np.asarray(v).shape
-        if dp and len(shape) >= 1 and shape[0] % mesh.shape[dp[0]] == 0:
-            return NamedSharding(mesh, PartitionSpec(dp[0]))
+        if dp and len(shape) >= 1:
+            n = mesh.shape[dp[0]]
+            if shape[0] % n == 0:
+                return NamedSharding(mesh, PartitionSpec(dp[0]))
+            if shape[0] > 1:
+                warnings.warn(
+                    f"feed of shape {shape}: leading dim {shape[0]} is not "
+                    f"divisible by mesh axis {dp[0]!r}={n}; the feed is "
+                    f"REPLICATED and every device computes the whole "
+                    f"batch — pad or resize the batch to a multiple of "
+                    f"{n}", RuntimeWarning, stacklevel=3)
         return NamedSharding(mesh, PartitionSpec())
 
     if isinstance(value, SeqArray):

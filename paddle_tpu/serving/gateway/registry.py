@@ -57,10 +57,10 @@ __all__ = ["HBMBudgetError", "ModelRegistry", "MANIFEST_NAME",
 
 MANIFEST_NAME = "gateway.json"
 # per-version persistent AOT executable cache (ISSUE 14): a published
-# version ships its compiled bucket set here (tools/aot_compile
-# pre-warms it offline; serving processes also store back what they do
-# compile, so even an un-prewarmed version pays its compile storm once
-# per artifact, not once per process/restart/swap)
+# version MAY ship its compiled bucket set here (tools/aot_compile
+# pre-warms it offline).  Only an artifact that ships the directory
+# mounts the tier; every other load compiles through the donating jit
+# and JAX's own persistent compilation cache (paddle_tpu/__init__.py).
 COMPILED_SUBDIR = "compiled"
 
 # the paged generator's constructor surface a manifest may carry — kept
@@ -120,13 +120,28 @@ def _register_registry_collector() -> None:
         _collector_registered = True
 
 
+def _ships_compiled(dirname: Optional[str]) -> bool:
+    """True when a load of ``dirname`` mounts the private AOT tier: the
+    artifact ships a ``compiled/`` directory (what ``tools/aot_compile``
+    produces) and the tier is not disabled
+    (``PADDLE_TPU_AOT_DISABLE=1``).  Executables served from the tier
+    dispatch WITHOUT donation, so this is also what the HBM planner
+    keys its ``assume_donation`` on."""
+    return bool(dirname) \
+        and os.environ.get("PADDLE_TPU_AOT_DISABLE", "") != "1" \
+        and os.path.isdir(os.path.join(dirname, COMPILED_SUBDIR))
+
+
 def _artifact_cache(dirname: str):
-    """The artifact's ``compiled/`` executable cache, or None when the
-    tier is disabled (``PADDLE_TPU_AOT_DISABLE=1``).  Always mounted
-    read-write: loads consume the shipped bucket set, and anything the
-    serving process does compile is published back for the next
-    restart."""
-    if os.environ.get("PADDLE_TPU_AOT_DISABLE", "") == "1":
+    """The artifact's shipped ``compiled/`` executable cache (mounted
+    read-write: a bucket the shipped set misses is compiled once and
+    published back), or None — no artifact tier; the executor defers
+    to the process default, normally none — when the artifact ships
+    none.  A fresh artifact never grows a ``compiled/`` behind the
+    publisher's back: its step compiles through
+    ``jax.jit(step, donate_argnums=(1,))``, so the KV pool updates in
+    place."""
+    if not _ships_compiled(dirname):
         return None
     from ...fluid.compile_cache import CompileCache
 
@@ -278,12 +293,12 @@ class ModelRegistry:
         persistable vars with recorded shapes — no separate
         kv_page_bytes term), an engine's saved ``__model__`` program is
         planned at its largest declared batch bucket.  Artifact loads
-        that will mount a ``compiled/`` AOT cache (ISSUE 14) are priced
-        WITHOUT donation aliasing — their executables really dispatch
-        with write-back copies, and a budget computed from the donating
-        ideal would admit models that OOM the chip mid-traffic."""
-        donation = not dirname or \
-            os.environ.get("PADDLE_TPU_AOT_DISABLE", "") == "1"
+        that mount a shipped ``compiled/`` AOT cache (ISSUE 14) are
+        priced WITHOUT donation aliasing — their executables really
+        dispatch with write-back copies, and a budget computed from the
+        donating ideal would admit models that OOM the chip
+        mid-traffic."""
+        donation = not _ships_compiled(dirname)
         if kind == "generator":
             plan = estimate_generator_hbm(config,
                                           assume_donation=donation)
@@ -423,9 +438,10 @@ class ModelRegistry:
         and the HBM budget charges the PAIR jointly (target priced at
         its k+1-token verify shape, draft at its masked decode shape,
         both pools and parameter sets resident at once) BEFORE either
-        model is built.  Each artifact mounts its own ``compiled/`` AOT
-        cache, so a pre-compiled pair serves its draft/verify/cow
-        executables from disk (zero process compiles)."""
+        model is built.  Each artifact that ships a ``compiled/`` AOT
+        cache mounts its own, so a pre-compiled pair serves its
+        draft/verify/cow executables from disk (zero process
+        compiles)."""
         name, version = str(name), str(version)
         key = f"{name}@{version}"
         self._reserve_load(key)
@@ -457,8 +473,8 @@ class ModelRegistry:
                     f"draft kind {d_manifest.get('kind')!r})")
             t_cfg = dict(t_manifest.get("config", {}))
             d_cfg = dict(d_manifest.get("config", {}))
-            donation = os.environ.get(
-                "PADDLE_TPU_AOT_DISABLE", "") == "1"
+            donation = not (_ships_compiled(t_dir)
+                            or _ships_compiled(d_dir))
             plan = estimate_speculative_hbm(t_cfg, d_cfg, k=int(k),
                                             assume_donation=donation)
             cost = int(plan.peak_bytes)

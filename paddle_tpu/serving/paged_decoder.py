@@ -1567,11 +1567,31 @@ class PagedTransformerGenerator:
         }
         if self.mesh is None:
             return out
-        if not self._slots:
-            raise RuntimeError("open_slots() before collective_report()")
-        feed = self._prefill_arrays()
-        feed.update(self._decode_arrays())
         with fluid.scope_guard(self.scope), self._mesh_ctx():
             out["measured"] = self.exe.collective_analysis(
-                prog, feed=feed, fetch_list=[next_ids], mode="infer")
+                prog, feed=self._step_feed(), fetch_list=[next_ids],
+                mode="infer")
         return out
+
+    def _step_feed(self) -> Dict[str, np.ndarray]:
+        """A full unified-step feed at the open lane count, for the
+        lowering-only reports (call between requests: a prefilling
+        lane's pending chunk is recorded by ``_prefill_arrays``)."""
+        if not self._slots:
+            raise RuntimeError("open_slots() before lowering the step")
+        feed = self._prefill_arrays()
+        feed.update(self._decode_arrays())
+        return feed
+
+    def compiled_step_hlo(self) -> str:
+        """Optimized HLO text of the unified serving step at the open
+        lane count, under this generator's mesh
+        (``Executor.compiled_hlo``) — what the device is really given
+        per token: the ragged attention as a Mosaic ``tpu_custom_call``
+        or as gathers, the pool aliased in place or copied.  Lowering
+        only — no KV state changes."""
+        prog, _, next_ids, _ = self._unified
+        with fluid.scope_guard(self.scope), self._mesh_ctx():
+            return self.exe.compiled_hlo(prog, feed=self._step_feed(),
+                                         fetch_list=[next_ids],
+                                         mode="infer")

@@ -76,6 +76,11 @@ def precompile(dirname: str, n_slots: int = 4,
     dirname = os.path.abspath(dirname)
     if not os.path.isdir(dirname):
         raise FileNotFoundError(f"no artifact at {dirname}")
+    # the registry mounts the AOT tier only on artifacts that SHIP a
+    # compiled/ directory — creating it is what marks this version as
+    # one that does
+    if cache_dir is None:
+        os.makedirs(os.path.join(dirname, COMPILED_SUBDIR), exist_ok=True)
     reg = ModelRegistry(place=place or fluid.CPUPlace())
     if draft_dirname is not None:
         if cache_dir is not None:
@@ -88,6 +93,8 @@ def precompile(dirname: str, n_slots: int = 4,
         if not os.path.isdir(draft_dirname):
             raise FileNotFoundError(f"no draft artifact at "
                                     f"{draft_dirname}")
+        os.makedirs(os.path.join(draft_dirname, COMPILED_SUBDIR),
+                    exist_ok=True)
         for what, d in (("target", dirname), ("draft", draft_dirname)):
             kind = reg._manifest(d).get("kind", "engine")
             if kind != "generator":

@@ -143,7 +143,8 @@ def test_ring_attention_grad():
     def loss_naive(q, k, v):
         return naive_attention(q, k, v, causal=True).sum()
 
-    g1 = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
+    # jitted: an eager shard_map dispatches (and compiles) op by op
+    g1 = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
     g2 = jax.grad(loss_naive, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -282,17 +283,16 @@ def test_keep_scale_rate():
 def test_ring_dropout_runs_and_differs():
     mesh = make_mesh({"sp": 4}, jax.devices()[:4])
     q, k, v = make_qkv(b=2, h=2, lq=32, lk=32, d=8)
-    clean = ring_attention_sharded(mesh, q, k, v, dp_axis=None)
-    drop = ring_attention_sharded(mesh, q, k, v, dp_axis=None,
-                                  dropout_rate=0.4, dropout_seed=5)
+    # jitted: an eager shard_map dispatches (and compiles) op by op
+    clean = jax.jit(lambda q: ring_attention_sharded(
+        mesh, q, k, v, dp_axis=None))(q)
+    dropped = jax.jit(lambda q: ring_attention_sharded(
+        mesh, q, k, v, dp_axis=None, dropout_rate=0.4, dropout_seed=5))
+    drop = dropped(q)
     assert not np.allclose(np.asarray(clean), np.asarray(drop))
     # deterministic given the seed, and differentiable
-    drop2 = ring_attention_sharded(mesh, q, k, v, dp_axis=None,
-                                   dropout_rate=0.4, dropout_seed=5)
-    np.testing.assert_array_equal(np.asarray(drop), np.asarray(drop2))
-    g = jax.grad(lambda q: ring_attention_sharded(
-        mesh, q, k, v, dp_axis=None, dropout_rate=0.4,
-        dropout_seed=5).sum())(q)
+    np.testing.assert_array_equal(np.asarray(drop), np.asarray(dropped(q)))
+    g = jax.jit(jax.grad(lambda q: dropped(q).sum()))(q)
     assert np.isfinite(np.asarray(g)).all()
 
 
@@ -378,6 +378,51 @@ def test_flash_pallas_non_divisible_kv_len(pallas_bwd):
         q, k, v, causal=True).sum(), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
+
+
+@pytest.mark.parametrize("axes", [{"dp": 4}, {"dp": 2, "mp": 2}])
+def test_flash_sharded_matches_unsharded(axes, pallas_bwd, fresh_programs):
+    """On a data / tensor-parallel mesh the Pallas flash kernels run
+    inside a shard_map over the batch and head axes (a Mosaic call cannot
+    be auto-partitioned) — outputs AND gradients equal the unsharded
+    kernels', called directly and through the fused_attention op under
+    ``mesh_guard`` (where ``kernel_axes`` picks the axes)."""
+    from paddle_tpu import fluid, parallel
+    from paddle_tpu.kernels import flash_attention_sharded
+
+    mesh = make_mesh(axes, jax.devices()[:4])
+    q, k, v = make_qkv(b=4, h=2, lq=32, lk=32, d=8)
+    b_ax, h_ax = parallel.kernel_axes(mesh, batch=4, heads=2)
+    assert (b_ax, h_ax) == ("dp", "mp" if "mp" in axes else None)
+    kw = dict(causal=True, impl="pallas_interpret")
+
+    def loss(attn):
+        return lambda q, k, v: (attn(q, k, v) ** 2).sum()
+
+    def sharded(q, k, v):
+        return flash_attention_sharded(mesh, q, k, v, batch_axis=b_ax,
+                                       head_axis=h_ax, **kw)
+
+    def plain(q, k, v):
+        return flash_attention(q, k, v, **kw)
+
+    np.testing.assert_allclose(jax.jit(sharded)(q, k, v), plain(q, k, v),
+                               atol=1e-6, rtol=1e-6)
+    g1 = jax.jit(jax.grad(loss(sharded), argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+
+    main, startup, scope = fresh_programs
+    names = ("q", "k", "v")
+    out = fluid.layers.fused_attention(
+        *(fluid.layers.data(n, [2, 32, 8], "float32") for n in names), **kw)
+    feed = {n: np.asarray(x) for n, x in zip(names, (q, k, v))}
+    exe = fluid.Executor(fluid.CPUPlace())
+    with parallel.mesh_guard(mesh):
+        via_op, = exe.run(main, feed=feed, fetch_list=[out])
+    np.testing.assert_allclose(np.asarray(via_op), plain(q, k, v),
+                               atol=1e-6, rtol=1e-6)
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
@@ -491,16 +536,19 @@ def test_ring_flash_chunks_match_unsharded_flash():
     mesh = make_mesh({"sp": 4}, jax.devices()[:4])
     q, k, v = make_qkv(b=2, h=2, lq=64, lk=64, d=8, seed=3)
 
-    ring = ring_attention_sharded(mesh, q, k, v, causal=True, dp_axis=None,
-                                  dropout_rate=0.25, dropout_seed=42)
+    # jitted: an eager shard_map dispatches (and compiles) op by op
+    def ring_fn(v_):
+        return ring_attention_sharded(mesh, q, k, v_, causal=True,
+                                      dp_axis=None, dropout_rate=0.25,
+                                      dropout_seed=42)
+
+    ring = jax.jit(ring_fn)(v)
     flat = flash_attention(q, k, v, causal=True, impl="xla",
                            dropout_rate=0.25, dropout_seed=42)
     np.testing.assert_allclose(np.asarray(ring), np.asarray(flat),
                                atol=2e-5, rtol=2e-5)
 
-    g_ring = jax.grad(lambda v_: ring_attention_sharded(
-        mesh, q, k, v_, causal=True, dp_axis=None, dropout_rate=0.25,
-        dropout_seed=42).sum())(v)
+    g_ring = jax.jit(jax.grad(lambda v_: ring_fn(v_).sum()))(v)
     g_flat = jax.grad(lambda v_: flash_attention(
         q, k, v_, causal=True, impl="xla", dropout_rate=0.25,
         dropout_seed=42).sum())(v)
@@ -513,11 +561,15 @@ def test_ring_non_divisible_shards():
     the ring (kv_len on local columns, offsets on global ones)."""
     mesh = make_mesh({"sp": 4}, jax.devices()[:4])
     q, k, v = make_qkv(b=1, h=2, lq=24, lk=24, d=8, seed=6)  # shards of 6
-    out = ring_attention_sharded(mesh, q, k, v, causal=True, dp_axis=None)
+    # jitted: an eager shard_map dispatches (and compiles) op by op
+    def ring_fn(q_):
+        return ring_attention_sharded(mesh, q_, k, v, causal=True,
+                                      dp_axis=None)
+
+    out = jax.jit(ring_fn)(q)
     ref = naive_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5, rtol=2e-5)
-    g1 = jax.grad(lambda q_: ring_attention_sharded(
-        mesh, q_, k, v, causal=True, dp_axis=None).sum())(q)
+    g1 = jax.jit(jax.grad(lambda q_: ring_fn(q_).sum()))(q)
     g2 = jax.grad(lambda q_: naive_attention(q_, k, v, causal=True).sum())(q)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
                                atol=5e-4, rtol=5e-4)
@@ -597,7 +649,8 @@ def test_ulysses_attention_grad():
     def loss_naive(q, k, v):
         return naive_attention(q, k, v, causal=True).sum()
 
-    g1 = jax.grad(loss_uly, argnums=(0, 1, 2))(q, k, v)
+    # jitted: an eager shard_map dispatches (and compiles) op by op
+    g1 = jax.jit(jax.grad(loss_uly, argnums=(0, 1, 2)))(q, k, v)
     g2 = jax.grad(loss_naive, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), b, atol=5e-4, rtol=5e-4)
@@ -672,25 +725,24 @@ def test_ulysses_dropout_runs_and_differs():
 
     mesh = make_mesh({"sp": 4}, jax.devices()[:4])
     q, k, v = make_qkv(b=2, h=4, lq=32, lk=32, d=8)
-    clean = ulysses_attention_sharded(mesh, q, k, v, dp_axis=None)
-    drop = ulysses_attention_sharded(mesh, q, k, v, dp_axis=None,
-                                     dropout_rate=0.4, dropout_seed=5)
+    # jitted: an eager shard_map dispatches (and compiles) op by op
+    clean = jax.jit(lambda q: ulysses_attention_sharded(
+        mesh, q, k, v, dp_axis=None))(q)
+    dropped = jax.jit(lambda q: ulysses_attention_sharded(
+        mesh, q, k, v, dp_axis=None, dropout_rate=0.4, dropout_seed=5))
+    drop = dropped(q)
     assert not np.allclose(np.asarray(clean), np.asarray(drop))
-    drop2 = ulysses_attention_sharded(mesh, q, k, v, dp_axis=None,
-                                      dropout_rate=0.4, dropout_seed=5)
-    np.testing.assert_array_equal(np.asarray(drop), np.asarray(drop2))
+    np.testing.assert_array_equal(np.asarray(drop), np.asarray(dropped(q)))
     # decorrelation across head tiles: with IDENTICAL q/k/v per head,
     # identical masks would give identical per-head outputs
     q1 = jnp.broadcast_to(q[:, :1], q.shape)
     k1 = jnp.broadcast_to(k[:, :1], k.shape)
     v1 = jnp.broadcast_to(v[:, :1], v.shape)
-    d1 = np.asarray(ulysses_attention_sharded(
-        mesh, q1, k1, v1, dp_axis=None, dropout_rate=0.4,
-        dropout_seed=5))
+    d1 = np.asarray(jax.jit(lambda *a: ulysses_attention_sharded(
+        mesh, *a, dp_axis=None, dropout_rate=0.4,
+        dropout_seed=5))(q1, k1, v1))
     pairs_equal = [np.allclose(d1[:, a], d1[:, b])
                    for a in range(4) for b in range(a + 1, 4)]
     assert not any(pairs_equal), "head-tile dropout masks are correlated"
-    g = jax.grad(lambda q: ulysses_attention_sharded(
-        mesh, q, k, v, dp_axis=None, dropout_rate=0.4,
-        dropout_seed=5).sum())(q)
+    g = jax.jit(jax.grad(lambda q: dropped(q).sum()))(q)
     assert np.isfinite(np.asarray(g)).all()

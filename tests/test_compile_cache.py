@@ -16,6 +16,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -103,7 +104,7 @@ def test_stale_salt_entry_is_a_miss(tmp_path):
     key = a.keys()[0]           # startup + main = two stored entries
     os.makedirs(b.dirname, exist_ok=True)
     os.rename(a._path(key), b._path(key))
-    assert b.load(key) is None
+    assert b.load(key, jax.devices()[:1]) is None
     assert b._stats["corrupt"] == 1 and b._stats["misses"] == 1
 
 

@@ -513,26 +513,31 @@ def test_registry_costs_with_static_plan(tmp_path, small_gen):
     ModelRegistry.save_generator_artifact(small_gen, root, "m", "1")
     cfg = json.load(open(os.path.join(root, "m", "1",
                                       "gateway.json")))["config"]
-    cost = ModelRegistry._estimate_cost(
-        "generator", fluid.io.model_version_dir(root, "m", "1"), cfg)
+    art = fluid.io.model_version_dir(root, "m", "1")
+    cost = ModelRegistry._estimate_cost("generator", art, cfg)
     # the manifest-built desc and the live generator agree exactly.
-    # An artifact load mounts a compiled/ AOT cache (ISSUE 14), so the
-    # registry prices the no-donation dispatch its executables really
-    # run; the live instance self-selects the same model once a cache
-    # is mounted on its executor — compare like for like both ways.
+    # A fresh artifact compiles through the donating jit, so the
+    # registry prices the donating plan; one that SHIPS a compiled/ AOT
+    # cache (ISSUE 14) mounts it and is priced for the no-donation
+    # dispatch its executables really run.  The live instance
+    # self-selects the same model once a cache is mounted on its
+    # executor — compare like for like both ways.
     from paddle_tpu.fluid.compile_cache import CompileCache
     from paddle_tpu.serving.paged_decoder import estimate_generator_hbm
 
     plan = small_gen.static_hbm_estimate()       # no cache: donating
     assert plan.peak_bytes == \
-        estimate_generator_hbm(cfg).peak_bytes
-    assert cost == \
+        estimate_generator_hbm(cfg).peak_bytes == cost
+    os.makedirs(os.path.join(art, "compiled"))
+    shipped = ModelRegistry._estimate_cost("generator", art, cfg)
+    os.rmdir(os.path.join(art, "compiled"))
+    assert shipped == \
         estimate_generator_hbm(cfg, assume_donation=False).peak_bytes
-    assert cost > plan.peak_bytes                # write-backs priced in
+    assert shipped > plan.peak_bytes             # write-backs priced in
     small_gen.exe.set_compile_cache(
         CompileCache(os.path.join(root, "unused-cache")))
     try:
-        assert cost == small_gen.static_hbm_estimate().peak_bytes
+        assert shipped == small_gen.static_hbm_estimate().peak_bytes
     finally:
         small_gen.exe.set_compile_cache(None)
     # …and the plan covers more than the old artifact-byte heuristic:

@@ -183,6 +183,72 @@ def test_ragged_pallas_interpret_matches_xla():
         assert (np.asarray(a)[1] == 0).all()       # dead lane contract
 
 
+@pytest.mark.parametrize("axes,int8", [({"dp": 4}, False),
+                                       ({"batch": 2, "model": 2}, False),
+                                       ({"batch": 2, "model": 2}, True)])
+def test_ragged_sharded_matches_unsharded(axes, int8, fresh_programs):
+    """On a mesh the Pallas ragged kernel runs inside a shard_map (a
+    Mosaic call cannot be auto-partitioned): lanes split over the batch
+    axis, heads — q's and the pool's — over the model axis, the int8
+    scale sidecar replicated.  Same numbers as the unsharded call, both
+    called directly and through the op under ``mesh_guard`` (where
+    ``kernel_axes`` picks the axes)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import parallel
+    from paddle_tpu.kernels.flash_attention import (
+        ragged_decode_attention, ragged_decode_attention_sharded)
+
+    rng = np.random.RandomState(5)
+    H, D, L, NPAGES, P, C, B = 2, 4, 2, 9, 2, 2, 4
+    R = NPAGES * L * 2
+    scales = None
+    if int8:
+        pool_np = rng.randint(-127, 128, (H, R, PS, D)).astype(np.int8)
+        scales = jnp.asarray(rng.rand(1, R, PS).astype(np.float32) + 0.5)
+    else:
+        pool_np = rng.randn(H, R, PS, D).astype(np.float32)
+    q = rng.randn(B, C, H, D).astype(np.float32)
+    tbl = rng.randint(1, NPAGES, (B, P)).astype(np.int32)
+    lengths = np.array([7, 0, 8, 3], np.int32)
+    base = np.array([5, 0, 6, 1], np.int32)
+    kw = dict(layer=1, n_layer=L, causal=True, impl="pallas_interpret",
+              scales=scales)
+    want = np.asarray(ragged_decode_attention(
+        jnp.asarray(q), jnp.asarray(pool_np), jnp.asarray(tbl),
+        jnp.asarray(lengths), jnp.asarray(base), **kw))
+
+    mesh = parallel.make_mesh(axes, jax.devices()[:4])
+    b_ax, h_ax = parallel.kernel_axes(mesh, batch=B, heads=H)
+    assert (b_ax, h_ax) == (("dp", None) if "dp" in axes
+                            else ("batch", "model"))
+    got = jax.jit(lambda *a: ragged_decode_attention_sharded(
+        mesh, *a, batch_axis=b_ax, head_axis=h_ax, **kw))(
+        q, pool_np, tbl, lengths, base)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)
+    if int8:
+        return
+    main, startup, scope = fresh_programs
+    pool = main.global_block().create_var(
+        name="pool", shape=list(pool_np.shape), dtype="float32",
+        persistable=True)
+    if h_ax:
+        pool.set_sharding((h_ax, None, None, None))
+    out = layers.ragged_decode_attention(
+        layers.data("q", [C, H, D], "float32"), pool,
+        layers.data("tbl", [P], "int32"), layers.data("ln", [], "int32"),
+        layers.data("qb", [], "int32"), layer=1, n_layer=L, causal=True,
+        impl="pallas_interpret")
+    scope.set_var("pool", jnp.asarray(pool_np))
+    exe = fluid.Executor(fluid.CPUPlace())
+    with parallel.mesh_guard(mesh):
+        via_op, = exe.run(main, feed={"q": q, "tbl": tbl, "ln": lengths,
+                                      "qb": base}, fetch_list=[out])
+    np.testing.assert_allclose(np.asarray(via_op), want, rtol=1e-6,
+                               atol=1e-6)
+
+
 # -- parity vs the dense decoder ----------------------------------------------
 
 def test_greedy_parity_token_for_token(paged_pair):

@@ -410,6 +410,29 @@ def test_registry_shard_preflight(monkeypatch, tmp_path):
 # the differential gate: inferred graph == compiled-HLO ground truth
 # ---------------------------------------------------------------------------
 
+def test_collective_scan_reads_tpu_and_tuple_hlo():
+    """The measured side must read what real compilers print: TPU
+    layouts carry tiling / memory-space annotations, collectives come as
+    async -start/-done pairs, and a combined gradient all-reduce is one
+    tuple whose long shape XLA numbers with /*index=N*/ comments.  (The
+    first four-chip run, PR 21, scanned a dp=4 step and found nothing.)"""
+    hlo = """
+  %ars.3 = f32[512,2048]{1,0:T(8,128)} all-reduce-start(f32[512,2048]{1,0:T(8,128)} %f), channel_id=5, to_apply=%add
+  %ard.3 = f32[512,2048]{1,0:T(8,128)} all-reduce-done(%ars.3)
+  %ar.42 = (f32[64,16]{1,0}, f32[9,16]{1,0}, f32[64,16]{1,0}, f32[9,16]{1,0}, f32[16,16]{1,0}, /*index=5*/f32[16,16]{1,0}) all-reduce(%a, %b), channel_id=1
+  %gte.1 = f32[64,16]{1,0} get-tuple-element(%ar.42), index=0
+  %ag = bf16[4,128]{1,0:T(4,128)(2,1)S(1)} all-gather(bf16[1,128]{1,0:T(2,128)(2,1)} %x), dimensions={0}
+  ROOT %t = (f32[2]{0}) tuple(%all-reduce.1)
+"""
+    per_kind, total = fluid.Executor.collectives_in_hlo(hlo)
+    tuple_bytes = 4 * (2 * 64 * 16 + 2 * 9 * 16 + 2 * 16 * 16)
+    assert per_kind == {
+        "all-reduce": {"count": 2,
+                       "payload_bytes": 4.0 * 512 * 2048 + tuple_bytes},
+        "all-gather": {"count": 1, "payload_bytes": 2.0 * 4 * 128}}
+    assert total == sum(k["payload_bytes"] for k in per_kind.values())
+
+
 def _assert_differential(tag, prog, mesh_axes, feed, fetch_list, exe,
                          scope, mesh, mode, assume_batch):
     with fluid.scope_guard(scope), pmesh.mesh_guard(mesh):
