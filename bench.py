@@ -365,81 +365,6 @@ def bench_guardrails(model: str, batch: int, steps: int, trials: int):
             "guarded_steps": stats["guarded_steps"]}
 
 
-def bench_observability(model: str, batch: int, steps: int, trials: int):
-    """Telemetry overhead (ISSUE 8 satellite): the SAME training loop
-    with the tracer off ("bare") and on ("instrumented") — the
-    per-step cost of instrumentation is one ring-buffer append per
-    dispatch span plus the registry's scrape-time collectors (zero on
-    the hot path), so overhead_pct must stay < 1%.  Also scrapes a live
-    /metrics endpoint mid-run and reports the exposed series count —
-    the regression guard for the exported surface itself."""
-    import urllib.request
-
-    from paddle_tpu import fluid, observability as obs
-
-    main_prog, startup, scope, cost, px, ncls = _build_image_net(
-        model, in_dtype="float32")
-    exe = fluid.Executor(fluid.TPUPlace(0))
-    rng = np.random.RandomState(0)
-    feed = {"img": rng.rand(batch, 3, px, px).astype(np.float32),
-            "label": rng.randint(0, ncls, (batch, 1)).astype(np.int32)}
-    tr = obs.tracer()
-    was_enabled = tr.enabled
-    try:
-        with fluid.scope_guard(scope):
-            exe.run(startup)
-            exe.run(main_prog, feed=feed, fetch_list=[cost])     # warm
-
-            best_bare = best_instr = float("inf")
-            tr.disable()
-            for _ in range(trials):
-                t0 = time.time()
-                for _ in range(steps):
-                    out, = exe.run(main_prog, feed=feed,
-                                   fetch_list=[cost],
-                                   return_numpy=False)
-                final = float(np.asarray(out))      # blocking fetch
-                best_bare = min(best_bare, time.time() - t0)
-                assert np.isfinite(final), f"diverged: {final}"
-            tr.enable()
-            tr.clear()
-            for _ in range(trials):
-                t0 = time.time()
-                for _ in range(steps):
-                    out, = exe.run(main_prog, feed=feed,
-                                   fetch_list=[cost],
-                                   return_numpy=False)
-                float(np.asarray(out))
-                best_instr = min(best_instr, time.time() - t0)
-            spans = len(tr.events())
-
-        srv = obs.ObservabilityServer()
-        srv.attach("executor", exe)
-        addr = srv.start()
-        try:
-            text = urllib.request.urlopen(
-                f"http://{addr}/metrics", timeout=10).read().decode()
-            health = urllib.request.urlopen(
-                f"http://{addr}/healthz", timeout=10).read()
-        finally:
-            srv.stop()
-        assert b'"ok": true' in health, health
-    finally:
-        tr.enabled = was_enabled
-    lines = text.splitlines()
-    bare_ms = best_bare / steps * 1e3
-    instr_ms = best_instr / steps * 1e3
-    return {"model": model, "batch": batch,
-            "bare_ms_per_batch": round(bare_ms, 3),
-            "instrumented_ms_per_batch": round(instr_ms, 3),
-            "overhead_pct": round((instr_ms - bare_ms) / bare_ms * 100,
-                                  2),
-            "spans_per_step": round(spans / (steps * trials), 2),
-            "metrics_lines": len(lines),
-            "metrics_series": sum(1 for ln in lines
-                                  if ln and not ln.startswith("#"))}
-
-
 def bench_transformer(batch: int, steps: int, trials: int,
                       seq_len: int = 256):
     import jax
@@ -2893,16 +2818,6 @@ def main() -> None:
         except Exception as e:
             print(f"pipeline bench failed: {e}", file=sys.stderr)
 
-    observability_cmp = None
-    if os.environ.get("BENCH_SKIP_OBSERVABILITY", "") != "1":
-        try:
-            observability_cmp = bench_observability(
-                os.environ.get("BENCH_OBS_MODEL", "smallnet"),
-                int(os.environ.get("BENCH_IMAGE_BATCH", "128")),
-                steps, trials)
-        except Exception as e:
-            print(f"observability bench failed: {e}", file=sys.stderr)
-
     serving_cmp = None
     if os.environ.get("BENCH_SKIP_SERVING", "") != "1":
         try:
@@ -3046,11 +2961,6 @@ def main() -> None:
         # guarded-vs-unguarded step cost (ISSUE 4): the measured price
         # of the fused NaN/divergence sentinel + health-flag sync
         "guardrails": guardrails_cmp,
-        # telemetry cost (ISSUE 8): instrumented-vs-bare step ms/batch
-        # (contract: overhead_pct < 1) and the live /metrics series
-        # count — instrumentation cost regressions caught like any perf
-        # regression
-        "observability": observability_cmp,
         # KV-cache serving vs full-re-run decoding (ISSUE 5): prefill
         # tok/s, decode steps/s, the O(L) vs O(L^2) speedup, continuous-
         # batching p50/p95 at a fixed offered load, bucket hit rate and
@@ -3156,9 +3066,6 @@ def main() -> None:
     if os.environ.get("BENCH_SKIP_GUARDRAILS", "") != "1" \
             and guardrails_cmp is None:
         missing.append("guardrails")
-    if os.environ.get("BENCH_SKIP_OBSERVABILITY", "") != "1" \
-            and observability_cmp is None:
-        missing.append("observability")
     if os.environ.get("BENCH_SKIP_SERVING", "") != "1" \
             and serving_cmp is None:
         missing.append("serving")

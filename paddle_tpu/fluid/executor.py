@@ -1053,97 +1053,111 @@ class Executor:
         the policy's watchdog deadline + transient-fault retry.
         Counters: ``health_stats()``.  Guarded steps are
         bitwise-identical to unguarded ones on healthy batches."""
-        policy = None
-        if guard is not None:
-            from ..resilience.guardrails import GuardPolicy
-
-            policy = (guard if isinstance(guard, GuardPolicy)
-                      else GuardPolicy(on_nonfinite=str(guard)))
-        program = program or default_main_program()
-        feed = {k: _as_feed_value(v) for k, v in (feed or {}).items()}
-        fetch_names = [f.name if isinstance(f, Variable) else str(f)
-                       for f in (fetch_list or [])]
-        scope = scope or global_scope()
-        desc = program.desc
-        block = desc.global_block()
-
-        prog_fp = self._program_key(program)
-        level = self._validate_level(validate)
-        if level != "off":
-            self._preflight(program, prog_fp, level, fetch_names)
-        traced_ops, pre_host, post_host, state_in, state_out = \
-            self._classified(prog_fp, feed, fetch_names, block)
-
-        for op in pre_host:
-            self._run_host_op(op, scope)
-        if not traced_ops and not fetch_names:
-            for op in post_host:
-                self._run_host_op(op, scope)
-            return []
-
-        state_vals = self._fetch_state(state_in, traced_ops, fetch_names,
-                                       scope)
-
-        from ..parallel import mesh as _pmesh
-
-        mesh = _pmesh.current_mesh()
-        # content key, not id(mesh): a GC'd Mesh's reused id must not replay
-        # an executable jitted for different axes/devices (same hazard the
-        # program fingerprint guards against)
-        mesh_key = None if mesh is None else (
-            tuple(mesh.shape.items()),
-            tuple(d.id for d in mesh.devices.flat))
-        guard_names = None
-        if policy is not None:
-            guard_names = self._guard_check_names(
-                prog_fp, policy, program, traced_ops, state_out, fetch_names)
-        key = (self._program_key(program), mode, mesh_key,
-               tuple((n, _sig_of(v)) for n, v in sorted(feed.items())),
-               tuple(fetch_names),
-               tuple((n, _sig_of(v)) for n, v in sorted(state_vals.items())),
-               None if guard_names is None else ("guard",) + guard_names)
+        from ..observability.tracing import tracer as _obs_tracer
         from ..utils.flags import FLAGS
 
-        compiled, state_sh, feed_sh = self._lookup_executable(key) \
-            or (None, None, None)
-        if compiled is None:
+        tr = _obs_tracer()
+        # four spans a step: prepare, (compile on a miss,) the launch
+        # (executor_step/<mode>, below) and writeback
+        with tr.span("executor/prepare", cat="executor", mode=mode):
+            policy = None
+            if guard is not None:
+                from ..resilience.guardrails import GuardPolicy
+
+                policy = (guard if isinstance(guard, GuardPolicy)
+                          else GuardPolicy(on_nonfinite=str(guard)))
+            program = program or default_main_program()
+            feed = {k: _as_feed_value(v) for k, v in (feed or {}).items()}
+            fetch_names = [f.name if isinstance(f, Variable) else str(f)
+                           for f in (fetch_list or [])]
+            scope = scope or global_scope()
+            desc = program.desc
+            block = desc.global_block()
+
+            prog_fp = self._program_key(program)
+            level = self._validate_level(validate)
+            if level != "off":
+                self._preflight(program, prog_fp, level, fetch_names)
+            traced_ops, pre_host, post_host, state_in, state_out = \
+                self._classified(prog_fp, feed, fetch_names, block)
+
+            for op in pre_host:
+                self._run_host_op(op, scope)
+            if not traced_ops and not fetch_names:
+                for op in post_host:
+                    self._run_host_op(op, scope)
+                return []
+
+            state_vals = self._fetch_state(state_in, traced_ops, fetch_names,
+                                           scope)
+
+            from ..parallel import mesh as _pmesh
+
+            mesh = _pmesh.current_mesh()
+            # content key, not id(mesh): a GC'd Mesh's reused id must not
+            # replay an executable jitted for different axes/devices (same
+            # hazard the program fingerprint guards against)
+            mesh_key = None if mesh is None else (
+                tuple(mesh.shape.items()),
+                tuple(d.id for d in mesh.devices.flat))
+            guard_names = None
             if policy is not None:
-                from ..resilience.guardrails import build_guarded_step_fn
+                guard_names = self._guard_check_names(
+                    prog_fp, policy, program, traced_ops, state_out,
+                    fetch_names)
+            key = (self._program_key(program), mode, mesh_key,
+                   tuple((n, _sig_of(v)) for n, v in sorted(feed.items())),
+                   tuple(fetch_names),
+                   tuple((n, _sig_of(v))
+                         for n, v in sorted(state_vals.items())),
+                   None if guard_names is None else ("guard",) + guard_names)
+            compiled, state_sh, feed_sh = self._lookup_executable(key) \
+                or (None, None, None)
+        if compiled is None:
+            import hashlib
 
-                step = build_guarded_step_fn(desc, 0, list(feed), state_in,
-                                             state_out, fetch_names, mode,
-                                             guard_names)
-            else:
-                step = build_step_fn(desc, 0, list(feed), state_in,
-                                     state_out, fetch_names, mode)
-            in_sh = None
-            if mesh is not None:
-                # SPMD: feeds batch-sharded over 'dp', persistables per
-                # their desc annotations; the partitioner emits the grad
-                # all-reduce the reference needed pserver/NCCL for.
-                feed_sh = {n: _pmesh.feed_sharding(mesh, v)
-                           for n, v in feed.items()}
-                state_sh = {
-                    n: _pmesh.state_sharding(
-                        mesh, v,
-                        block.vars[n].sharding if n in block.vars else None)
-                    for n, v in state_vals.items()}
-                from jax.sharding import NamedSharding, PartitionSpec
+            # the digest answers "which step recompiled": equal keys,
+            # equal digests, within one process
+            digest = hashlib.sha1(repr(key).encode()).hexdigest()[:12]
+            with tr.span("executor/compile", cat="executor", mode=mode,
+                         key=digest):
+                if policy is not None:
+                    from ..resilience.guardrails import build_guarded_step_fn
 
-                in_sh = (feed_sh, state_sh,
-                         NamedSharding(mesh, PartitionSpec()))
-            else:
-                feed_sh = None
-            # the rng placeholder shares the real rng_bits' signature
-            # (int32[2]); the persistent tier keys on the same mem_key
-            # the in-memory cache just missed on
-            compiled = self._aot_compile(
-                key, step,
-                (feed, state_vals, np.zeros(2, np.int32)),
-                in_shardings=in_sh, mesh=mesh)
-            self._store_executable(key, (compiled, state_sh
-                                         if mesh is not None else None,
-                                         feed_sh))
+                    step = build_guarded_step_fn(desc, 0, list(feed), state_in,
+                                                 state_out, fetch_names, mode,
+                                                 guard_names)
+                else:
+                    step = build_step_fn(desc, 0, list(feed), state_in,
+                                         state_out, fetch_names, mode)
+                in_sh = None
+                if mesh is not None:
+                    # SPMD: feeds batch-sharded over 'dp', persistables per
+                    # their desc annotations; the partitioner emits the grad
+                    # all-reduce the reference needed pserver/NCCL for.
+                    feed_sh = {n: _pmesh.feed_sharding(mesh, v)
+                               for n, v in feed.items()}
+                    state_sh = {
+                        n: _pmesh.state_sharding(
+                            mesh, v, block.vars[n].sharding
+                            if n in block.vars else None)
+                        for n, v in state_vals.items()}
+                    from jax.sharding import NamedSharding, PartitionSpec
+
+                    in_sh = (feed_sh, state_sh,
+                             NamedSharding(mesh, PartitionSpec()))
+                else:
+                    feed_sh = None
+                # the rng placeholder shares the real rng_bits' signature
+                # (int32[2]); the persistent tier keys on the same mem_key
+                # the in-memory cache just missed on
+                compiled = self._aot_compile(
+                    key, step,
+                    (feed, state_vals, np.zeros(2, np.int32)),
+                    in_shardings=in_sh, mesh=mesh)
+                self._store_executable(key, (compiled, state_sh
+                                             if mesh is not None else None,
+                                             feed_sh))
 
         if state_sh is not None:
             # re-lay out state whose current placement disagrees with its
@@ -1215,14 +1229,15 @@ class Executor:
             # outranks a partial guard's recovery)
             self._check_nan_inf(list(new_state.items()) +
                                 list(zip(fetch_names, fetches)))
-        for n, v in new_state.items():
-            scope.set_var(n, v)
-        for op in post_host:
-            self._run_host_op(op, scope)
+        with tr.span("executor/writeback", cat="executor", mode=mode):
+            for n, v in new_state.items():
+                scope.set_var(n, v)
+            for op in post_host:
+                self._run_host_op(op, scope)
 
-        if return_numpy:
-            return [_to_numpy(f) for f in fetches]
-        return list(fetches)
+            if return_numpy:
+                return [_to_numpy(f) for f in fetches]
+            return list(fetches)
 
     # -- pipelined dispatch --------------------------------------------------
     def run_pipeline(self, program: Optional[Program] = None,
@@ -1439,6 +1454,7 @@ class Executor:
 
                 return jax.lax.scan(body, state, (stacked_feeds, rng_stack))
 
+            multi.__name__ = f"{mode}_scan{k}"
             compiled = self._aot_compile(
                 key, multi, (stacked_feeds, state_vals, rng_stack))
             self._store_executable(key, (compiled, None, None))

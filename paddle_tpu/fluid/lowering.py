@@ -201,4 +201,7 @@ def build_step_fn(desc: ProgramDesc, block_idx: int,
         new_state = {n: env[n] for n in state_out if n in env}
         return fetches, new_state
 
+    # the executable's name in a profile (``jit_train_step``): by mode, so
+    # that a trace says which step ran and a refactor renames nothing
+    step.__name__ = f"{mode}_step"
     return step

@@ -38,23 +38,19 @@ def record_event(name: str):
     """RAII timing block — analog of platform::RecordEvent (profiler.h:25).
     The executor wraps each compiled-step invocation in one of these.
 
-    Every event is ALSO emitted as an observability tracing span (same
-    name, cat="profiler"), so ``get_profile_table`` and the Chrome-trace
-    export describe the same timeline — the table aggregates, the trace
-    keeps per-occurrence timing."""
-    tr = _obs_tracer()
-    if not _enabled and not tr.enabled:
-        yield
-        return
+    Every event is ALSO an observability tracing span (same name,
+    cat="profiler": ring event plus profiler annotation), so
+    ``get_profile_table``, the Chrome-trace export and a ``jax.profiler``
+    trace describe the same timeline — the table aggregates, the traces
+    keep per-occurrence timing."""
     t0 = time.perf_counter()
     try:
-        yield
+        with _obs_tracer().span(name, cat="profiler"):
+            yield
     finally:
-        t1 = time.perf_counter()
         if _enabled:
             with _events_lock:
-                _events[name].append(t1 - t0)
-        tr.complete(name, t0, t1, cat="profiler")
+                _events[name].append(time.perf_counter() - t0)
 
 
 def reset_profiler():

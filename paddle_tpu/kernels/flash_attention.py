@@ -318,6 +318,7 @@ def _ragged_pallas(q, pool, page_table, lengths, q_base, layer, n_layer,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="ragged_paged_attn",
     )(k_rows, v_rows, meta, *args)
     return jnp.transpose(out.reshape(b, h, c, d), (0, 2, 1, 3))
 
@@ -796,6 +797,7 @@ def _pallas_forward(q, k, v, bias, seed, offsets, sm_scale, causal, kv_len,
         scratch_shapes=scratch,
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     if need_lse:
         out, lse = res
@@ -989,6 +991,7 @@ def _pallas_backward(q, k, v, do, out, lse128, seed, offsets, sm_scale,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*dq_args)
 
     # ---- dk/dv: grid (bh, nk, nq), q-blocks innermost
@@ -1032,6 +1035,7 @@ def _pallas_backward(q, k, v, do, out, lse128, seed, offsets, sm_scale,
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*dkv_args)
     dq = dq.reshape(b, h, lq, d)
     dk = dk.reshape(b, h, lk, d)

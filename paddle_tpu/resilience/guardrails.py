@@ -289,6 +289,7 @@ def build_guarded_step_fn(desc, block_idx: int, feed_names: Sequence[str],
                 gated[n] = jnp.where(healthy, v, old)
         return [outs[idx[n]] for n in fetch_names], gated, healthy
 
+    step.__name__ = f"{mode}_step"      # as build_step_fn names its own
     return step
 
 

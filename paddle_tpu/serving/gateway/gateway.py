@@ -35,6 +35,7 @@ from typing import Dict, Iterator, List, Optional
 import numpy as np
 
 from ...observability import metrics as _obs_metrics
+from ...observability.tracing import tracer as _obs_tracer
 from ...resilience.chaos import injector as _chaos_injector
 from ...utils.sync import RANK_GATEWAY_WEDGE, OrderedLock
 from ..scheduler import (ContinuousBatchingScheduler, Request,
@@ -75,6 +76,7 @@ class TokenStream:
         self.request = request
         self.timeout = float(timeout)
         self._q: "_queue.Queue" = _queue.Queue()
+        self._handed = 0            # tokens handed to the consumer, to 2
 
     # the scheduler-side callback (runs under the scheduler lock: a
     # lock-free enqueue is all that happens here)
@@ -85,6 +87,13 @@ class TokenStream:
         return self
 
     def __next__(self) -> int:
+        if self._handed == 1:
+            # the consumer is back for its second token: the first has
+            # been dealt with (the HTTP handler has written its chunk and
+            # flushed the socket).  Once per request, nothing per token.
+            self._handed = 2
+            _obs_tracer().instant("gateway/first_chunk", cat="gateway",
+                                  rid=self.request.rid)
         if self.request.done and self._q.empty():
             self._finish()
         try:
@@ -95,6 +104,8 @@ class TokenStream:
                 f"(rid {self.request.rid})")
         if item is self._DONE:
             self._finish()
+        if not self._handed:
+            self._handed = 1
         return item
 
     def _finish(self):
@@ -502,64 +513,70 @@ class Gateway:
         ride the request as ``Request.decode`` (ISSUE 15).  ``tag`` is
         an opaque caller id journaled with the entry (ISSUE 16: the
         fleet router's migration correlator)."""
-        if self._draining:
-            # refuse BEFORE rate-limit debit and BEFORE journaling:
-            # work accepted now would only be handed back as failed
-            # when the drain reaches the queue
-            raise GatewayDraining(
-                "gateway is draining; resubmit to another replica")
-        cfg = self.router.tenant(tenant)
-        key = self.registry.resolve(model)
-        try:
-            inst = self.registry.instance(key)  # KeyError: unknown model
-        except KeyError:
-            # TOCTOU with a concurrent hot swap (found by the ISSUE 13
-            # race harness): the alias flipped and the old version
-            # unloaded between resolve() and instance() — a client
-            # submitting against a model that IS being served got a
-            # spurious unknown-model error mid-swap.  Re-resolve once;
-            # a genuinely unknown model still raises.
+        # one span per request, from the caller's arguments (the HTTP
+        # handler's parsed body) to the scheduler's queue; ``rid`` is
+        # filled in once the scheduler has given one
+        with _obs_tracer().span("gateway/ingress", cat="gateway",
+                                model=model, tenant=tenant) as ingress:
+            if self._draining:
+                # refuse BEFORE rate-limit debit and BEFORE journaling:
+                # work accepted now would only be handed back as failed
+                # when the drain reaches the queue
+                raise GatewayDraining(
+                    "gateway is draining; resubmit to another replica")
+            cfg = self.router.tenant(tenant)
             key = self.registry.resolve(model)
-            inst = self.registry.instance(key)
-        if not callable(getattr(inst, "open_slots", None)):
-            raise TypeError(
-                f"model {model!r} is an engine artifact (batch "
-                f"inference); the generate path needs a generator — "
-                f"call registry.instance({model!r}).infer(feed) instead")
-        cap = getattr(inst, "max_out_len", self.sched.default_max_new)
-        eff_new = min(max_new or self.sched.default_max_new, cap)
-        # rate-limit BEFORE decoding options: compile_constraint can
-        # cost real CPU/memory on a large grammar, and an over-budget
-        # tenant must not get to burn it
-        self.router.check_submit(
-            tenant, self.router.request_cost(len(prompt), eff_new))
-        decode = self._decode_options(model, inst, draft_model,
-                                      constraint, speculate)
-        jid = None
-        if self.journal is not None:
-            jid = self.journal.new_jid()
-            self.journal.record_submit(jid, tenant, model, prompt,
-                                       eff_new, decode=decode, tag=tag,
-                                       session=session)
-        try:
-            req = self.sched.submit(
-                prompt, max_new_tokens=eff_new, model=model,
-                tenant=tenant, decode=decode, session=session,
-                on_token=self._wrap_on_token(jid, cfg.slo, inst,
-                                             on_token))
-        except BaseException as e:
-            # the scheduler refused it (infeasible prompt, too long):
-            # close the journal entry, or a restart would replay a
-            # request that can never be served — a poison pill
-            if self.journal is not None and jid is not None:
-                self.journal.record_done(jid, ok=False,
-                                         error=type(e).__name__)
-            raise
-        req.jid = jid
-        version = key.split("@", 1)[-1] if "@" in key else "?"
-        self._m_requests.labels(tenant=tenant, model=model,
-                                version=version, event="submitted").inc()
-        return req
+            try:
+                inst = self.registry.instance(key)  # KeyError: unknown model
+            except KeyError:
+                # TOCTOU with a concurrent hot swap (found by the ISSUE 13
+                # race harness): the alias flipped and the old version
+                # unloaded between resolve() and instance() — a client
+                # submitting against a model that IS being served got a
+                # spurious unknown-model error mid-swap.  Re-resolve once;
+                # a genuinely unknown model still raises.
+                key = self.registry.resolve(model)
+                inst = self.registry.instance(key)
+            if not callable(getattr(inst, "open_slots", None)):
+                raise TypeError(
+                    f"model {model!r} is an engine artifact (batch "
+                    f"inference); the generate path needs a generator — "
+                    f"call registry.instance({model!r}).infer(feed) instead")
+            cap = getattr(inst, "max_out_len", self.sched.default_max_new)
+            eff_new = min(max_new or self.sched.default_max_new, cap)
+            # rate-limit BEFORE decoding options: compile_constraint can
+            # cost real CPU/memory on a large grammar, and an over-budget
+            # tenant must not get to burn it
+            self.router.check_submit(
+                tenant, self.router.request_cost(len(prompt), eff_new))
+            decode = self._decode_options(model, inst, draft_model,
+                                          constraint, speculate)
+            jid = None
+            if self.journal is not None:
+                jid = self.journal.new_jid()
+                self.journal.record_submit(jid, tenant, model, prompt,
+                                           eff_new, decode=decode, tag=tag,
+                                           session=session)
+            try:
+                req = self.sched.submit(
+                    prompt, max_new_tokens=eff_new, model=model,
+                    tenant=tenant, decode=decode, session=session,
+                    on_token=self._wrap_on_token(jid, cfg.slo, inst,
+                                                 on_token))
+            except BaseException as e:
+                # the scheduler refused it (infeasible prompt, too long):
+                # close the journal entry, or a restart would replay a
+                # request that can never be served — a poison pill
+                if self.journal is not None and jid is not None:
+                    self.journal.record_done(jid, ok=False,
+                                             error=type(e).__name__)
+                raise
+            req.jid = jid
+            ingress["rid"] = req.rid
+            version = key.split("@", 1)[-1] if "@" in key else "?"
+            self._m_requests.labels(tenant=tenant, model=model,
+                                    version=version, event="submitted").inc()
+            return req
 
     def generate(self, model: str, prompt, tenant: str = "default",
                  max_new: Optional[int] = None,

@@ -349,7 +349,7 @@ class _Lane:
     __slots__ = ("phase", "src", "s_true", "max_new", "enc_done",
                  "pending_chunk", "enc_table", "cross_table", "self_table",
                  "hashes", "hit_hashes", "inserted_hashes", "enc_owned",
-                 "cross_owned", "cur", "pos")
+                 "cross_owned", "cur", "pos", "rid")
 
     def __init__(self):
         self.reset()
@@ -371,6 +371,7 @@ class _Lane:
         self.cross_owned: List[int] = []
         self.cur = 0
         self.pos = 0
+        self.rid = None             # the scheduler's request id (tag_slot)
 
 
 class PagedTransformerGenerator:
@@ -727,6 +728,11 @@ class PagedTransformerGenerator:
         else:
             lane.phase = "prefill"
         return s_true
+
+    def tag_slot(self, slot: int, rid: int) -> None:
+        """Name the request a lane serves, so that the lane's own trace
+        instants (``lane/prefill_chunk``) join the request's timeline."""
+        self._lanes[slot].rid = rid
 
     def clear_slot(self, slot: int) -> None:
         """Retire a lane: release every page reference immediately.
@@ -1191,11 +1197,12 @@ class PagedTransformerGenerator:
         for slot, lane in enumerate(self._lanes):
             if lane.phase != "prefill":
                 continue
+            who = {} if lane.rid is None else {"rid": lane.rid}
             self._tracer.instant(
                 "lane/prefill_chunk", cat="serving", slot=slot,
                 tokens=lane.pending_chunk,
                 done=lane.enc_done + lane.pending_chunk,
-                total=lane.s_true)
+                total=lane.s_true, **who)
             lane.enc_done += lane.pending_chunk
             lane.pending_chunk = 0
             if lane.enc_done >= lane.s_true:
@@ -1208,29 +1215,35 @@ class PagedTransformerGenerator:
         B = self._slots
         if B == 0:
             raise RuntimeError("open_slots() before lane_step()")
-        feed = self._prefill_arrays()
-        dec = self._decode_arrays()
-        decoding: List[int] = []
-        for slot, lane in enumerate(self._lanes):
-            if lane.phase == "decode" and lane.self_table:
-                self._fill_decode_lane(dec, slot, lane, [lane.cur],
-                                       lane.pos)
-                decoding.append(slot)
-        prog, _, next_ids, _logits = self._unified
-        feed.update(dec)
-        with fluid.scope_guard(self.scope), self._mesh_ctx():
+        tr = self._tracer
+        with tr.span("engine/feed_build", cat="serving"):
+            feed = self._prefill_arrays()
+            dec = self._decode_arrays()
+            decoding: List[int] = []
+            for slot, lane in enumerate(self._lanes):
+                if lane.phase == "decode" and lane.self_table:
+                    self._fill_decode_lane(dec, slot, lane, [lane.cur],
+                                           lane.pos)
+                    decoding.append(slot)
+            prog, _, next_ids, _logits = self._unified
+            feed.update(dec)
+        with tr.span("engine/dispatch", cat="serving"), \
+                fluid.scope_guard(self.scope), self._mesh_ctx():
             nxt, = self.exe.run(prog, feed=feed, fetch_list=[next_ids],
                                 return_numpy=False, mode="infer")
-        ids = np.asarray(nxt).reshape(B)
+        with tr.span("engine/fetch", cat="serving"):
+            # the host blocks here until the device has finished the step
+            ids = np.asarray(nxt).reshape(B)
         self._steps += 1
-        self._absorb_prefill()
-        emitted: Dict[int, int] = {}
-        for slot, lane in enumerate(self._lanes):
-            if slot in decoding:
-                tok = int(ids[slot])
-                lane.cur = tok
-                lane.pos += 1
-                emitted[slot] = tok
+        with tr.span("engine/absorb", cat="serving"):
+            self._absorb_prefill()
+            emitted: Dict[int, int] = {}
+            for slot, lane in enumerate(self._lanes):
+                if slot in decoding:
+                    tok = int(ids[slot])
+                    lane.cur = tok
+                    lane.pos += 1
+                    emitted[slot] = tok
         return emitted
 
     # -- greedy --------------------------------------------------------------

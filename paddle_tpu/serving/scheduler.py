@@ -795,6 +795,9 @@ class ContinuousBatchingScheduler:
                             slot, req.src, max_new=req.max_new_tokens)
                 else:
                     s_true = group.model.admit_slot(slot, req.src)
+                tag = getattr(group.model, "tag_slot", None)
+                if callable(tag):       # the engine's instants carry rid
+                    tag(slot, req.rid)
             except BaseException as e:
                 # fail THIS request, give the slot back, keep serving —
                 # one bad prompt must not leak capacity or kill the loop
@@ -943,8 +946,11 @@ class ContinuousBatchingScheduler:
         self._tracer.instant("request/token", cat="serving", rid=req.rid,
                              index=len(req.tokens))
 
-    def _step_group(self, group: _LaneGroup, snap) -> None:
-        """One lockstep dispatch over ``group``'s lanes + retirement."""
+    def _step_group(self, group: _LaneGroup, snap, step: int) -> None:
+        """One lockstep dispatch over ``group``'s lanes + retirement.
+        ``step`` is the round's id (``step_once``): it rides both spans,
+        and everything the engine and the executor open under
+        ``scheduler/step`` inherits it."""
         if group.managed:
             # self-managed model: one dispatch interleaves chunked
             # prefill and decode over every lane; only lanes that
@@ -958,12 +964,14 @@ class ContinuousBatchingScheduler:
             # decoded them).
             try:
                 with self._tracer.span("scheduler/step", cat="serving",
-                                       managed=True, model=group.key):
+                                       managed=True, model=group.key,
+                                       step=step):
                     emitted = group.model.lane_step()
             except BaseException as e:
                 self._fail_group(group, e)
                 return
-            with self._lock:
+            with self._tracer.span("scheduler/deliver", cat="serving",
+                                   step=step), self._lock:
                 self._steps += 1
                 self._m_steps.inc()
                 for slot, toks in emitted.items():
@@ -984,12 +992,14 @@ class ContinuousBatchingScheduler:
         tokens, pos, src_len = snap
         try:
             with self._tracer.span("scheduler/step", cat="serving",
-                                   managed=False, model=group.key):
+                                   managed=False, model=group.key,
+                                   step=step):
                 nxt = group.model.step_slots(tokens, pos, src_len)
         except BaseException as e:
             self._fail_group(group, e)
             return
-        with self._lock:
+        with self._tracer.span("scheduler/deliver", cat="serving",
+                               step=step), self._lock:
             self._steps += 1
             self._m_steps.inc()
             for slot, req in list(group.active.items()):
@@ -1005,9 +1015,19 @@ class ContinuousBatchingScheduler:
     def step_once(self) -> bool:
         """Admit what fits, run ONE lockstep decode step per lane group
         with active lanes, retire finished lanes.  Returns False when
-        there was nothing to do."""
-        self._admit_pending()
-        with self._lock:
+        there was nothing to do.
+
+        The host's share of a round is spanned phase by phase, each span
+        carrying ``step`` (the step count as the round began): ``admit``,
+        ``plan`` (this method's locked part), ``step`` (the dispatch),
+        ``deliver`` (tokens out and retirement, locked) and
+        ``maintenance`` (the tier slice)."""
+        tr = self._tracer
+        step = self._steps
+        with tr.span("scheduler/admit", cat="serving", step=step):
+            self._admit_pending()
+        with tr.span("scheduler/plan", cat="serving", step=step), \
+                self._lock:
             self._reap_cancelled_locked()
             work = []
             maint = []
@@ -1035,17 +1055,19 @@ class ContinuousBatchingScheduler:
                 return False
         busy = bool(work)
         for group, snap in work:
-            self._step_group(group, snap)
+            self._step_group(group, snap, step)
         # the off-lock tier slice, AFTER stepping: pending suspends
         # spill to host/disk, queued-prompt chunks prefetch back, free
         # pages top up to the demote watermark.  Counted as progress so
         # the loop (and drain) keeps running until suspends complete.
-        for group, pre in maint:
-            try:
-                if group.model.tier_maintenance(prefetch=pre):
-                    busy = True
-            except BaseException:           # pragma: no cover - belt and
-                pass                        # braces; never kill the loop
+        if maint:
+            with tr.span("scheduler/maintenance", cat="serving", step=step):
+                for group, pre in maint:
+                    try:
+                        if group.model.tier_maintenance(prefetch=pre):
+                            busy = True
+                    except BaseException:   # pragma: no cover - belt and
+                        pass                # braces; never kill the loop
         return busy
 
     def _fail_group(self, group: _LaneGroup, exc: BaseException) -> None:
@@ -1096,7 +1118,10 @@ class ContinuousBatchingScheduler:
                     with self._work:
                         if not self._queue and not any(
                                 g.active for g in self._groups.values()):
-                            self._work.wait(timeout=0.05)
+                            # an idle chip with no traffic has this name
+                            with self._tracer.span("scheduler/wait",
+                                                   cat="serving"):
+                                self._work.wait(timeout=0.05)
 
         self._thread = threading.Thread(target=loop, daemon=True,
                                         name="serving-scheduler")

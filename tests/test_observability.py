@@ -607,3 +607,209 @@ def test_guardrail_counters_exported_on_recovery():
         r'paddle_guardrail_events_total\{event="skips"\} (\d+)', text)
     assert m and int(m.group(1)) >= 1
     assert len(tr.events("guard/skip")) == 1
+
+
+# -- one call site, two sinks; a cause on every span (ISSUE 25) ---------------
+
+def test_nested_spans_carry_parent_and_hand_down_rid_and_step():
+    tr = Tracer()
+    with tr.span("outer", step=7) as filled:
+        tr.instant("mark", rid=3)
+        with tr.span("inner"):
+            with tr.span("innermost", step=8):
+                tr.instant("deep")
+        filled["rid"] = 11                      # known only at the end
+    with tr.span("alone"):
+        pass
+    by = {e["name"]: e for e in tr.events()}
+    assert "parent" not in by["outer"] and "parent" not in by["alone"]
+    assert by["mark"]["parent"] == by["outer"]["id"]
+    assert by["inner"]["parent"] == by["outer"]["id"]
+    assert by["innermost"]["parent"] == by["inner"]["id"]
+    assert by["deep"]["parent"] == by["innermost"]["id"]
+    # step is handed down until a span sets its own; rid set late is the
+    # span's own and nobody else's
+    assert by["mark"]["args"] == {"rid": 3, "step": 7}
+    assert by["inner"]["args"] == {"step": 7}
+    assert by["deep"]["args"] == {"step": 8}
+    assert by["outer"]["args"] == {"step": 7, "rid": 11}
+    assert "args" not in by["alone"]
+    # a span takes its id when it opens: ids follow the starts
+    assert by["outer"]["id"] < by["inner"]["id"] < by["innermost"]["id"]
+    # another thread's spans are no parent of this thread's
+    seen = []
+
+    def other():
+        with tr.span("elsewhere"):
+            pass
+        seen.extend(tr.events("elsewhere"))
+
+    with tr.span("here"):
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+    assert "parent" not in seen[0]
+
+
+@pytest.fixture(scope="module")
+def tiny_paged():
+    from paddle_tpu.serving import PagedTransformerGenerator
+
+    gen = PagedTransformerGenerator(
+        24, 24, n_layer=2, n_head=2, d_key=4, d_value=4, d_model=16,
+        d_inner_hid=32, max_length=64, src_len=8, max_out_len=8,
+        page_size=4, chunk_size=4, num_pages=32, param_prefix="tfspan",
+        end_id=10 ** 6, place=fluid.CPUPlace())
+    gen.init_params(seed=5)
+    return gen
+
+
+def _serve_one(gen, max_new=3):
+    """One streamed request through the gateway's front door; returns its
+    rid once every token has been consumed."""
+    from paddle_tpu.serving.gateway import Gateway
+
+    gw = Gateway(n_slots=2, max_new_tokens=8)
+    gw.load_model("spans", "1", instance=gen, n_slots=2)
+    try:
+        stream = gw.submit_stream("spans", np.arange(2, 8), max_new=max_new)
+        gw.run_until_idle()
+        assert len(list(stream)) == max_new
+        return stream.request.rid
+    finally:
+        gw.unload_model("spans")
+
+
+def test_one_request_through_the_gateway_is_one_rid_in_order(tiny_paged):
+    tr = tracer()
+    tr.clear()
+    rid = _serve_one(tiny_paged)
+    mine = [e for e in tr.events()
+            if (e.get("args") or {}).get("rid") == rid]
+    first = {}
+    for e in sorted(mine, key=lambda e: e["ts"]):
+        first.setdefault(e["name"], e)
+    order = ["gateway/ingress", "request/submitted", "request/admitted",
+             "lane/prefill_chunk", "request/token", "gateway/first_chunk"]
+    assert [n for n in first if n in order] == order
+    # once per request, nothing per token
+    assert len(tr.events("gateway/ingress")) == 1
+    assert len(tr.events("gateway/first_chunk")) == 1
+    # the scheduler's instant was emitted under the gateway's span
+    assert first["request/submitted"]["parent"] == \
+        first["gateway/ingress"]["id"]
+
+
+def test_serve_step_spans_share_step_and_only_nest(tiny_paged):
+    tr = tracer()
+    tr.clear()
+    _serve_one(tiny_paged, max_new=4)
+    spans = [e for e in tr.events() if e["ph"] == "X" and e["name"].startswith(
+        ("scheduler/", "engine/", "executor"))]
+    by_step = {}
+    for e in spans:                 # load and warm-up run outside any step
+        if "step" in (e.get("args") or {}):
+            by_step.setdefault(e["args"]["step"], []).append(e)
+    stepped = {s: evs for s, evs in by_step.items()
+               if any(e["name"] == "scheduler/step" for e in evs)}
+    assert len(stepped) >= 4                    # 2 prefill chunks + decode
+    for step, evs in stepped.items():
+        names = [e["name"] for e in evs]
+        for want in ("scheduler/admit", "scheduler/plan", "scheduler/step",
+                     "scheduler/deliver", "scheduler/maintenance",
+                     "engine/feed_build", "engine/dispatch", "engine/fetch",
+                     "engine/absorb", "executor/prepare",
+                     "executor_step/infer", "executor/writeback"):
+            assert names.count(want) == 1, (step, want, names)
+        assert "executor/compile" not in names or step == min(stepped)
+        ids = {e["id"]: e for e in evs}
+        for e in evs:
+            for o in evs:
+                if o is e or o["ts"] < e["ts"]:
+                    continue
+                a0, a1 = e["ts"], e["ts"] + e["dur"]
+                b0, b1 = o["ts"], o["ts"] + o["dur"]
+                # o starts inside e: then it ends inside e, and says so
+                if b0 < a1:
+                    assert b1 <= a1 + 1e-3, (e["name"], o["name"])
+                    up = o
+                    while up.get("parent") in ids and up is not e:
+                        up = ids[up["parent"]]
+                    assert up is e, (e["name"], o["name"])
+        under = {e["name"]: ids[e["parent"]]["name"] for e in evs
+                 if e.get("parent") in ids}
+        assert under["engine/feed_build"] == "scheduler/step"
+        assert under["executor/prepare"] == "engine/dispatch"
+        assert under["executor_step/infer"] == "engine/dispatch"
+
+
+def _host_span_names(trace_dir):
+    import glob
+
+    import jax
+
+    (path,) = glob.glob(str(trace_dir / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    return {e.name for p in data.planes if p.name.startswith("/host:CPU")
+            for ln in p.lines for e in ln.events}
+
+
+def _profiled(trace_dir, body):
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_span_names(trace_dir)
+
+
+def test_profiler_session_holds_the_programs_spans(tiny_paged, tmp_path):
+    """The second sink: the same ``with`` puts the span on the host plane
+    of an open ``jax.profiler`` session, under its own name."""
+    names = _profiled(tmp_path, lambda: _serve_one(tiny_paged))
+    assert {"gateway/ingress", "scheduler/step", "scheduler/deliver",
+            "engine/feed_build", "engine/fetch", "executor/prepare",
+            "executor_step/infer", "executor/writeback"} <= names
+    # instants and retrospective events stay in the ring alone
+    assert not {"request/token", "request"} & names
+
+
+@pytest.mark.parametrize("enabled, session", [(True, False), (False, False),
+                                              (False, True)])
+def test_span_body_runs_whatever_the_sinks(tmp_path, enabled, session):
+    tr = Tracer(enabled=enabled)
+    ran = []
+
+    def body():
+        with tr.span("sink-check", n=1) as filled:
+            filled["more"] = 2
+            ran.append(True)
+
+    names = _profiled(tmp_path, body) if session else (body() or set())
+    assert ran == [True]
+    if enabled:
+        (ev,) = tr.events()
+        assert ev["name"] == "sink-check" and ev["args"] == {"n": 1,
+                                                            "more": 2}
+    else:                                       # disable(): both sinks off
+        assert tr.events() == [] and "sink-check" not in names
+
+
+def test_ring_and_harness_clocks_are_one():
+    """The ring stamps ``perf_counter``, the benchmark's windows
+    ``monotonic``: the per-layer readers compare them directly."""
+    import time
+
+    gaps = []
+    for _ in range(5):
+        a = time.monotonic()
+        b = time.perf_counter()
+        c = time.monotonic()
+        gaps.append(abs(b - 0.5 * (a + c)))
+    assert min(gaps) < 1e-3
