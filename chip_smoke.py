@@ -223,12 +223,12 @@ def phase_kernels(cfg, pallas_impl: str):
         scales = None
         if dtype == "int8":
             pool = jnp.asarray(
-                rng.randint(-127, 128, (h, rows, ps, d)).astype(np.int8))
+                rng.randint(-127, 128, (rows, ps, h * d)).astype(np.int8))
             scales = jnp.asarray(
                 (rng.rand(1, rows, ps).astype(np.float32) + 0.5) / 127.0)
         else:
             pool = jnp.asarray(
-                rng.randn(h, rows, ps, d).astype(np.float32)).astype(dtype)
+                rng.randn(rows, ps, h * d).astype(np.float32)).astype(dtype)
         for c in (1, s["chunk_size"]):
             q = jnp.asarray(rng.randn(b, c, h, d).astype(np.float32))
             # lane 0 is dead; the others end mid-page, full, and short
@@ -478,8 +478,8 @@ def mesh_serve(cfg, on_chip: bool, served, four):
         pools[name] = np.asarray(pool).astype(np.float32)
     # inst, src and pool are now the tensor-parallel load's
     check(set(pool.sharding.device_set) == four and
-          pool.addressable_shards[0].data.shape[0]
-          == cfg["model"]["n_head"] // 4,
+          pool.addressable_shards[0].data.shape[-1]
+          == cfg["model"]["n_head"] * cfg["model"]["d_key"] // 4,
           "multichip serve: pool not split by heads over four devices")
     check(first["tp"] == first["one"] == served_first,
           f"multichip serve: first tokens {first} vs the served "

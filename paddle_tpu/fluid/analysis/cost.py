@@ -570,15 +570,16 @@ def _decode_attention_cost(od, env):
 
 
 def _pool_geometry(env, od):
-    """(n_head, page_size, d_head, itemsize) from the Pool input."""
-    ps = env.shape((od.inputs.get("Pool") or [""])[0]) or [1, 1, 1, 1]
+    """(page_size, n_head * d_head, itemsize) from the Pool input
+    [R, page_size, n_head * d_head]."""
+    ps = env.shape((od.inputs.get("Pool") or [""])[0]) or [1, 1, 1]
     item = env.itemsize((od.inputs.get("Pool") or [""])[0])
-    return ps[0], ps[2], ps[3], item
+    return ps[1], ps[2], item
 
 
 @cost_rule("paged_cache_write")
 def _paged_write_cost(od, env):
-    _, _, _, item = _pool_geometry(env, od)
+    _, _, item = _pool_geometry(env, od)
     toks = env.slot_bytes(od, "K") + env.slot_bytes(od, "V")
     written = (sum(env.elems(n) for n in od.inputs.get("K", []) if n)
                + sum(env.elems(n) for n in od.inputs.get("V", []) if n)) \
@@ -602,16 +603,16 @@ def _qpaged_write_cost(od, env):
 
 @cost_rule("ragged_decode_attention")
 def _ragged_attention_cost(od, env):
-    h, page, d, item = _pool_geometry(env, od)
-    q = env.shape((od.inputs.get("Q") or [""])[0]) or [1, 1, h, d]
+    page, hd, item = _pool_geometry(env, od)
+    q = env.shape((od.inputs.get("Q") or [""])[0]) or [1, 1]
     pt = env.shape((od.inputs.get("PageTable") or [""])[0]) or [1, 1]
     b, c = q[0], q[1] if len(q) >= 2 else 1
     p = pt[-1]
     lmax = p * page                         # static page-table capacity
-    flops = 4.0 * b * c * h * lmax * d
+    flops = 4.0 * b * c * hd * lmax
     # the pool pages a lane's table can address, K+V, plus the int8
     # pool's fp32 block-scale sidecar rows when present
-    reads = 2.0 * b * p * page * h * d * item + env.slot_bytes(od, "Q") \
+    reads = 2.0 * b * p * page * hd * item + env.slot_bytes(od, "Q") \
         + env.slot_bytes(od, "PageTable") + env.slot_bytes(od, "Lengths")
     if od.inputs.get("Scales"):
         reads += 2.0 * b * p * page * 4
@@ -620,11 +621,11 @@ def _ragged_attention_cost(od, env):
 
 @cost_rule("paged_page_copy", "quantized_paged_page_copy")
 def _page_copy_cost(od, env):
-    h, page, d, item = _pool_geometry(env, od)
+    page, hd, item = _pool_geometry(env, od)
     n_layer = max(1, int(od.attrs.get("n_layer", 1)))
     src = env.shape((od.inputs.get("Src") or [""])[0]) or [1]
     b = _prod(src)
-    page_bytes = 2 * n_layer * page * h * d * item
+    page_bytes = 2 * n_layer * page * hd * item
     moved = float(b * page_bytes)
     if od.inputs.get("Scales"):
         moved += b * 2 * n_layer * page * 4
@@ -637,11 +638,11 @@ def _page_xfer_cost(od, env):
     """Tier transfers move W whole pages (all layers, K+V) between the
     pool and a dense slab — pure bandwidth, zero flops; the int8 pool's
     fp32 scale sidecar rides the same rows."""
-    h, page, d, item = _pool_geometry(env, od)
+    page, hd, item = _pool_geometry(env, od)
     n_layer = max(1, int(od.attrs.get("n_layer", 1)))
     pages = env.shape((od.inputs.get("Pages") or [""])[0]) or [1]
     w = _prod(pages)
-    moved = float(w * 2 * n_layer * page * h * d * item)
+    moved = float(w * 2 * n_layer * page * hd * item)
     if od.inputs.get("Scales"):
         moved += w * 2 * n_layer * page * 4
     return OpCost(0.0, moved, moved)

@@ -1059,19 +1059,20 @@ def _r_attention(ctx: _OpCtx) -> None:
 
 @prop_rule("paged_cache_write", "quantized_paged_cache_write")
 def _r_paged_write(ctx: _OpCtx) -> None:
-    """The pool is [heads, pages, page, d]; K/V updates are
-    [lanes, t, heads, d].  The head axis must agree — a head-sharded
-    pool written from a differently-sharded K forces an all-to-all."""
+    """The pool is [rows, page, heads*d] (its minor axis shards by
+    whole heads); K/V updates are [lanes, t, heads, d].  The head axis
+    must agree — a head-sharded pool written from a differently-sharded
+    K forces an all-to-all."""
     pool = ctx.first("Pool")
     ps = ctx.spec(pool) if pool else ()
     for kn in (ctx.first("K"), ctx.first("V")):
         if kn is None:
             continue
         ks = ctx.spec(kn)
-        if len(ks) > 2 and ks[2] and ps and ps[0] and ks[2] != ps[0]:
+        if len(ks) > 2 and ks[2] and ps and ps[-1] and ks[2] != ps[-1]:
             ctx.hazard(ALL_TO_ALL, ks[2], kn,
                        f"KV update head dim sharded '{ks[2]}' but the "
-                       f"pool's head dim is '{ps[0]}'", slot="K#0")
+                       f"pool's head dim is '{ps[-1]}'", slot="K#0")
     scales = ctx.first("Scales")
     for slot, pos, name in ctx.op.writes:
         if slot == "ScalesOut" and scales is not None:
@@ -1087,9 +1088,9 @@ def _r_ragged_attention(ctx: _OpCtx) -> None:
     qs = ctx.spec(q) if q else ()
     ps = ctx.spec(pool) if pool else ()
     # Q's head dim is rank-2 ([lanes, heads, d] / [lanes, t, heads, d])
-    if len(qs) >= 2 and ps and ps[0] and qs[-2] and qs[-2] != ps[0]:
-        ctx.hazard(ALL_TO_ALL, ps[0], pool,
-                   f"pool head dim sharded '{ps[0]}' but Q's head dim "
+    if len(qs) >= 2 and ps and ps[-1] and qs[-2] and qs[-2] != ps[-1]:
+        ctx.hazard(ALL_TO_ALL, ps[-1], pool,
+                   f"pool head dim sharded '{ps[-1]}' but Q's head dim "
                    f"is '{qs[-2]}'", slot="Pool#0")
     for slot, pos, name in ctx.op.writes:
         ctx.set_out(name, ctx.fit(q, name) if q else (),
@@ -1108,10 +1109,10 @@ def _r_page_copy(ctx: _OpCtx) -> None:
 
 @prop_rule("paged_page_gather", "quantized_paged_page_gather")
 def _r_page_gather(ctx: _OpCtx) -> None:
-    """KV-tier download: the slab is pool rows restacked on a page
-    axis — [h, W*2L, ps, d] has the pool's rank and head-leading
-    layout, so Out keeps the pool's sharding and the scale slab
-    mirrors the scales sidecar."""
+    """KV-tier download: the slab is whole pool rows —
+    [W*2L, ps, h*d] has the pool's rank and its heads-minor layout, so
+    Out keeps the pool's sharding and the scale slab mirrors the scales
+    sidecar."""
     pool = ctx.first("Pool")
     scales = ctx.first("Scales")
     for slot, pos, name in ctx.op.writes:
@@ -1130,10 +1131,10 @@ def _r_page_scatter(ctx: _OpCtx) -> None:
     data = ctx.first("Data")
     if data is not None:
         ds = ctx.spec(data)
-        if ps and ps[0] and ds and ds[0] and ds[0] != ps[0]:
-            ctx.hazard(ALL_TO_ALL, ps[0], data,
-                       f"upload slab head dim sharded '{ds[0]}' but the "
-                       f"pool's head dim is '{ps[0]}'", slot="Data#0")
+        if ps and ps[-1] and ds and ds[-1] and ds[-1] != ps[-1]:
+            ctx.hazard(ALL_TO_ALL, ps[-1], data,
+                       f"upload slab head dim sharded '{ds[-1]}' but the "
+                       f"pool's head dim is '{ps[-1]}'", slot="Data#0")
     scales = ctx.first("Scales")
     for slot, pos, name in ctx.op.writes:
         src = scales if slot == "ScalesOut" else pool

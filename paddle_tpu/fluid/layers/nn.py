@@ -889,7 +889,7 @@ def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
     walking each lane's page list (ops/cache_ops.ragged_decode_attention;
     the Pallas kernel lives in kernels/flash_attention).  q [B, C, H, D]
     (C=1 steady-state decode, C=chunk during chunked prefill), pool
-    [H, R, page_size, D], page_table [B, P] int32 logical pages, lengths
+    [R, page_size, H*D], page_table [B, P] int32 logical pages, lengths
     [B] int32 live positions, q_base [B] int32 global query start
     (required when causal).  ``scales`` ([1, R, page_size] fp32) rides
     along for int8 pools — K/V dequantize in-register during the walk."""

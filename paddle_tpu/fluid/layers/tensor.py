@@ -179,7 +179,7 @@ def paged_page_copy(pool, src, dst, n_layer, out=None, scales=None,
 
 def paged_page_gather(pool, pages, n_layer, scales=None):
     """Gather W whole logical pages out of the paged pool as a dense
-    [H, W*2L, page_size, D] slab — the device side of a KV-tier download
+    [W*2L, page_size, H*D] slab — the device side of a KV-tier download
     (ops/cache_ops.paged_page_gather).  ``pages`` [W] int32 is DATA;
     short transfers pad with the trash page.  Pass the int8 pool's
     ``scales`` sidecar to gather the fp32 block scales with the bytes;

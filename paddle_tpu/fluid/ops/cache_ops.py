@@ -76,13 +76,43 @@ def decode_attention(ctx, q, k_cache, v_cache, lengths):
 
 # ---------------------------------------------------------------------------
 # Paged KV-cache ops (ISSUE 6).  The pool is ONE persistable tensor
-# [H, R, page_size, D]; a *logical* page spans every layer and K+V of a
-# page_size-token span (physical row = (page*n_layer + layer)*2 (+1 for
-# V) — kernels/flash_attention.paged_kv_rows is the single source of
-# truth for that arithmetic).  Logical page 0 is the reserved trash page
-# dead lanes write into, so one compiled program serves any mix of
-# prefilling / decoding / idle lanes without recompiling.
+# [R, page_size, H*D], token-major with the heads in the minor dim; a
+# *logical* page spans every layer and K+V of a page_size-token span
+# (physical row = (page*n_layer + layer)*2 (+1 for V) —
+# kernels/flash_attention.paged_kv_rows is the single source of truth
+# for that arithmetic).  Every op below indexes the pool's LEADING axes
+# only, so under donation each is an in-place row update and no
+# compiled step holds a second buffer of pool size.  Logical page 0 is
+# the reserved trash page dead lanes write into, so one compiled
+# program serves any mix of prefilling / decoding / idle lanes without
+# recompiling.
 # ---------------------------------------------------------------------------
+
+
+def _token_writes(pages, offsets, k, v):
+    """Normalise a write op's feeds to ``pages``/``offsets`` [B, C] and
+    ``k``/``v`` [B, C, H, D] (a decode step may pass one token a lane
+    without the C axis)."""
+    pages = jnp.asarray(pages).astype(jnp.int32)
+    offsets = jnp.asarray(offsets).astype(jnp.int32)
+    if pages.ndim == 1:               # one token per lane (decode step)
+        pages = pages[:, None]
+        offsets = offsets[:, None]
+        k = k if k.ndim == 4 else k[:, None]
+        v = v if v.ndim == 4 else v[:, None]
+    return pages, offsets, k, v
+
+
+def _scatter_tokens(pool, rows, offsets, val):
+    """pool[rows[b,c], offsets[b,c], :] <- val[b,c,:,:] as ONE
+    leading-axis row scatter on the [R*page_size, H*D] view of the pool
+    (a bitcast): the embedding-table update XLA performs in place on a
+    donated operand."""
+    r, ps, hd = pool.shape
+    flat = (rows * ps + offsets).reshape(-1)
+    out = pool.reshape(r * ps, hd).at[flat].set(
+        val.astype(pool.dtype).reshape(-1, hd))
+    return out.reshape(r, ps, hd)
 
 
 @primitive("paged_cache_write",
@@ -100,21 +130,11 @@ def paged_cache_write(ctx, pool, k, v, pages, offsets):
     never recompiles."""
     from ...kernels.flash_attention import paged_kv_rows
 
-    layer = int(ctx.attr("layer", 0))
-    n_layer = int(ctx.attr("n_layer", 1))
-    pages = jnp.asarray(pages).astype(jnp.int32)
-    offsets = jnp.asarray(offsets).astype(jnp.int32)
-    if pages.ndim == 1:               # one token per lane (decode step)
-        pages = pages[:, None]
-        offsets = offsets[:, None]
-        k = k if k.ndim == 4 else k[:, None]
-        v = v if v.ndim == 4 else v[:, None]
-    k_rows, v_rows = paged_kv_rows(pages, layer, n_layer)
-    # pool[h, rows[b,c], offs[b,c]] <- value[b,c,h,:]  (head-major pool)
-    kt = jnp.transpose(k.astype(pool.dtype), (2, 0, 1, 3))
-    vt = jnp.transpose(v.astype(pool.dtype), (2, 0, 1, 3))
-    pool = pool.at[:, k_rows, offsets].set(kt)
-    return pool.at[:, v_rows, offsets].set(vt)
+    pages, offsets, k, v = _token_writes(pages, offsets, k, v)
+    k_rows, v_rows = paged_kv_rows(pages, int(ctx.attr("layer", 0)),
+                                   int(ctx.attr("n_layer", 1)))
+    pool = _scatter_tokens(pool, k_rows, offsets, k)
+    return _scatter_tokens(pool, v_rows, offsets, v)
 
 
 @primitive("quantized_paged_cache_write",
@@ -131,29 +151,21 @@ def quantized_paged_cache_write(ctx, pool, scales, k, v, pages, offsets):
     from ...kernels.flash_attention import paged_kv_rows
     from .quant_ops import abs_max_scale, quantize_array
 
-    layer = int(ctx.attr("layer", 0))
-    n_layer = int(ctx.attr("n_layer", 1))
-    pages = jnp.asarray(pages).astype(jnp.int32)
-    offsets = jnp.asarray(offsets).astype(jnp.int32)
-    if pages.ndim == 1:               # one token per lane (decode step)
-        pages = pages[:, None]
-        offsets = offsets[:, None]
-        k = k if k.ndim == 4 else k[:, None]
-        v = v if v.ndim == 4 else v[:, None]
-    k_rows, v_rows = paged_kv_rows(pages, layer, n_layer)
+    pages, offsets, k, v = _token_writes(pages, offsets, k, v)
+    k_rows, v_rows = paged_kv_rows(pages, int(ctx.attr("layer", 0)),
+                                   int(ctx.attr("n_layer", 1)))
 
     def tok_quant(val):
-        """[B, C, H, D] float -> (int8 [H, B, C, D], scale [B, C]) via
+        """[B, C, H, D] float -> (int8 [B, C, H, D], scale [B, C]) via
         quant_ops' shared max-abs rule (one block scale per token)."""
         vf = val.astype(jnp.float32)
         sc = abs_max_scale(vf, axis=(0, 1))                 # [B, C]
-        q = quantize_array(vf, sc, axis=(0, 1))
-        return jnp.transpose(q.astype(pool.dtype), (2, 0, 1, 3)), sc
+        return quantize_array(vf, sc, axis=(0, 1)), sc
 
     kq, ks = tok_quant(k)
     vq, vs = tok_quant(v)
-    pool = pool.at[:, k_rows, offsets].set(kq)
-    pool = pool.at[:, v_rows, offsets].set(vq)
+    pool = _scatter_tokens(pool, k_rows, offsets, kq)
+    pool = _scatter_tokens(pool, v_rows, offsets, vq)
     scales = scales.at[0, k_rows, offsets].set(ks)
     scales = scales.at[0, v_rows, offsets].set(vs)
     return pool, scales
@@ -166,7 +178,7 @@ def ragged_decode_attention(ctx, q, pool, page_table, lengths, q_base,
                             scales):
     """Per-lane attention over the lane's page list — see
     kernels/flash_attention.ragged_decode_attention (q [B, C, H, D],
-    pool [H, R, page_size, D], page_table [B, P] int32 logical pages,
+    pool [R, page_size, H*D], page_table [B, P] int32 logical pages,
     lengths [B], optional q_base [B] for causal chunk queries, optional
     Scales [1, R, page_size] fp32 block scales for an int8 pool).  Under
     an active mesh (tensor-parallel serving) the kernel maps over the
@@ -190,12 +202,12 @@ def ragged_decode_attention(ctx, q, pool, page_table, lengths, q_base,
     return _ra(q, pool, page_table, lengths, q_base, **kw)
 
 
-def _page_copy_rows(src, dst, n_layer):
-    src = jnp.asarray(src).astype(jnp.int32).reshape(-1)
-    dst = jnp.asarray(dst).astype(jnp.int32).reshape(-1)
+def _page_rows(pages, n_layer):
+    """Logical pages [N] -> their physical rows [N, 2L] (all layers, K
+    and V: ``paged_kv_rows`` for every layer at once)."""
+    pages = jnp.asarray(pages).astype(jnp.int32).reshape(-1)
     span = jnp.arange(2 * n_layer, dtype=jnp.int32)[None, :]
-    return (src[:, None] * (2 * n_layer) + span,          # [B, 2L]
-            dst[:, None] * (2 * n_layer) + span)
+    return pages[:, None] * (2 * n_layer) + span
 
 
 @primitive("paged_page_copy", inputs=["Pool", "Src", "Dst"],
@@ -206,9 +218,9 @@ def paged_page_copy(ctx, pool, src, dst):
     parent's partially-filled page get their own copy IN the step
     dispatch before writing.  ``src == dst`` rows are identity writes
     (the no-op encoding for lanes that don't need a copy this step)."""
-    src_rows, dst_rows = _page_copy_rows(src, dst,
-                                         int(ctx.attr("n_layer", 1)))
-    return pool.at[:, dst_rows].set(pool[:, src_rows])
+    n_layer = int(ctx.attr("n_layer", 1))
+    src_rows, dst_rows = _page_rows(src, n_layer), _page_rows(dst, n_layer)
+    return pool.at[dst_rows].set(pool[src_rows])
 
 
 @primitive("quantized_paged_page_copy",
@@ -219,9 +231,9 @@ def quantized_paged_page_copy(ctx, pool, scales, src, dst):
     the SAME physical-row move the int8 bytes do — a copied page is
     bit-identical to its parent, scales included, so copy-on-write
     never changes what a beam lane dequantizes."""
-    src_rows, dst_rows = _page_copy_rows(src, dst,
-                                         int(ctx.attr("n_layer", 1)))
-    pool = pool.at[:, dst_rows].set(pool[:, src_rows])
+    n_layer = int(ctx.attr("n_layer", 1))
+    src_rows, dst_rows = _page_rows(src, n_layer), _page_rows(dst, n_layer)
+    pool = pool.at[dst_rows].set(pool[src_rows])
     scales = scales.at[:, dst_rows].set(scales[:, src_rows])
     return pool, scales
 
@@ -229,7 +241,7 @@ def quantized_paged_page_copy(ctx, pool, scales, src, dst):
 # ---------------------------------------------------------------------------
 # Tiered-KV transfer ops (ISSUE 20).  The device half of host-RAM page
 # demotion: gather pulls whole logical pages out of the pool as a dense
-# [H, W*2L, page_size, D] slab the host fetches (device->host), scatter
+# [W*2L, page_size, H*D] slab the host fetches (device->host), scatter
 # writes such a slab back into fresh pages (host->device).  W is FIXED
 # per compiled program (short transfers pad with the trash page), and
 # the page lists are int32 DATA — so the whole tier machinery compiles
@@ -241,28 +253,22 @@ def quantized_paged_page_copy(ctx, pool, scales, src, dst):
            outputs=["Out"], no_grad=True)
 def paged_page_gather(ctx, pool, pages):
     """Gather W whole logical pages (all layers, K and V) into a dense
-    slab [H, W*2L, page_size, D] for host download.  ``pages`` [W] int32
+    slab [W*2L, page_size, H*D] for host download.  ``pages`` [W] int32
     logical page ids; trash-page entries gather junk the host side
     ignores (the fixed-width padding encoding)."""
-    n_layer = int(ctx.attr("n_layer", 1))
-    pages = jnp.asarray(pages).astype(jnp.int32).reshape(-1)
-    span = jnp.arange(2 * n_layer, dtype=jnp.int32)[None, :]
-    rows = (pages[:, None] * (2 * n_layer) + span).reshape(-1)  # [W*2L]
-    return pool[:, rows]
+    rows = _page_rows(pages, int(ctx.attr("n_layer", 1))).reshape(-1)
+    return pool[rows]
 
 
 @primitive("paged_page_scatter", inputs=["Pool", "Data", "Pages"],
            outputs=["Out"], no_grad=True)
 def paged_page_scatter(ctx, pool, data, pages):
-    """Scatter a gathered slab [H, W*2L, page_size, D] back into the
+    """Scatter a gathered slab [W*2L, page_size, H*D] back into the
     pool at W logical pages — the host->device upload of a promoted or
     resumed page.  Out aliases Pool (the cache_write ParamOut idiom);
     trash-page entries absorb the padding rows harmlessly."""
-    n_layer = int(ctx.attr("n_layer", 1))
-    pages = jnp.asarray(pages).astype(jnp.int32).reshape(-1)
-    span = jnp.arange(2 * n_layer, dtype=jnp.int32)[None, :]
-    rows = (pages[:, None] * (2 * n_layer) + span).reshape(-1)
-    return pool.at[:, rows].set(data.astype(pool.dtype))
+    rows = _page_rows(pages, int(ctx.attr("n_layer", 1))).reshape(-1)
+    return pool.at[rows].set(data.astype(pool.dtype))
 
 
 @primitive("quantized_paged_page_gather", inputs=["Pool", "Scales", "Pages"],
@@ -271,11 +277,8 @@ def quantized_paged_page_gather(ctx, pool, scales, pages):
     """``paged_page_gather`` for an int8 pool: the fp32 block-scale
     sidecar rows travel WITH the int8 bytes (same physical rows), so a
     demoted page carries everything needed to dequantize after resume."""
-    n_layer = int(ctx.attr("n_layer", 1))
-    pages = jnp.asarray(pages).astype(jnp.int32).reshape(-1)
-    span = jnp.arange(2 * n_layer, dtype=jnp.int32)[None, :]
-    rows = (pages[:, None] * (2 * n_layer) + span).reshape(-1)
-    return pool[:, rows], scales[:, rows]
+    rows = _page_rows(pages, int(ctx.attr("n_layer", 1))).reshape(-1)
+    return pool[rows], scales[:, rows]
 
 
 @primitive("quantized_paged_page_scatter",
@@ -285,10 +288,7 @@ def quantized_paged_page_scatter(ctx, pool, scales, data, scale_data, pages):
     """``paged_page_scatter`` for an int8 pool: re-installs the int8
     bytes AND their fp32 block scales at the same physical rows —
     a promoted chunk dequantizes bit-identically to pre-demotion."""
-    n_layer = int(ctx.attr("n_layer", 1))
-    pages = jnp.asarray(pages).astype(jnp.int32).reshape(-1)
-    span = jnp.arange(2 * n_layer, dtype=jnp.int32)[None, :]
-    rows = (pages[:, None] * (2 * n_layer) + span).reshape(-1)
-    pool = pool.at[:, rows].set(data.astype(pool.dtype))
+    rows = _page_rows(pages, int(ctx.attr("n_layer", 1))).reshape(-1)
+    pool = pool.at[rows].set(data.astype(pool.dtype))
     scales = scales.at[:, rows].set(scale_data.astype(scales.dtype))
     return pool, scales

@@ -99,13 +99,16 @@ def decode_attention(q, k_cache, v_cache, lengths,
 # Ragged paged decode attention (serving paged-KV hot path)
 # ---------------------------------------------------------------------------
 #
-# The paged KV pool is ONE persistable tensor [H, R, page_size, D]
-# (head-major — the layout the TPU paged-attention kernels index, so a
-# one-page block's trailing dims are (page_size, D), never a sub-lane
-# (1, d) tile).  A *logical* page spans every layer and both K and V of
-# a page_size-token span: physical row = (page * n_layer + layer) * 2
-# (+1 for V).  Per-request block tables hold logical page ids; row 0's
-# logical page 0 is the reserved trash page dead lanes write into.
+# The paged KV pool is ONE persistable tensor [R, page_size, H*D]:
+# token-major, every head of a token side by side in the minor dim, the
+# layout the K/V projections produce.  A page is one contiguous
+# [page_size, H*D] block (one DMA), a new token is one row of the
+# [R*page_size, H*D] view (an in-place row update under donation), and
+# the minor dim is lane-dense.  A *logical* page spans every layer and
+# both K and V of a page_size-token span: physical row =
+# (page * n_layer + layer) * 2 (+1 for V).  Per-request block tables
+# hold logical page ids; row 0's logical page 0 is the reserved trash
+# page dead lanes write into.
 
 
 def paged_kv_rows(page_table, layer: int, n_layer: int):
@@ -117,15 +120,19 @@ def paged_kv_rows(page_table, layer: int, n_layer: int):
     return base, base + 1
 
 
-def _ragged_mask(scores, lengths_b, base_b, p0, n_cols, causal, c):
-    """[C, n_cols] additive mask for global key positions p0..p0+n_cols
-    against live length ``lengths_b`` and (optionally) causal position
-    ``base_b + row``."""
-    cols = p0 + jax.lax.broadcasted_iota(jnp.int32, (c, n_cols), 1)
+def _ragged_mask(scores, lengths_b, base_b, p0, causal, c):
+    """Additive mask of a [rows, n_cols] score tile for global key
+    positions p0..p0+n_cols against live length ``lengths_b`` and
+    (optionally) the causal position ``base_b + query``; row r holds
+    query ``r % c`` (several heads' C queries may be stacked)."""
+    shape = scores.shape
+    cols = p0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     keep = cols < lengths_b
     if causal:
-        rows = base_b + jax.lax.broadcasted_iota(jnp.int32, (c, n_cols), 0)
-        keep = jnp.logical_and(keep, cols <= rows)
+        rows = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        if shape[0] != c:
+            rows = jax.lax.rem(rows, jnp.int32(c))
+        keep = jnp.logical_and(keep, cols <= base_b + rows)
     return jnp.where(keep, scores, -1e9)
 
 
@@ -136,20 +143,20 @@ def _ragged_xla(q, pool, page_table, lengths, q_base, layer, n_layer,
     int8 pool dequantizes right after the gather (``scales`` holds one
     fp32 scale per (row, slot) block) — HBM moved int8 bytes; the f32
     view exists only as a fused register-level convert."""
-    h, _r, ps, d = pool.shape
-    b, c, _h, _d = q.shape
+    _r, ps, _hd = pool.shape
+    b, c, h, d = q.shape
     n_pages = page_table.shape[1]
     k_rows, v_rows = paged_kv_rows(page_table, layer, n_layer)
-    k = pool[:, k_rows]                       # [h, B, P, ps, d]
-    v = pool[:, v_rows]
+    k = pool[k_rows].reshape(b, n_pages, ps, h, d)
+    v = pool[v_rows].reshape(b, n_pages, ps, h, d)
     if scales is not None:
         sc = scales.reshape(scales.shape[-2], scales.shape[-1])  # [R, ps]
-        k = k.astype(jnp.float32) * sc[k_rows][None, :, :, :, None]
-        v = v.astype(jnp.float32) * sc[v_rows][None, :, :, :, None]
+        k = k.astype(jnp.float32) * sc[k_rows][..., None, None]
+        v = v.astype(jnp.float32) * sc[v_rows][..., None, None]
     elif k.dtype != q.dtype:          # bf16 pool: upcast like the Pallas
         k = k.astype(q.dtype)         # kernel so probs stay full precision
         v = v.astype(q.dtype)         # (probs.astype(v.dtype) below)
-    scores = jnp.einsum("bqhd,hbpsd->bhqps", q, k,
+    scores = jnp.einsum("bqhd,bpshd->bhqps", q, k,
                         preferred_element_type=jnp.float32)
     scores = scores.reshape(b, h, c, n_pages * ps).astype(jnp.float32)
     scores = scores * jnp.float32(sm_scale)
@@ -171,26 +178,45 @@ def _ragged_xla(q, pool, page_table, lengths, q_base, layer, n_layer,
     dead = jnp.logical_not(keep.any(axis=-1))                     # [B,?,C]
     probs = jnp.where(dead[..., None], 0.0, probs)
     probs = probs.reshape(b, h, c, n_pages, ps)
-    ctx = jnp.einsum("bhqps,hbpsd->bqhd", probs.astype(v.dtype), v,
+    ctx = jnp.einsum("bhqps,bpshd->bqhd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     return ctx.astype(q.dtype)
 
 
+def _lane_group(h: int, d: int) -> int:
+    """Width of the lane slices the ragged kernel cuts a [*, h*d] pool
+    row into.  Mosaic slices lanes at multiples of 128 only, so heads
+    narrower than that share a group: ``d`` itself when it fills whole
+    128-lane tiles, else 128 when the heads pack evenly into it, else
+    the whole row (shapes below one tile: the tests' sizes)."""
+    if d % LANES == 0:
+        return d
+    if (h * d) % LANES == 0 and LANES % d == 0:
+        return LANES
+    return h * d
+
+
 def _ragged_kernel(krows_ref, vrows_ref, meta_ref, q_ref, k_ref, v_ref,
                    ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr,
-                   *, h, c, ps, n_pages, causal, sm_scale):
+                   *, n_groups, rows, c, ps, n_pages, causal, sm_scale):
     """grid (B, P): per lane, walk its page list (scalar-prefetched
     block table drives the k/v index maps) with an online softmax.
-    q rides head-major [B, h*C, d]; scratch rows j*C..(j+1)*C hold head
-    j's running stats.  ks_ref/vs_ref (present for an int8 pool) carry
-    this page-row's [1, ps] fp32 block scales.  They apply to the score /
-    probability COLUMNS — (q·k_i8ᵀ)·s == q·(k_i8·s)ᵀ and
+    A page block is token-major [ps, h*d] and is only ever cut into
+    ``n_groups`` lane groups of the width ``w`` of q's rows.  q rides
+    [n_groups*rows, w]: group g's ``rows`` rows stack, head by head, the
+    C queries of each head of the group, zero outside the head's own
+    lanes — so q·kᵀ against the group is exactly q_j·k_jᵀ, and of p·v
+    against the group the caller keeps head j's lanes.  Scratch and
+    output rows mirror q's.  ks_ref/vs_ref (present for an int8 pool)
+    carry this page-row's [1, ps] fp32 block scales.  They apply to the
+    score / probability COLUMNS — (q·k_i8ᵀ)·s == q·(k_i8·s)ᵀ and
     (p·s)·v_i8 == p·(v_i8·s) — where ps already sits on the lane axis,
-    so dequant is a [C, ps] multiply and the scale row never needs a
+    so dequant is a [rows, ps] multiply and the scale row never needs a
     lane->sublane relayout.  The page DMA moved int8 bytes,
     halving-again the decode read stream vs bf16."""
     b = pl.program_id(0)
     p = pl.program_id(1)
+    w = q_ref.shape[-1]
 
     @pl.when(p == 0)
     def _init():
@@ -203,23 +229,25 @@ def _ragged_kernel(krows_ref, vrows_ref, meta_ref, q_ref, k_ref, v_ref,
 
     @pl.when(p * ps < length)
     def _page():
-        q = q_ref[0]                       # [h*C, d]
-        k = k_ref[:, 0]                    # [h, ps, d]
-        v = v_ref[:, 0]
+        q = q_ref[0]                       # [n_groups*rows, w]
+        k = k_ref[0]                       # [ps, h*d]
+        v = v_ref[0]
         if k.dtype != q.dtype:             # bf16/int8 pool: VMEM-level
             k = k.astype(q.dtype)          # upcast (the DMA moved narrow
             v = v.astype(q.dtype)          # bytes; dot_general won't promote)
         p0 = p * ps
-        for j in range(h):                 # static head loop
-            qj = q[j * c:(j + 1) * c]      # [C, d]
-            s = jax.lax.dot_general(qj, k[j], (((1,), (1,)), ((), ())),
+        for g in range(n_groups):          # static lane-group loop
+            r = slice(g * rows, (g + 1) * rows)
+            lanes = slice(g * w, (g + 1) * w)
+            s = jax.lax.dot_general(q[r], k[:, lanes],
+                                    (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = s * sm_scale
             if ks_ref is not None:
                 s = s * ks_ref[0]          # [1, ps] over the key columns
-            s = _ragged_mask(s, length, base, p0, ps, causal, c)
-            m_prev = m_scr[j * c:(j + 1) * c]              # [C, LANES]
-            l_prev = l_scr[j * c:(j + 1) * c]
+            s = _ragged_mask(s, length, base, p0, causal, c)
+            m_prev = m_scr[r]                              # [rows, LANES]
+            l_prev = l_scr[r]
             m_cur = jnp.max(s, axis=1)[:, None]
             m_new = jnp.maximum(m_prev,
                                 jnp.broadcast_to(m_cur, m_prev.shape))
@@ -227,15 +255,14 @@ def _ragged_kernel(krows_ref, vrows_ref, meta_ref, q_ref, k_ref, v_ref,
             pr = jnp.exp(s - m_new[:, :1])
             l_new = alpha * l_prev + jnp.broadcast_to(
                 jnp.sum(pr, axis=1)[:, None], l_prev.shape)
-            m_scr[j * c:(j + 1) * c] = m_new
-            l_scr[j * c:(j + 1) * c] = l_new
+            m_scr[r] = m_new
+            l_scr[r] = l_new
             if vs_ref is not None:
                 pr = pr * vs_ref[0]
-            pv = jax.lax.dot_general(pr.astype(v.dtype), v[j],
+            pv = jax.lax.dot_general(pr.astype(v.dtype), v[:, lanes],
                                      (((1,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
-            acc_scr[j * c:(j + 1) * c] = (
-                acc_scr[j * c:(j + 1) * c] * alpha[:, :1] + pv)
+            acc_scr[r] = acc_scr[r] * alpha[:, :1] + pv
 
     @pl.when(p == n_pages - 1)
     def _finalize():
@@ -248,29 +275,36 @@ def _ragged_kernel(krows_ref, vrows_ref, meta_ref, q_ref, k_ref, v_ref,
 
 def _ragged_pallas(q, pool, page_table, lengths, q_base, layer, n_layer,
                    causal, sm_scale, interpret, scales=None):
-    h, _r, ps, d = pool.shape
-    b, c, _h, _d = q.shape
+    _r, ps, hd = pool.shape
+    b, c, h, d = q.shape
     n_pages = page_table.shape[1]
     k_rows, v_rows = paged_kv_rows(page_table, layer, n_layer)
     meta = jnp.stack([jnp.asarray(lengths, jnp.int32).reshape(b),
                       jnp.asarray(q_base, jnp.int32).reshape(b)])
-    # head-major query rows: head j's C queries are contiguous
-    qk = jnp.transpose(q, (0, 2, 1, 3)).reshape(b, h * c, d)
     have_scales = scales is not None
+    # heads narrower than a lane group share it (``per`` to a group): a
+    # head's queries are padded with zeros to its group, and of the
+    # group-wide p·v only the head's own lanes are kept at the end
+    w = _lane_group(h, d)
+    per, n_groups = w // d, h * d // w
+    own = jnp.eye(per, dtype=q.dtype)
+    qk = jnp.einsum("bcgkd,jk->bgjckd", q.reshape(b, c, n_groups, per, d),
+                    own).reshape(b, h * c, w)
 
     def q_map(bi, pi, kr, vr, mt):
         return (bi, 0, 0)
 
     def k_map(bi, pi, kr, vr, mt):
-        return (0, kr[bi, pi], 0, 0)
+        return (kr[bi, pi], 0, 0)
 
     def v_map(bi, pi, kr, vr, mt):
-        return (0, vr[bi, pi], 0, 0)
+        return (vr[bi, pi], 0, 0)
 
+    # one page = one contiguous [ps, h*d] block of the pool
     in_specs = [
-        pl.BlockSpec((1, h * c, d), q_map),
-        pl.BlockSpec((h, 1, ps, d), k_map),
-        pl.BlockSpec((h, 1, ps, d), v_map),
+        pl.BlockSpec((1, h * c, w), q_map),
+        pl.BlockSpec((1, ps, hd), k_map),
+        pl.BlockSpec((1, ps, hd), v_map),
     ]
     args = [qk, pool, pool]
     if have_scales:
@@ -281,28 +315,24 @@ def _ragged_pallas(q, pool, page_table, lengths, q_base, layer, n_layer,
         # second-minor extent of 1 — neither R nor a multiple of 8 —
         # which the TPU lowering refuses.
         sc = scales.reshape(scales.shape[-2], 1, scales.shape[-1])
-        in_specs.append(pl.BlockSpec((1, 1, ps),
-                                     lambda bi, pi, kr, vr, mt:
-                                     (kr[bi, pi], 0, 0)))
-        in_specs.append(pl.BlockSpec((1, 1, ps),
-                                     lambda bi, pi, kr, vr, mt:
-                                     (vr[bi, pi], 0, 0)))
+        in_specs.append(pl.BlockSpec((1, 1, ps), k_map))
+        in_specs.append(pl.BlockSpec((1, 1, ps), v_map))
         args += [sc, sc]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, n_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h * c, d), q_map),
+        out_specs=pl.BlockSpec((1, h * c, w), q_map),
         scratch_shapes=[
             pltpu.VMEM((h * c, LANES), jnp.float32),
             pltpu.VMEM((h * c, LANES), jnp.float32),
-            pltpu.VMEM((h * c, d), jnp.float32),
+            pltpu.VMEM((h * c, w), jnp.float32),
         ],
     )
-    base = functools.partial(_ragged_kernel, h=h, c=c, ps=ps,
-                             n_pages=n_pages, causal=causal,
-                             sm_scale=sm_scale)
+    base = functools.partial(_ragged_kernel, n_groups=n_groups,
+                             rows=per * c, c=c, ps=ps, n_pages=n_pages,
+                             causal=causal, sm_scale=sm_scale)
 
     def kernel(krows_ref, vrows_ref, meta_ref, q_ref, k_ref, v_ref, *rest):
         rest = list(rest)
@@ -314,13 +344,15 @@ def _ragged_pallas(q, pool, page_table, lengths, q_base, layer, n_layer,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h * c, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h * c, w), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="ragged_paged_attn",
     )(k_rows, v_rows, meta, *args)
-    return jnp.transpose(out.reshape(b, h, c, d), (0, 2, 1, 3))
+    out = jnp.einsum("bgjckd,jk->bcgjd",
+                     out.reshape(b, n_groups, per, c, per, d), own)
+    return out.reshape(b, c, h, d)
 
 
 def _resolve_q_base(q, q_base, causal: bool):
@@ -342,7 +374,7 @@ def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
     Shapes:
         q           [B, C, H, D]  (C = 1 steady-state decode; C = chunk
                                    size during chunked prefill)
-        pool        [H, R, page_size, D]  (see paged_kv_rows layout)
+        pool        [R, page_size, H*D]   (see paged_kv_rows layout)
         page_table  [B, P] int32  logical page ids (trash page 0 pads)
         lengths     [B]    int32  live KV positions per lane
         q_base      [B]    int32  global position of q[:, 0] (required
@@ -382,7 +414,8 @@ def ragged_decode_attention_sharded(mesh: Mesh, q, pool, page_table,
     XLA partitions the gather path by itself, but a Mosaic kernel
     "cannot be automatically partitioned", so the Pallas impls map the
     call over the mesh: lanes (q, tables, lengths) split on
-    ``batch_axis``, heads (q's and the pool's head dim) on ``head_axis``;
+    ``batch_axis``, heads (q's head dim and the pool's minor dim, whose
+    equal slices are whole heads) on ``head_axis``;
     either may be None (that dim stays whole on every device).  The
     int8 scale sidecar is one scale per (row, slot) for ALL heads, so it
     rides replicated — each shard pages its own head slice of the pool
@@ -396,7 +429,7 @@ def ragged_decode_attention_sharded(mesh: Mesh, q, pool, page_table,
     q_spec = P(batch_axis, None, head_axis, None)
     args = [q, pool, page_table, lengths,
             _resolve_q_base(q, q_base, causal)]
-    specs = [q_spec, P(head_axis, None, None, None), P(batch_axis, None),
+    specs = [q_spec, P(None, None, head_axis), P(batch_axis, None),
              P(batch_axis), P(batch_axis)]
     if scales is not None:
         args.append(scales)

@@ -8,9 +8,10 @@ request uses it, and decode attention reads padded garbage bytes.
 ``PagedTransformerGenerator`` replaces that with the Ragged-Paged-
 Attention model (PAPERS.md, arxiv 2604.15464):
 
-* **one pooled KV tensor** ``[h, R, page_size, d]`` shared by every
+* **one pooled KV tensor** ``[R, page_size, h*d]`` shared by every
   lane, layer, and role (encoder-KV, cross-KV, decoder-self-KV) — a
-  logical page spans all layers and K+V of a page_size-token span;
+  logical page spans all layers and K+V of a page_size-token span, and
+  a token is one row the donated step writes in place;
 * **per-request page tables** allocated/freed by the host-side
   ``PageAllocator`` and fed as int32 data (a new page id never
   recompiles anything);
@@ -192,8 +193,8 @@ def build_unified_program(cfg: _Cfg, *, src_len: int, max_out_len: int,
     float32 feed applied to the logits before the argmax — constrained
     generation with masks as DATA (a grammar change never recompiles).
     ``shard_axis`` (ISSUE 17) annotates the program for a tensor-
-    parallel mesh axis of that name: the pool partitions on its head
-    axis, QKV/O and the MLP carry Megatron column/row shardings (the
+    parallel mesh axis of that name: the pool partitions on its minor
+    (heads) axis, QKV/O and the MLP carry Megatron column/row shardings (the
     attention-output allreduce lands in-graph via GSPMD), the int8
     scale sidecar and all paging feeds stay replicated DATA, and the
     vocab head stays replicated for bitwise argmax parity.  The
@@ -205,8 +206,8 @@ def build_unified_program(cfg: _Cfg, *, src_len: int, max_out_len: int,
     K = int(verify_tokens)
     p_src = _ceil_div(int(src_len), int(page_size))
     p_out = _ceil_div(int(max_out_len), int(page_size))
-    pool_shape = [c.n_head, int(num_pages) * c.n_layer * 2,
-                  int(page_size), c.d_key]
+    pool_shape = [int(num_pages) * c.n_layer * 2, int(page_size),
+                  c.n_head * c.d_key]
     scales_shape = [1, int(num_pages) * c.n_layer * 2, int(page_size)]
     prog, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(prog, startup), fluid.unique_name.guard():
@@ -215,11 +216,12 @@ def build_unified_program(cfg: _Cfg, *, src_len: int, max_out_len: int,
                                 shape=pool_shape, dtype=kv_dtype,
                                 persistable=True)
         if shard_axis:
-            # [h, R, page_size, d] partitions on the head axis; the
-            # per-token page scatters and the ragged attention walk are
-            # head-parallel, so every shard pages its own slice of the
-            # pool against the SAME replicated block tables
-            pool.set_sharding((shard_axis, None, None, None))
+            # [R, page_size, h*d] partitions on its minor axis, whose
+            # equal slices are whole heads; the per-token row scatters
+            # and the ragged attention walk are head-parallel, so every
+            # shard pages its own slice of the pool against the SAME
+            # replicated block tables
+            pool.set_sharding((None, None, shard_axis))
         kv_scales = None
         if kv_dtype == "int8":
             # the sidecar stays replicated: one scale per (row, slot)
@@ -446,8 +448,8 @@ class PagedTransformerGenerator:
         self.kv_dtype = kv_dtype
         self._pool_name = f"{param_prefix}@kv_pool"
         self._scales_name = f"{param_prefix}@kv_scales"
-        self._pool_shape = (n_head, self.num_pages * n_layer * 2,
-                            self.page_size, d_key)
+        self._pool_shape = (self.num_pages * n_layer * 2, self.page_size,
+                            n_head * d_key)
         self._scales_shape = (1, self.num_pages * n_layer * 2,
                               self.page_size)
         self.page_bytes = kv_page_bytes(n_layer, n_head, d_key,
@@ -505,7 +507,7 @@ class PagedTransformerGenerator:
             from jax.sharding import NamedSharding, PartitionSpec
 
             pool = jax.device_put(pool, NamedSharding(
-                self.mesh, PartitionSpec(self.shard_axis)))
+                self.mesh, PartitionSpec(None, None, self.shard_axis)))
         self.scope.set_var(self._pool_name, pool)
         if self.kv_dtype == "int8":
             self.scope.set_var(self._scales_name,
@@ -516,7 +518,7 @@ class PagedTransformerGenerator:
                              shape=list(self._pool_shape),
                              dtype=self.kv_dtype, persistable=True)
         if self.shard_axis:
-            v.set_sharding((self.shard_axis, None, None, None))
+            v.set_sharding((None, None, self.shard_axis))
         return v
 
     def _scales_var(self, block):
@@ -786,8 +788,7 @@ class PagedTransformerGenerator:
             kv_scales = self._scales_var(block)
             pages = layers.data("xfer_pages", [W], "int32",
                                 append_batch_size=False)
-            data = layers.data("xfer_data",
-                               [c.n_head, rows, self.page_size, c.d_key],
+            data = layers.data("xfer_data", [rows, *self._pool_shape[1:]],
                                self.kv_dtype, append_batch_size=False)
             if kv_scales is not None:
                 sdata = layers.data("xfer_scales",
@@ -806,12 +807,13 @@ class PagedTransformerGenerator:
     def _tier_download(self, pages) -> Dict[str, object]:
         """Device->host: pull whole logical pages as host numpy.  Groups
         of ``xfer_width`` ride one fixed-signature dispatch each.
-        Returns ``{"kv": [h, n*2L, ps, d], "scales": [1, n*2L, ps]|None}``
+        Returns ``{"kv": [n*2L, ps, h*d], "scales": [1, n*2L, ps]|None}``
         with rows in the order of ``pages``."""
         progs = self._xfer()
         down, fetches = progs["down"]
         c = self.cfg
         W, L2, ps = self.xfer_width, 2 * c.n_layer, self.page_size
+        page = self._pool_shape[1:]                 # (page_size, h*d)
         kv_parts: List[np.ndarray] = []
         sc_parts: List[np.ndarray] = []
         pages = [int(p) for p in pages]
@@ -822,14 +824,13 @@ class PagedTransformerGenerator:
             with fluid.scope_guard(self.scope), self._mesh_ctx():
                 out = self.exe.run(down, feed={"xfer_pages": pad},
                                    fetch_list=fetches, mode="infer")
-            slab = np.asarray(out[0]).reshape(c.n_head, W * L2, ps,
-                                              c.d_key)
-            kv_parts.append(slab[:, :len(grp) * L2])
+            slab = np.asarray(out[0]).reshape(W * L2, *page)
+            kv_parts.append(slab[:len(grp) * L2])
             if len(fetches) > 1:
                 ssl = np.asarray(out[1]).reshape(1, W * L2, ps)
                 sc_parts.append(ssl[:, :len(grp) * L2])
-        kv = np.concatenate(kv_parts, axis=1) if kv_parts else \
-            np.zeros((c.n_head, 0, ps, c.d_key), self.kv_dtype)
+        kv = np.concatenate(kv_parts, axis=0) if kv_parts else \
+            np.zeros((0, *page), self.kv_dtype)
         scales = np.concatenate(sc_parts, axis=1) if sc_parts else None
         return {"kv": kv, "scales": scales}
 
@@ -844,16 +845,16 @@ class PagedTransformerGenerator:
         kv = np.asarray(payload["kv"])
         scales = payload.get("scales")
         pages = [int(p) for p in pages]
-        if kv.shape[1] != len(pages) * L2:
+        if kv.shape[0] != len(pages) * L2:
             raise ValueError(
-                f"tier upload: payload holds {kv.shape[1] // L2} pages, "
+                f"tier upload: payload holds {kv.shape[0] // L2} pages, "
                 f"target list has {len(pages)}")
         for i in range(0, len(pages), W):
             grp = pages[i:i + W]
             pad = np.full(W, TRASH_PAGE, np.int32)
             pad[:len(grp)] = grp
-            data = np.zeros((c.n_head, W * L2, ps, c.d_key), kv.dtype)
-            data[:, :len(grp) * L2] = kv[:, i * L2:(i + len(grp)) * L2]
+            data = np.zeros((W * L2, *self._pool_shape[1:]), kv.dtype)
+            data[:len(grp) * L2] = kv[i * L2:(i + len(grp)) * L2]
             feed = {"xfer_pages": pad, "xfer_data": data}
             if self.kv_dtype == "int8":
                 sdata = np.zeros((1, W * L2, ps), np.float32)

@@ -45,7 +45,8 @@ from ..utils.sync import RANK_COLLECTOR_INIT, RANK_SESSIONS, OrderedLock
 
 __all__ = ["SessionStore", "SESSION_MAGIC"]
 
-SESSION_MAGIC = b"PDLKVS1\n"
+# 2: KV slabs are token-major [rows, page, heads*depth] (1 was head-major)
+SESSION_MAGIC = b"PDLKVS2\n"
 _SUFFIX = ".kvs"
 
 _LIVE_STORES: "weakref.WeakSet[SessionStore]" = weakref.WeakSet()

@@ -51,3 +51,19 @@ def fresh_programs():
 
 def rng(seed=0):
     return np.random.RandomState(seed)
+
+
+def hlo_results_of_size(hlo: str, n_elems: int):
+    """{opcode: count} of the instructions of an (optimized) HLO text
+    whose result holds ``n_elems`` elements — how the paged-pool tests
+    ask whether a compiled step still holds a second pool-sized buffer."""
+    import re
+
+    kinds = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m and int(np.prod([int(x) for x in m.group(1).split(",")])) \
+                == n_elems:
+            kinds[m.group(2)] = kinds.get(m.group(2), 0) + 1
+    return kinds

@@ -74,7 +74,7 @@ def test_quantized_paged_cache_write_int8_sidecar_golden():
     n_pages, n_layer, page, h, d = 4, 1, 4, 2, 4
     rows = n_pages * n_layer * 2
     t = OpTestCase("quantized_paged_cache_write", {
-        "Pool": np.zeros((h, rows, page, d), np.int8),
+        "Pool": np.zeros((rows, page, h * d), np.int8),
         "Scales": np.zeros((1, rows, page), np.float32),
         "K": np.ones((2, 1, h, d), np.float32),
         "V": np.ones((2, 1, h, d), np.float32),
@@ -101,7 +101,7 @@ def test_ragged_decode_attention_cost_golden():
     b, c, p = 2, 1, 2
     t = OpTestCase("ragged_decode_attention", {
         "Q": np.ones((b, c, h, d), np.float32),
-        "Pool": np.zeros((h, rows, page, d), np.int8),
+        "Pool": np.zeros((rows, page, h * d), np.int8),
         "PageTable": np.ones((b, p), np.int32),
         "Lengths": np.ones(b, np.int32),
         "QBase": np.zeros(b, np.int32),

@@ -8,6 +8,7 @@ prompts), and the engine's true-vs-padded accounting satellite."""
 
 import numpy as np
 import pytest
+from conftest import hlo_results_of_size
 
 from paddle_tpu import fluid
 from paddle_tpu.fluid import layers
@@ -59,7 +60,7 @@ def test_paged_cache_write_and_page_copy(fresh_programs):
 
     main, startup, scope = fresh_programs
     H, D, NPAGES, L = 2, 3, 4, 2
-    pool_shape = (H, NPAGES * L * 2, PS, D)
+    pool_shape = (NPAGES * L * 2, PS, H * D)
     pool = main.global_block().create_var(
         name="pool", shape=list(pool_shape), dtype="float32",
         persistable=True)
@@ -81,9 +82,9 @@ def test_paged_cache_write_and_page_copy(fresh_programs):
     k_rows, v_rows = paged_kv_rows(pg, 1, L)
     for b in range(2):
         np.testing.assert_array_equal(
-            got[:, int(k_rows[b, 0]), int(of[b, 0])], kv[b, 0])
+            got[int(k_rows[b, 0]), int(of[b, 0])], kv[b, 0].reshape(-1))
         np.testing.assert_array_equal(
-            got[:, int(v_rows[b, 0]), int(of[b, 0])], vv[b, 0])
+            got[int(v_rows[b, 0]), int(of[b, 0])], vv[b, 0].reshape(-1))
     assert np.count_nonzero(got) == 2 * 2 * H * D  # nothing else written
 
     # page copy: dst page 2 <- page 1, lane 1 no-op (src == dst == 0)
@@ -102,10 +103,10 @@ def test_paged_cache_write_and_page_copy(fresh_programs):
             fetch_list=["pool"])
     after = np.asarray(scope.find_var("pool"))
     rows = np.arange(2 * L)
-    np.testing.assert_array_equal(after[:, 2 * 2 * L + rows],
-                                  before[:, 1 * 2 * L + rows])
-    np.testing.assert_array_equal(after[:, :2 * 2 * L],
-                                  before[:, :2 * 2 * L])
+    np.testing.assert_array_equal(after[2 * 2 * L + rows],
+                                  before[1 * 2 * L + rows])
+    np.testing.assert_array_equal(after[:2 * 2 * L],
+                                  before[:2 * 2 * L])
 
 
 def test_ragged_attention_matches_masked_reference(fresh_programs):
@@ -117,7 +118,7 @@ def test_ragged_attention_matches_masked_reference(fresh_programs):
 
     main, startup, scope = fresh_programs
     H, D, L, NPAGES, P, C = 2, 4, 2, 6, 2, 2
-    pool_shape = (H, NPAGES * L * 2, PS, D)
+    pool_shape = (NPAGES * L * 2, PS, H * D)
     rng = np.random.RandomState(1)
     pool_np = rng.randn(*pool_shape).astype(np.float32)
     pool = main.global_block().create_var(
@@ -142,10 +143,8 @@ def test_ragged_attention_matches_masked_reference(fresh_programs):
     k_rows, v_rows = paged_kv_rows(tv, 1, L)
     scale = D ** -0.5
     for b in range(B):
-        k = np.transpose(pool_np[:, np.asarray(k_rows)[b]],
-                         (1, 2, 0, 3)).reshape(P * PS, H, D)
-        v = np.transpose(pool_np[:, np.asarray(v_rows)[b]],
-                         (1, 2, 0, 3)).reshape(P * PS, H, D)
+        k = pool_np[np.asarray(k_rows)[b]].reshape(P * PS, H, D)
+        v = pool_np[np.asarray(v_rows)[b]].reshape(P * PS, H, D)
         for c in range(C):
             n = min(int(lv[b]), int(bv[b]) + c + 1)
             s = np.einsum("hd,khd->hk", qv[b, c], k[:n]) * scale
@@ -166,7 +165,7 @@ def test_ragged_pallas_interpret_matches_xla():
 
     rng = np.random.RandomState(3)
     H, D, L, NPAGES, P, C, B = 2, 4, 3, 6, 3, 2, 3
-    pool = jnp.asarray(rng.randn(H, NPAGES * L * 2, PS, D)
+    pool = jnp.asarray(rng.randn(NPAGES * L * 2, PS, H * D)
                        .astype(np.float32))
     q = jnp.asarray(rng.randn(B, C, H, D).astype(np.float32))
     tbl = jnp.asarray(rng.randint(0, NPAGES, (B, P)).astype(np.int32))
@@ -205,10 +204,10 @@ def test_ragged_sharded_matches_unsharded(axes, int8, fresh_programs):
     R = NPAGES * L * 2
     scales = None
     if int8:
-        pool_np = rng.randint(-127, 128, (H, R, PS, D)).astype(np.int8)
+        pool_np = rng.randint(-127, 128, (R, PS, H * D)).astype(np.int8)
         scales = jnp.asarray(rng.rand(1, R, PS).astype(np.float32) + 0.5)
     else:
-        pool_np = rng.randn(H, R, PS, D).astype(np.float32)
+        pool_np = rng.randn(R, PS, H * D).astype(np.float32)
     q = rng.randn(B, C, H, D).astype(np.float32)
     tbl = rng.randint(1, NPAGES, (B, P)).astype(np.int32)
     lengths = np.array([7, 0, 8, 3], np.int32)
@@ -234,7 +233,7 @@ def test_ragged_sharded_matches_unsharded(axes, int8, fresh_programs):
         name="pool", shape=list(pool_np.shape), dtype="float32",
         persistable=True)
     if h_ax:
-        pool.set_sharding((h_ax, None, None, None))
+        pool.set_sharding((None, None, h_ax))
     out = layers.ragged_decode_attention(
         layers.data("q", [C, H, D], "float32"), pool,
         layers.data("tbl", [P], "int32"), layers.data("ln", [], "int32"),
@@ -785,7 +784,7 @@ def test_quantized_paged_cache_write_roundtrip_and_scale_placement(
 
     main, startup, scope = fresh_programs
     H, D, NPAGES, L = 2, 3, 4, 2
-    pool_shape = (H, NPAGES * L * 2, PS, D)
+    pool_shape = (NPAGES * L * 2, PS, H * D)
     scales_shape = (1, NPAGES * L * 2, PS)
     pool = main.global_block().create_var(
         name="pool", shape=list(pool_shape), dtype="int8",
@@ -819,8 +818,9 @@ def test_quantized_paged_cache_write_roundtrip_and_scale_placement(
             sc = got_sc[0, r, s]
             want_sc = np.abs(val[b, 0]).max() / 127.0
             np.testing.assert_allclose(sc, want_sc, rtol=1e-6)
-            deq = got[:, r, s].astype(np.float32) * sc
-            assert (np.abs(deq - val[b, 0]) <= sc / 2 + 1e-7).all()
+            deq = got[r, s].astype(np.float32) * sc
+            assert (np.abs(deq - val[b, 0].reshape(-1))
+                    <= sc / 2 + 1e-7).all()
     # unwritten slots: zero bytes AND zero scales
     assert np.count_nonzero(got) > 0
     mask = np.ones(scales_shape, bool)
@@ -848,8 +848,8 @@ def test_quantized_paged_cache_write_roundtrip_and_scale_placement(
     after = np.asarray(scope.find_var("pool"))
     after_sc = np.asarray(scope.find_var("scales"))
     rows = np.arange(2 * L)
-    np.testing.assert_array_equal(after[:, 2 * 2 * L + rows],
-                                  got[:, 1 * 2 * L + rows])
+    np.testing.assert_array_equal(after[2 * 2 * L + rows],
+                                  got[1 * 2 * L + rows])
     np.testing.assert_array_equal(after_sc[:, 2 * 2 * L + rows],
                                   got_sc[:, 1 * 2 * L + rows])
 
@@ -866,7 +866,7 @@ def test_ragged_pallas_interpret_matches_xla_int8():
     rng = np.random.RandomState(13)
     H, D, L, NPAGES, P, C, B = 2, 4, 3, 6, 3, 2, 3
     R = NPAGES * L * 2
-    pool = jnp.asarray(rng.randint(-127, 128, (H, R, PS, D))
+    pool = jnp.asarray(rng.randint(-127, 128, (R, PS, H * D))
                        .astype(np.int8))
     scales = jnp.asarray(rng.uniform(1e-3, 0.1, (1, R, PS))
                          .astype(np.float32))
@@ -897,7 +897,7 @@ def test_ragged_pallas_interpret_matches_xla_bf16():
     rng = np.random.RandomState(17)
     H, D, L, NPAGES, P, C, B = 2, 4, 3, 6, 3, 2, 3
     R = NPAGES * L * 2
-    pool = jnp.asarray(rng.randn(H, R, PS, D).astype(np.float32),
+    pool = jnp.asarray(rng.randn(R, PS, H * D).astype(np.float32),
                        jnp.bfloat16)
     q = jnp.asarray(rng.randn(B, C, H, D).astype(np.float32))
     tbl = jnp.asarray(rng.randint(0, NPAGES, (B, P)).astype(np.int32))
@@ -1017,3 +1017,196 @@ def test_capacity_contest_int8_gt_bf16_gt_dense():
     n_dense = budget // dense_slot
     assert admitted["int8"] > admitted["bfloat16"] > n_dense, \
         (admitted, n_dense)
+
+
+# -- token-major pool, written in place (ISSUE 26) ----------------------------
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
+def test_write_then_read_matches_dense_reference(kv_dtype, impl,
+                                                 fresh_programs):
+    """paged_cache_write followed by ragged_decode_attention, through the
+    ops, against a dense per-lane reference: two dispatches, so the
+    second chunk of lane 0 starts mid-page and crosses a page boundary;
+    lane 1 is dead throughout (trash page, length 0) and lane 2's chunk
+    ends in a dead token, which goes to the trash page too.  The pool
+    must hold every live token's [H*D] row at its (row, slot) and
+    nothing else outside the trash page; the attention must equal a
+    causal softmax over what the pool's dtype kept of K and V."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels.flash_attention import paged_kv_rows
+
+    main, startup, scope = fresh_programs
+    H, D, L, LAYER, NPAGES, P, C, B = 2, 4, 2, 1, 8, 2, 3, 3
+    R = NPAGES * L * 2
+    block = main.global_block()
+    pool = block.create_var(name="pool", shape=[R, PS, H * D],
+                            dtype=kv_dtype, persistable=True)
+    scope.set_var("pool", jnp.zeros((R, PS, H * D), kv_dtype))
+    scales = None
+    if kv_dtype == "int8":
+        scales = block.create_var(name="scales", shape=[1, R, PS],
+                                  dtype="float32", persistable=True)
+        scope.set_var("scales", jnp.zeros((1, R, PS), jnp.float32))
+    k = layers.data("k", [C, H, D], "float32")
+    v = layers.data("v", [C, H, D], "float32")
+    pages = layers.data("pages", [C], "int32")
+    offs = layers.data("offs", [C], "int32")
+    tbl = layers.data("tbl", [P], "int32")
+    ln = layers.data("ln", [], "int32")
+    qb = layers.data("qb", [], "int32")
+    if scales is not None:
+        pool, scales = layers.quantized_paged_cache_write(
+            pool, scales, k, v, pages, offs, layer=LAYER, n_layer=L)
+    else:
+        pool = layers.paged_cache_write(pool, k, v, pages, offs,
+                                        layer=LAYER, n_layer=L)
+    out = layers.ragged_decode_attention(k, pool, tbl, ln, qb, layer=LAYER,
+                                         n_layer=L, causal=True, impl=impl,
+                                         scales=scales)
+    exe = fluid.Executor(fluid.CPUPlace())
+    table = np.array([[3, 5], [0, 0], [6, 0]], np.int32)
+    # tokens live per lane in each of the two dispatches
+    live = [[2, 0, 2], [3, 0, 3]]
+    rng = np.random.RandomState(26)
+    kept_k = [np.zeros((0, H, D), np.float32) for _ in range(B)]
+    kept_v = [np.zeros((0, H, D), np.float32) for _ in range(B)]
+    written = set()
+    for step_live in live:
+        kv_ = (rng.randn(B, C, H, D) * 2).astype(np.float32)
+        vv_ = rng.randn(B, C, H, D).astype(np.float32)
+        pg = np.zeros((B, C), np.int32)
+        of = np.zeros((B, C), np.int32)
+        base = np.array([len(x) for x in kept_k], np.int32)
+        for b in range(B):
+            for c in range(step_live[b]):
+                pos = int(base[b]) + c
+                pg[b, c], of[b, c] = table[b, pos // PS], pos % PS
+        lengths = base + np.asarray(step_live, np.int32)
+        got, = exe.run(main, feed={"k": kv_, "v": vv_, "pages": pg,
+                                   "offs": of, "tbl": table, "ln": lengths,
+                                   "qb": base}, fetch_list=[out])
+        got = np.asarray(got)
+        pool_np = np.asarray(scope.find_var("pool")).astype(np.float32)
+        if kv_dtype == "int8":
+            pool_np = pool_np * np.asarray(
+                scope.find_var("scales"))[0][..., None]
+        k_rows, v_rows = (np.asarray(x) for x in paged_kv_rows(pg, LAYER, L))
+        tol = {"float32": 0.0, "bfloat16": 2.0 ** -8, "int8": 0.5 / 127}
+        for b in range(B):
+            for c in range(step_live[b]):
+                for rows, val, kept in ((k_rows, kv_, kept_k),
+                                        (v_rows, vv_, kept_v)):
+                    row = pool_np[rows[b, c], of[b, c]].reshape(H, D)
+                    assert np.abs(row - val[b, c]).max() <= \
+                        tol[kv_dtype] * np.abs(val[b, c]).max() + 1e-7
+                    kept[b] = np.concatenate([kept[b], row[None]])
+                    written.add((int(rows[b, c]), int(of[b, c])))
+        # outside the live tokens' slots only the trash page was written
+        untouched = np.ones((R, PS), bool)
+        untouched[:2 * L] = False
+        for r, s in written:
+            untouched[r, s] = False
+        assert not pool_np[untouched].any()
+        for b in range(B):
+            for c in range(C):
+                n = min(int(lengths[b]), int(base[b]) + c + 1)
+                if not lengths[b]:
+                    assert not got[b, c].any()     # dead lane contract
+                    continue
+                s = np.einsum("hd,khd->hk", kv_[b, c],
+                              kept_k[b][:n]) * D ** -0.5
+                p = np.exp(s - s.max(-1, keepdims=True))
+                p /= p.sum(-1, keepdims=True)
+                want = np.einsum("hk,khd->hd", p, kept_v[b][:n])
+                np.testing.assert_allclose(got[b, c], want, rtol=2e-5,
+                                           atol=2e-5)
+
+
+def test_unified_step_holds_no_second_pool_sized_buffer():
+    """The regression guard of ISSUE 26, on the program's side: in the
+    optimized HLO of the donated unified step nothing but the pool
+    parameter, its views and the in-place row scatters (two per write
+    op: 3 write ops a layer) has the pool's element count.  No copy, no
+    transpose, no other fusion.  (The head-major pool this replaced
+    compiled to 13 transposes and 14 copies of the whole pool here, and
+    to 14 copies of 2 GB on the chip.)"""
+    gen = PagedTransformerGenerator(
+        V, V, n_layer=NL, n_head=NH, d_key=DK, d_value=DK, d_model=DM,
+        d_inner_hid=DI, max_length=64, src_len=SRC, max_out_len=OUT,
+        page_size=PS, chunk_size=CHUNK, num_pages=67, param_prefix="hlo",
+        executor=fluid.Executor(fluid.CPUPlace()))
+    gen.init_params(seed=1)
+    gen.open_slots(3)
+    hlo = gen.compiled_step_hlo()
+    assert "input_output_alias" in hlo.splitlines()[0]
+    n_elems = int(np.prod(gen._pool_shape))     # 67 pages: no other match
+    kinds = hlo_results_of_size(hlo, n_elems)
+    n_writes = 2 * 3 * NL
+    assert kinds.pop("scatter") == n_writes
+    assert kinds.pop("fusion", n_writes) == n_writes   # one per scatter
+    assert set(kinds) <= {"parameter", "bitcast"}, kinds
+
+
+# what the head-major pool produced at e99c405 (PR 25) for these weights
+# and prompts: the token-major pool must reproduce it token for token
+_GOLDEN_SRC = [[8, 18, 3, 8, 21, 15, 2, 6], [15, 19, 21, 23, 22, 0, 0, 0],
+               [23, 17, 12, 0, 0, 0, 0, 0], [20, 17, 23, 14, 17, 18, 0, 0]]
+_GOLDEN_LENS = [8, 5, 3, 6]
+_GOLDEN_GREEDY = [[11, 11, 11, 22, 22, 22, 1, 1], [11, 22, 1, 1, 1, 1, 1, 1],
+                  [11, 11, 11, 11, 11, 11, 11, 11],
+                  [11, 11, 11, 11, 11, 11, 11, 11]]
+_GOLDEN_BEAM = [
+    [[11, 22, 22, 1, 1, 1, 1, 1], [11, 11, 22, 1, 1, 1, 1, 1],
+     [11, 11, 11, 22, 22, 22, 1, 1]],
+    [[11, 22, 1, 1, 1, 1, 1, 1], [17, 11, 22, 22, 22, 22, 1, 1],
+     [17, 11, 11, 22, 22, 22, 1, 1]],
+    [[11, 11, 11, 22, 1, 1, 1, 1], [11, 11, 11, 11, 11, 11, 11, 11],
+     [11, 11, 11, 11, 11, 11, 11, 22]],
+    [[2, 11, 11, 11, 11, 11, 11, 11], [11, 11, 11, 11, 11, 11, 11, 11],
+     [2, 11, 11, 11, 11, 11, 11, 22]]]
+_GOLDEN_BEAM_SCORES = [
+    [-7.833042144775391, -7.993684768676758, -13.988262176513672],
+    [-6.084239482879639, -13.333710670471191, -13.369152069091797],
+    [-9.643131256103516, -14.632216453552246, -14.970511436462402],
+    [-11.750408172607422, -11.869285583496094, -12.717007637023926]]
+
+
+@pytest.fixture(scope="module")
+def golden_pair():
+    from paddle_tpu.serving import SpeculativeGenerator
+
+    scope = fluid.Scope()
+    kw = dict(n_layer=NL, n_head=NH, d_key=DK, d_value=DK, d_model=DM,
+              d_inner_hid=DI, max_length=64, src_len=SRC, max_out_len=OUT,
+              page_size=PS, chunk_size=CHUNK, num_pages=64, scope=scope,
+              executor=fluid.Executor(fluid.CPUPlace()))
+    target = PagedTransformerGenerator(V, V, param_prefix="gold", **kw)
+    draft = PagedTransformerGenerator(V, V, param_prefix="golddraft", **kw)
+    target.init_params(seed=11)
+    draft.init_params(seed=12)
+    return target, SpeculativeGenerator(target, draft, k=3,
+                                        draft_name="golddraft")
+
+
+@pytest.mark.parametrize("mode", ["greedy", "beam", "speculative"])
+def test_decoding_reproduces_head_major_pool_outputs(mode, golden_pair):
+    """Greedy, beam (reorders reassign page tables and copy shared pages
+    on write) and speculative decoding (K-token verify writes, rollback
+    by truncation; the reseeded draft is rejected 15 times in 16) emit
+    exactly what they emitted over the head-major pool."""
+    target, spec = golden_pair
+    src = np.asarray(_GOLDEN_SRC, np.int64)
+    lens = np.asarray(_GOLDEN_LENS, np.int32)
+    if mode == "beam":
+        ids, scores = target.beam(src, lens, beam_size=3, max_new=OUT)
+        np.testing.assert_array_equal(np.asarray(ids), _GOLDEN_BEAM)
+        np.testing.assert_allclose(np.asarray(scores), _GOLDEN_BEAM_SCORES,
+                                   rtol=1e-5)
+        return
+    gen = target if mode == "greedy" else spec
+    got = gen.greedy(src, lens, max_new=OUT, stop_at_end=False)
+    np.testing.assert_array_equal(np.asarray(got), _GOLDEN_GREEDY)
+    if mode == "speculative":
+        assert spec.cache_stats()["speculative"]["accept_rate"] < 0.5

@@ -1,0 +1,107 @@
+"""Compiles for a described TPU v5e, without the chip (ISSUE 26): the
+serve path's Mosaic kernel and the donated unified step at the served
+widths (8 heads of 64, pages of 16 tokens, chunks of 32), through the
+TPU compiler installed here.  What interpret mode and the CPU backend
+cannot show: that Mosaic accepts the kernel's lane slices for every pool
+dtype, and that the chip's compiler updates the token-major pool in
+place.  Nothing runs and nothing is timed.  All such compiles live in
+this one file: only one process may hold the TPU library, so the
+topology is described inside a fixture, after collection."""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+from conftest import hlo_results_of_size
+
+from paddle_tpu import fluid
+from paddle_tpu.serving import PagedTransformerGenerator
+
+H, D, PS, CHUNK, NL = 8, 64, 16, 32, 2
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(tree, sharding):
+    import jax
+
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=sharding), tree)
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("c", [1, CHUNK])
+def test_ragged_kernel_compiles_for_v5e(kv_dtype, c, one_chip):
+    """One page is one (1, page, H*D) block, cut into 128-lane groups of
+    two heads each: Mosaic takes that for 4-, 2- and 1-byte pools, for a
+    decode step's single query and for a prefill chunk."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels.flash_attention import ragged_decode_attention
+
+    b, p, rows = 8, 16, 64 * NL * 2
+    scales = np.zeros((1, rows, PS), np.float32) \
+        if kv_dtype == "int8" else None
+
+    def call(q, pool, table, lengths, base, scales):
+        return ragged_decode_attention(q, pool, table, lengths, base,
+                                       layer=1, n_layer=NL, causal=True,
+                                       impl="pallas", scales=scales)
+
+    args = (np.zeros((b, c, H, D), np.float32),
+            jnp.zeros((rows, PS, H * D), kv_dtype),
+            np.zeros((b, p), np.int32), np.zeros(b, np.int32),
+            np.zeros(b, np.int32), scales)
+    hlo = jax.jit(call).lower(*_shapes(args, one_chip)).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_unified_step_updates_the_pool_in_place_on_v5e(one_chip,
+                                                       monkeypatch):
+    """The donated unified step, compiled by the chip's compiler: every
+    ragged attention is a Mosaic call, the pool is aliased to its output,
+    no copy or transpose of pool size remains, and the step's temporaries
+    are a fraction of the pool (the head-major pool needed six times
+    it).  The pool is 262 MB: one of a few MB the compiler may stage
+    whole through faster memory, which is not what is guarded here."""
+    import jax
+
+    monkeypatch.setattr(sys.modules["paddle_tpu.kernels.flash_attention"],
+                        "default_impl", lambda: "pallas")
+    gen = PagedTransformerGenerator(
+        96, 96, n_layer=NL, n_head=H, d_key=D, d_value=D, d_model=H * D,
+        d_inner_hid=256, max_length=65, src_len=64, max_out_len=64,
+        page_size=PS, chunk_size=CHUNK, num_pages=2003, param_prefix="v5e",
+        executor=fluid.Executor(fluid.CPUPlace()))
+    gen.init_params(seed=1)
+    gen.open_slots(8)
+    prog, _, next_ids, _ = gen._unified
+    with fluid.scope_guard(gen.scope):
+        feed, state, step = gen.exe._prepare_step(
+            prog, gen._step_feed(), [next_ids], gen.scope, "infer")
+    args = _shapes((feed, state, np.zeros(2, np.int32)), one_chip)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3 * NL
+    assert "input_output_alias" in hlo.splitlines()[0]
+    n_elems = int(np.prod(gen._pool_shape))    # 2003 pages: no other match
+    kinds = hlo_results_of_size(hlo[hlo.index("ENTRY "):], n_elems)
+    assert kinds.pop("fusion") == 2 * 3 * NL, kinds    # the row scatters
+    assert set(kinds) <= {"parameter", "bitcast"}, kinds
+    pool_bytes = n_elems * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
