@@ -62,15 +62,18 @@ def _fsync_dir(dirname: str) -> None:
         os.close(fd)
 
 
-def _atomic_write(path: str, payload: bytes) -> None:
+def _atomic_write(path: str, payload) -> None:
     """tmp + fsync + os.replace + dir fsync — the pserver checkpoint
-    recipe (service.go:119-175 writes .tmp then renames)."""
+    recipe (service.go:119-175 writes .tmp then renames).  ``payload`` is
+    bytes, or a list of bytes-like pieces written one after another."""
     d = os.path.dirname(os.path.abspath(path))
     os.makedirs(d, exist_ok=True)
     tmp = f"{path}.tmp.{os.getpid()}"
     try:
         with open(tmp, "wb") as f:
-            f.write(payload)
+            for piece in (payload if isinstance(payload, list)
+                          else [payload]):
+                f.write(piece)
             f.flush()
             os.fsync(f.fileno())
         os.replace(tmp, path)
@@ -91,34 +94,50 @@ def _read_checked(path: str) -> bytes:
     return unframe_bytes(buf, path)
 
 
-def _tensor_bytes(value) -> bytes:
+def _raw(array: np.ndarray):
+    """The array's bytes as a flat view: no copy of a contiguous array."""
+    return np.ascontiguousarray(array).reshape(-1).view(np.uint8).data
+
+
+def _tensor_parts(value) -> List:
+    """A tensor's payload as pieces (length-prefixed header, then the
+    data's own bytes as views): ``_tensor_bytes`` joins them, and
+    ``save_tensor`` writes them one after another, so a large tensor is
+    never copied whole just to be written."""
     if isinstance(value, SeqArray):
         data = np.asarray(value.data)
         lengths = np.asarray(value.lengths, np.int32)
         header = {"dtype": data.dtype.name, "shape": list(data.shape),
                   "lod": True, "batch": int(lengths.shape[0])}
         hb = json.dumps(header).encode()
-        return (struct.pack("<I", len(hb)) + hb + data.tobytes()
-                + lengths.tobytes())
+        return [struct.pack("<I", len(hb)) + hb, _raw(data), _raw(lengths)]
     data = np.asarray(value)
     header = {"dtype": data.dtype.name, "shape": list(data.shape),
               "lod": False}
     hb = json.dumps(header).encode()
-    return struct.pack("<I", len(hb)) + hb + data.tobytes()
+    return [struct.pack("<I", len(hb)) + hb, _raw(data)]
 
 
-def _tensor_from(buf: bytes, offset: int = 0):
+def _tensor_bytes(value) -> bytes:
+    return b"".join(_tensor_parts(value))
+
+
+def _tensor_from(buf, offset: int = 0, copy: bool = True):
+    """``copy=False`` returns arrays that are read-only views of ``buf``
+    (bytes or a memoryview): for a reader that hands them straight on."""
     (hlen,) = struct.unpack_from("<I", buf, offset)
     offset += 4
-    header = json.loads(buf[offset: offset + hlen].decode())
+    header = json.loads(bytes(buf[offset: offset + hlen]).decode())
     offset += hlen
     import ml_dtypes  # noqa: F401  (registers bfloat16 with numpy)
 
     dt = np.dtype(header["dtype"]) if header["dtype"] != "bfloat16" else \
         np.dtype(__import__("ml_dtypes").bfloat16)
     n = int(np.prod(header["shape"])) * dt.itemsize
-    data = np.frombuffer(buf[offset: offset + n], dtype=dt).reshape(
-        header["shape"]).copy()
+    data = np.frombuffer(buf, dtype=dt, count=n // dt.itemsize,
+                         offset=offset).reshape(header["shape"])
+    if copy:
+        data = data.copy()
     offset += n
     if header.get("lod"):
         ln = header["batch"] * 4
@@ -165,10 +184,28 @@ def tensor_from_bytes(data: bytes, what: str = "<bytes>"):
 
 
 def save_tensor(value, path: str) -> None:
-    _atomic_write(path, tensor_to_bytes(value))
+    """``tensor_to_bytes(value)`` into ``path`` (tmp + fsync + rename),
+    written piece by piece with the checksum kept as it goes: the same
+    file, without three copies of the tensor on the way."""
+    parts = [_MAGIC2] + _tensor_parts(value)
+    crc = 0
+    for part in parts[1:]:
+        crc = zlib.crc32(part, crc)
+    parts.append(struct.pack("<I", crc & 0xFFFFFFFF))
+    _atomic_write(path, parts)
 
 
-def load_tensor(path: str):
+def load_tensor(path: str, copy: bool = True):
+    """The tensor in ``path``, checksum verified.  ``copy=False`` returns
+    a read-only array over the file's bytes as read (no second and third
+    copy of a large tensor): for a loader that puts it on the device and
+    drops it."""
+    if not copy:
+        with open(path, "rb") as f:
+            buf = f.read()
+        # unframing a memoryview slices it without copying
+        return _tensor_from(unframe_bytes(memoryview(buf), path), 0,
+                            copy=False)[0]
     value, _ = _tensor_from(_read_checked(path), 0)
     return value
 

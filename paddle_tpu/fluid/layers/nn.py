@@ -22,7 +22,8 @@ __all__ = [
     "softmax_with_cross_entropy", "smooth_l1", "l2_normalize", "split",
     "nce", "im2sequence", "beam_search", "beam_search_decode", "batch_gather",
     "gather", "expand", "multiplex", "fused_attention", "decode_attention",
-    "ragged_decode_attention", "quantize", "dequantize", "quantized_mul",
+    "ragged_decode_attention", "rms_norm", "rotary_embedding", "swiglu",
+    "routed_experts", "vocab_logits", "quantize", "dequantize", "quantized_mul",
     "quantized_matmul", "quantized_conv2d",
     "pad", "crop", "lod_reset", "lrn", "label_smooth", "rank_loss",
     "margin_rank_loss", "log_loss", "conv_shift", "row_conv",
@@ -884,7 +885,9 @@ def decode_attention(q, k_cache, v_cache, lengths, sm_scale=None,
 
 def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
                             layer=0, n_layer=1, causal=True, sm_scale=None,
-                            impl=None, scales=None, name=None):
+                            impl=None, scales=None, name=None, v_pool=None,
+                            window=None, sink=None, ring_top=None,
+                            out_scale=None, scope=None):
     """Attention of per-lane query blocks against the paged KV pool,
     walking each lane's page list (ops/cache_ops.ragged_decode_attention;
     the Pallas kernel lives in kernels/flash_attention).  q [B, C, H, D]
@@ -892,7 +895,15 @@ def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
     [R, page_size, H*D], page_table [B, P] int32 logical pages, lengths
     [B] int32 live positions, q_base [B] int32 global query start
     (required when causal).  ``scales`` ([1, R, page_size] fp32) rides
-    along for int8 pools — K/V dequantize in-register during the walk."""
+    along for int8 pools — K/V dequantize in-register during the walk.
+
+    With ``v_pool`` the cache is a SPLIT pair (keys [R, page, Hkv*Dk] in
+    ``pool``, values [R, page, Hkv*Dv] in ``v_pool``): H query heads on
+    Hkv KV heads, values of another width than keys, ``window`` (keys
+    q-window < j <= q), ``sink`` ([H], a logit per query head in the
+    softmax's denominator only), ``ring_top`` ([B]: the table is a ring
+    of pages), ``out_scale`` (the result times a constant) and ``scope``
+    (the name its device operations carry in a trace)."""
     helper = LayerHelper("ragged_decode_attention", name=name)
     out = helper.create_tmp_variable(q.dtype, stop_gradient=True)
     attrs = {"layer": int(layer), "n_layer": int(n_layer),
@@ -907,8 +918,109 @@ def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
         inputs["QBase"] = q_base
     if scales is not None:
         inputs["Scales"] = scales
+    for slot, var in (("VPool", v_pool), ("Sink", sink),
+                      ("RingTop", ring_top)):
+        if var is not None:
+            inputs[slot] = var
+    for key, val in (("window", window), ("out_scale", out_scale),
+                     ("scope", scope)):
+        if val is not None:
+            attrs[key] = val
     helper.append_op("ragged_decode_attention", inputs, {"Out": out}, attrs)
     return out
+
+
+def vocab_logits(x, size, param_attr=None, name=None):
+    """Output head with float32 logits (ops/llm_ops.vocab_logits); the
+    weight [d, size] is stored in x's type."""
+    helper = LayerHelper("vocab_logits", param_attr=param_attr, name=name)
+    w = helper.create_parameter(helper.param_attr,
+                                shape=[x.shape[-1], int(size)],
+                                dtype=x.dtype)
+    out = helper.create_tmp_variable("float32", stop_gradient=True)
+    helper.append_op("vocab_logits", {"X": x, "W": w}, {"Out": out}, {})
+    return out
+
+
+def rms_norm(x, param_attr=None, epsilon=1e-5, out_dtype=None, name=None):
+    """RMS normalisation over the last axis with a learned scale
+    (ops/llm_ops.rms_norm), computed in float32; ``out_dtype`` is what the
+    next product reads."""
+    helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
+    from ..initializer import ConstantInitializer
+
+    scale = helper.create_parameter(
+        helper.param_attr, shape=[x.shape[-1]], dtype="float32",
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_tmp_variable(out_dtype or x.dtype,
+                                     stop_gradient=True)
+    attrs = {"epsilon": float(epsilon)}
+    if out_dtype is not None:
+        attrs["out_dtype"] = str(out_dtype)
+    helper.append_op("rms_norm", {"X": x, "Scale": scale}, {"Out": out},
+                     attrs)
+    return out
+
+
+def rotary_embedding(x, pos, rotary_dim, base=10000.0, name=None):
+    """Partial rotary position embedding of x [T, H, D] at positions pos
+    [T]: the first ``rotary_dim`` dims of every head rotate (halves), the
+    rest pass (ops/llm_ops.rotary_embedding)."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = helper.create_tmp_variable(x.dtype, stop_gradient=True)
+    helper.append_op("rotary_embedding", {"X": x, "Pos": pos}, {"Out": out},
+                     {"rotary_dim": int(rotary_dim), "base": float(base)})
+    return out
+
+
+def swiglu(gate, up, name=None):
+    """``silu(gate) * up``: the gate of a gated feed-forward."""
+    helper = LayerHelper("swiglu", name=name)
+    out = helper.create_tmp_variable(gate.dtype, stop_gradient=True)
+    helper.append_op("swiglu", {"Gate": gate, "Up": up}, {"Out": out}, {})
+    return out
+
+
+def routed_experts(x, n_experts, held, first_expert, top_k, d_inner,
+                   param_prefix, dtype=None, live=None, impl=None,
+                   name=None):
+    """A device's share of a routed-expert layer (ops/llm_ops.
+    routed_experts): routes over all ``n_experts`` in float32 (``x`` is
+    the float32 norm output; sigmoid scores, a selection bias, ``top_k`` a
+    token), computes the ``held`` experts from ``first_expert`` on in
+    ``dtype``.  ``live`` [T] marks the rows that are a request's tokens;
+    the others make no pair.  Parameters, under ``param_prefix``:
+    ``router.w`` [d, n_experts] and ``router.bias`` [n_experts] (float32),
+    ``experts.gate.w`` / ``experts.up.w`` [held, d, d_inner],
+    ``experts.down.w`` [held, d_inner, d] (``dtype``).  Returns (out
+    [T, d] in ``dtype``, load [held] int32: pairs per held expert)."""
+    from ..param_attr import ParamAttr
+
+    helper = LayerHelper("routed_experts", name=name)
+    dtype = dtype or x.dtype
+    d = x.shape[-1]
+
+    def param(suffix, shape, dt):
+        return helper.create_parameter(
+            ParamAttr(name=f"{param_prefix}.{suffix}", keep_dtype=True),
+            shape=shape, dtype=dt)
+
+    inputs = {"X": x,
+              "RouterW": param("router.w", [d, n_experts], "float32"),
+              "RouterBias": param("router.bias", [n_experts], "float32"),
+              "WGate": param("experts.gate.w", [held, d, d_inner], dtype),
+              "WUp": param("experts.up.w", [held, d, d_inner], dtype),
+              "WDown": param("experts.down.w", [held, d_inner, d], dtype)}
+    if live is not None:
+        inputs["Live"] = live
+    out = helper.create_tmp_variable(dtype, stop_gradient=True)
+    load = helper.create_tmp_variable("int32", stop_gradient=True)
+    attrs = {"top_k": int(top_k), "first_expert": int(first_expert)}
+    if impl is not None:
+        attrs["impl"] = impl
+    helper.append_op("routed_experts", inputs, {"Out": out, "Load": load},
+                     attrs)
+    return out, load
 
 
 # ---------------------------------------------------------------------------

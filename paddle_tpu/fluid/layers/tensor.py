@@ -8,7 +8,8 @@ from ..layer_helper import LayerHelper
 __all__ = ["create_tensor", "create_global_var", "fill_constant",
            "fill_constant_batch_size_like", "zeros", "ones", "concat",
            "sums", "assign", "cast", "argmax", "isfinite", "cache_write",
-           "paged_cache_write", "quantized_paged_cache_write",
+           "paged_cache_write", "paged_row_write",
+           "quantized_paged_cache_write",
            "paged_page_copy", "paged_page_gather", "paged_page_scatter"]
 
 
@@ -126,6 +127,21 @@ def paged_cache_write(pool, k, v, pages, offsets, layer, n_layer, out=None):
                      {"Out": out},
                      {"layer": int(layer), "n_layer": int(n_layer)})
     return out
+
+
+def paged_row_write(pool, value, pages, offsets, layer, n_layer):
+    """One row a token into ONE pool of a split key/value pair
+    (ops/llm_ops.paged_row_write): ``value`` [T, ...] at ``pages`` /
+    ``offsets`` [T].  Out is the pool itself, so donation makes it an
+    in-place row scatter."""
+    helper = LayerHelper("paged_row_write")
+    pool.stop_gradient = True
+    helper.append_op("paged_row_write",
+                     {"Pool": pool, "Value": value, "Pages": pages,
+                      "Offsets": offsets},
+                     {"Out": pool},
+                     {"layer": int(layer), "n_layer": int(n_layer)})
+    return pool
 
 
 def quantized_paged_cache_write(pool, scales, k, v, pages, offsets, layer,

@@ -12,6 +12,7 @@ from . import (  # noqa: F401
     ctc_ops,
     detection_ops,
     io_ops,
+    llm_ops,
     crf_ops,
     loss_ops,
     math_ops,

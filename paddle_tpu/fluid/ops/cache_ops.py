@@ -20,6 +20,7 @@ Both are inference-only (``no_grad``): training never builds them, and
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ..core.registry import primitive
@@ -172,10 +173,11 @@ def quantized_paged_cache_write(ctx, pool, scales, k, v, pages, offsets):
 
 
 @primitive("ragged_decode_attention",
-           inputs=["Q", "Pool", "PageTable", "Lengths", "QBase?", "Scales?"],
+           inputs=["Q", "Pool", "PageTable", "Lengths", "QBase?", "Scales?",
+                   "VPool?", "Sink?", "RingTop?"],
            outputs=["Out"], no_grad=True)
 def ragged_decode_attention(ctx, q, pool, page_table, lengths, q_base,
-                            scales):
+                            scales, v_pool=None, sink=None, ring_top=None):
     """Per-lane attention over the lane's page list — see
     kernels/flash_attention.ragged_decode_attention (q [B, C, H, D],
     pool [R, page_size, H*D], page_table [B, P] int32 logical pages,
@@ -194,6 +196,23 @@ def ragged_decode_attention(ctx, q, pool, page_table, lengths, q_base,
               sm_scale=ctx.attr("sm_scale", None),
               impl=ctx.attr("impl", None), scales=scales)
     mesh = _pmesh.current_mesh()
+    if v_pool is not None:
+        # a split pool pair (keys in Pool, values in VPool): grouped KV
+        # heads, a window, a sink, a ring of pages; attr ``out_scale``
+        # multiplies the result (a model's value scale)
+        if mesh is not None:
+            raise NotImplementedError(
+                "ragged_decode_attention: split pools are not mapped over "
+                "a mesh")
+        scope = ctx.attr("scope", None) or "attn/paged"
+        with jax.named_scope(scope):
+            out = _ra(q, pool, page_table, lengths, q_base, v_pool=v_pool,
+                      window=ctx.attr("window", None), sink=sink,
+                      ring_top=ring_top,
+                      kernel_name="paged_" + scope.replace("/", "_"), **kw)
+            scale = ctx.attr("out_scale", None)
+            return out if scale is None else \
+                (out.astype(jnp.float32) * float(scale)).astype(out.dtype)
     if mesh is not None:
         b_ax, h_ax = _pmesh.kernel_axes(mesh, batch=q.shape[0],
                                         heads=q.shape[2])

@@ -52,7 +52,7 @@ PALLAS_BWD_MIN_L = 1024
 
 __all__ = ["flash_attention", "flash_attention_sharded", "decode_attention",
            "ragged_decode_attention", "ragged_decode_attention_sharded",
-           "paged_kv_rows", "default_impl"]
+           "paged_kv_rows", "split_kv_rows", "default_impl"]
 
 
 def default_impl() -> str:
@@ -355,6 +355,290 @@ def _ragged_pallas(q, pool, page_table, lengths, q_base, layer, n_layer,
     return out.reshape(b, c, h, d)
 
 
+# ---------------------------------------------------------------------------
+# Split pools: grouped KV heads, unequal key/value widths, window, sink
+# ---------------------------------------------------------------------------
+#
+# A decoder-only model whose layers differ in cache shape declares one
+# pool pair per KIND of layer: keys in ``[R, page, Hkv*Dk]``, values in
+# ``[R, page, Hkv*Dv]``, both token-major, physical row = page *
+# n_layer + layer (``split_kv_rows``; page 0 is the trash page).  H query
+# heads read Hkv KV heads (head h reads h // (H/Hkv)).  A window layer
+# keeps its pages as a RING: the table's slot i holds the newest logical
+# page congruent to i, so the walk is as long as the ring, never as the
+# context; ``ring_top`` [B] is the logical page of each lane's newest
+# written token, from which a slot's key positions follow.
+
+
+def split_kv_rows(page_table, layer: int, n_layer: int):
+    """Logical page table -> physical rows of one layer in a split pool
+    pair (the same rows in the key pool and in the value pool)."""
+    return jnp.asarray(page_table).astype(jnp.int32) * n_layer + layer
+
+
+def _ring_page(slot, top, n_slots):
+    """The logical page ring slot ``slot`` holds when the newest written
+    page is ``top``: the largest page <= top congruent to slot."""
+    return top - jax.lax.rem(top - slot + n_slots, n_slots)
+
+
+def _split_xla(q, k_pool, v_pool, page_table, lengths, q_base, ring_top,
+               layer, n_layer, sm_scale, window, sink):
+    """Gather form: every addressed page, masked.  The reference for the
+    kernel below and the path of a host without Mosaic."""
+    b, c, h, dk = q.shape
+    _r, ps, kw = k_pool.shape
+    hkv = kw // dk
+    dv = v_pool.shape[2] // hkv
+    g = h // hkv
+    n_pages = page_table.shape[1]
+    rows = split_kv_rows(page_table, layer, n_layer)
+    k = k_pool[rows].reshape(b, n_pages * ps, hkv, dk).astype(q.dtype)
+    v = v_pool[rows].reshape(b, n_pages * ps, hkv, dv).astype(q.dtype)
+    slot = jnp.arange(n_pages, dtype=jnp.int32)[None, :]
+    if ring_top is not None:
+        page = _ring_page(slot, ring_top.astype(jnp.int32)[:, None],
+                          n_pages)
+    else:
+        page = jnp.broadcast_to(slot, (b, n_pages))
+    kpos = (page[:, :, None] * ps
+            + jnp.arange(ps, dtype=jnp.int32)[None, None, :]
+            ).reshape(b, 1, n_pages * ps)
+    qpos = (q_base.astype(jnp.int32)[:, None]
+            + jnp.arange(c, dtype=jnp.int32)[None, :])[:, :, None]
+    keep = (kpos >= 0) & (kpos < lengths.astype(jnp.int32)[:, None, None]) \
+        & (kpos <= qpos)
+    if window is not None:
+        keep = keep & (kpos > qpos - int(window))
+    s = jnp.einsum("bckgd,blkd->bkgcl", q.reshape(b, c, hkv, g, dk), k,
+                   preferred_element_type=jnp.float32) * jnp.float32(sm_scale)
+    keep = keep[:, None, None]                               # [B,1,1,C,L]
+    s = jnp.where(keep, s, -1e9)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if sink is not None:
+        sk = sink.astype(jnp.float32).reshape(1, hkv, g, 1, 1)
+        m = jnp.maximum(m, sk)
+    p = jnp.where(keep, jnp.exp(s - m), 0.0)
+    denom = jnp.sum(p, axis=-1, keepdims=True)
+    dead = denom == 0.0
+    if sink is not None:
+        denom = denom + jnp.exp(sk - m)
+    p = jnp.where(dead, 0.0, p / jnp.where(dead, 1.0, denom))
+    ctx = jnp.einsum("bkgcl,blkd->bckgd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return ctx.reshape(b, c, h, dv).astype(q.dtype)
+
+
+def _head_slices(n_head: int, d: int):
+    """How the kernel cuts head j out of a token-major [*, n_head*d] row:
+    (starts, width, offsets).  Mosaic slices lanes at multiples of 128
+    only, so head j's slice starts at the 128-multiple at or below j*d
+    and is ``width`` wide for every head; its own lanes lie ``offsets[j]``
+    into it.  Rows narrower than a tile (the tests' sizes) are taken
+    whole."""
+    row = n_head * d
+    if row % LANES:
+        return [0] * n_head, row, [j * d for j in range(n_head)]
+    starts = [(j * d // LANES) * LANES for j in range(n_head)]
+    width = max(-(-(j * d + d) // LANES) * LANES - starts[j]
+                for j in range(n_head))
+    starts = [min(st, row - width) for st in starts]
+    return starts, width, [j * d - starts[j] for j in range(n_head)]
+
+
+def _split_kernel(rows_ref, meta_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
+                  m_scr, l_scr, acc_scr, *, k_starts, k_width, v_starts,
+                  v_width, c, ps, n_pages, window, ring, sm_scale):
+    """grid (B, P) like ``_ragged_kernel``; q rides [Hkv, G*C, k_width]:
+    KV head j's rows stack the C queries of each of its G query heads,
+    zero outside the head's own lanes of its slice."""
+    b = pl.program_id(0)
+    p = pl.program_id(1)
+    hkv = len(k_starts)
+
+    @pl.when(p == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, -1e30)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    length = meta_ref[0, b]
+    base = meta_ref[1, b]
+    if ring:
+        p0 = _ring_page(p, meta_ref[2, b], n_pages) * ps
+    else:
+        p0 = p * ps
+    live = jnp.logical_and(p0 >= 0, p0 < length)
+    live = jnp.logical_and(live, p0 <= base + (c - 1))
+    if window is not None:
+        live = jnp.logical_and(live, p0 + ps > base - (window - 1))
+
+    @pl.when(live)
+    def _page():
+        k = k_ref[0]                       # [ps, Hkv*Dk]
+        v = v_ref[0]                       # [ps, Hkv*Dv]
+        for j in range(hkv):               # static KV-head loop
+            q = q_ref[0, j]                # [G*C, k_width]
+            kj = k[:, k_starts[j]:k_starts[j] + k_width]
+            vj = v[:, v_starts[j]:v_starts[j] + v_width]
+            if kj.dtype != q.dtype:
+                kj = kj.astype(q.dtype)
+                vj = vj.astype(q.dtype)
+            s = jax.lax.dot_general(q, kj, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * sm_scale
+            cols = p0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            if c > 1:
+                qpos = jax.lax.rem(qpos, jnp.int32(c))
+            else:
+                qpos = jnp.zeros_like(qpos)
+            qpos = qpos + base
+            keep = jnp.logical_and(cols < length, cols <= qpos)
+            if window is not None:
+                keep = jnp.logical_and(keep, cols > qpos - window)
+            s = jnp.where(keep, s, -1e30)
+            m_prev = m_scr[j]                              # [rows, LANES]
+            l_prev = l_scr[j]
+            m_cur = jnp.max(s, axis=1)[:, None]
+            m_new = jnp.maximum(m_prev,
+                                jnp.broadcast_to(m_cur, m_prev.shape))
+            alpha = jnp.exp(m_prev - m_new)
+            pr = jnp.where(keep, jnp.exp(s - m_new[:, :1]), 0.0)
+            l_scr[j] = alpha * l_prev + jnp.broadcast_to(
+                jnp.sum(pr, axis=1)[:, None], l_prev.shape)
+            m_scr[j] = m_new
+            pv = jax.lax.dot_general(pr.astype(vj.dtype), vj,
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            acc_scr[j] = acc_scr[j] * alpha[:, :1] + pv
+
+    @pl.when(p == n_pages - 1)
+    def _finalize():
+        l_fin = l_scr[...]
+        dead = l_fin == 0.0                # no key of the lane was live
+        if sink_ref is not None:
+            # the sink takes mass and gives no value
+            l_fin = l_fin + jnp.exp(sink_ref[...] - m_scr[...])
+        denom = jnp.where(dead, 1.0, l_fin)
+        out = jnp.where(dead[..., :1], 0.0, acc_scr[...] / denom[..., :1])
+        o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _split_pallas(q, k_pool, v_pool, page_table, lengths, q_base, ring_top,
+                  layer, n_layer, sm_scale, window, sink, interpret,
+                  name="ragged_paged_attn_gqa"):
+    b, c, h, dk = q.shape
+    _r, ps, kw = k_pool.shape
+    hkv = kw // dk
+    vw = v_pool.shape[2]
+    dv = vw // hkv
+    g = h // hkv
+    n_pages = page_table.shape[1]
+    rows = split_kv_rows(page_table, layer, n_layer)
+    ring = ring_top is not None
+    top = jnp.asarray(ring_top, jnp.int32).reshape(b) if ring \
+        else jnp.zeros(b, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32).reshape(b)
+    meta = jnp.stack([lengths, jnp.asarray(q_base, jnp.int32).reshape(b),
+                      top])
+    k_starts, k_width, k_offs = _head_slices(hkv, dk)
+    v_starts, v_width, v_offs = _head_slices(hkv, dv)
+    # [B, C, Hkv, G, Dk] -> per KV head [G*C, k_width], the head's Dk
+    # lanes at their place in its slice
+    qh = q.reshape(b, c, hkv, g, dk).transpose(0, 2, 3, 1, 4)
+    qh = qh.reshape(b, hkv, g * c, dk)
+    qk = jnp.stack([jnp.pad(qh[:, j], ((0, 0), (0, 0),
+                                       (k_offs[j],
+                                        k_width - dk - k_offs[j])))
+                    for j in range(hkv)], axis=1)
+    have_sink = sink is not None
+
+    def q_map(bi, pi, rw, mt):
+        return (bi, 0, 0, 0)
+
+    def kv_map(bi, pi, rw, mt):
+        return (rw[bi, pi], 0, 0)
+
+    in_specs = [pl.BlockSpec((1, hkv, g * c, k_width), q_map),
+                pl.BlockSpec((1, ps, kw), kv_map),
+                pl.BlockSpec((1, ps, vw), kv_map)]
+    args = [qk, k_pool, v_pool]
+    if have_sink:
+        sk = jnp.broadcast_to(
+            jnp.asarray(sink, jnp.float32).reshape(hkv, g, 1, 1),
+            (hkv, g, c, LANES)).reshape(hkv, g * c, LANES)
+        in_specs.append(pl.BlockSpec((hkv, g * c, LANES),
+                                     lambda bi, pi, rw, mt: (0, 0, 0)))
+        args.append(sk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, n_pages),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, hkv, g * c, v_width), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((hkv, g * c, LANES), jnp.float32),
+            pltpu.VMEM((hkv, g * c, LANES), jnp.float32),
+            pltpu.VMEM((hkv, g * c, v_width), jnp.float32),
+        ],
+    )
+    base = functools.partial(
+        _split_kernel, k_starts=tuple(k_starts), k_width=k_width,
+        v_starts=tuple(v_starts), v_width=v_width, c=c, ps=ps,
+        n_pages=n_pages, window=None if window is None else int(window),
+        ring=ring, sm_scale=sm_scale)
+
+    def kernel(rows_ref, meta_ref, q_ref, k_ref, v_ref, *rest):
+        rest = list(rest)
+        sink_ref = rest.pop(0) if have_sink else None
+        return base(rows_ref, meta_ref, q_ref, k_ref, v_ref, sink_ref, *rest)
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g * c, v_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(rows, meta, *args)
+    out = jnp.stack([out[:, j, :, v_offs[j]:v_offs[j] + dv]
+                     for j in range(hkv)], axis=1)       # [B,Hkv,G*C,Dv]
+    out = out.reshape(b, hkv, g, c, dv).transpose(0, 3, 1, 2, 4)
+    return out.reshape(b, c, h, dv)
+
+
+# Mosaic's default scoped VMEM on a v5e: the split kernel asks for no more
+SPLIT_VMEM_BYTES = 16 * 1024 * 1024
+
+
+def split_query_tile(chunk: int, n_head: int, kv_heads: int, d_key: int,
+                     d_value: int, page_size: int, itemsize: int,
+                     vmem_bytes: Optional[int] = None) -> int:
+    """How many queries of a prompt chunk one lane of the split kernel
+    takes: the largest of chunk, chunk / 2, chunk / 4 ... whose blocks fit
+    ``vmem_bytes`` (default ``SPLIT_VMEM_BYTES``).  A lane holds, per query and query head: q and the
+    output (double-buffered blocks), the running max, sum and float32
+    accumulator, the sink's block; besides a page of keys and values
+    (double-buffered) and one KV head's scores, mask and probabilities."""
+    _, k_width, _ = _head_slices(kv_heads, d_key)
+    _, v_width, _ = _head_slices(kv_heads, d_value)
+    per_row = 2 * (k_width + v_width) * itemsize \
+        + (2 * LANES + v_width) * 4 + 2 * LANES * 4
+    pages = 2 * page_size * kv_heads * (d_key + d_value) * itemsize
+
+    def need(c):
+        return n_head * c * per_row + pages \
+            + 3 * (n_head // kv_heads) * c * page_size * 4
+
+    if vmem_bytes is None:
+        vmem_bytes = SPLIT_VMEM_BYTES
+    c = int(chunk)
+    while c % 2 == 0 and need(c) > vmem_bytes:
+        c //= 2
+    return c
+
+
 def _resolve_q_base(q, q_base, causal: bool):
     if q_base is not None:
         return q_base
@@ -368,7 +652,10 @@ def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
                             *, layer: int, n_layer: int, causal: bool = True,
                             sm_scale: Optional[float] = None,
                             impl: Optional[str] = None,
-                            scales=None) -> jax.Array:
+                            scales=None, v_pool=None,
+                            window: Optional[int] = None, sink=None,
+                            ring_top=None,
+                            kernel_name: Optional[str] = None) -> jax.Array:
     """Attention of per-lane query blocks against a paged KV pool.
 
     Shapes:
@@ -392,6 +679,27 @@ def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
     q_base = _resolve_q_base(q, q_base, causal)
     if impl is None:
         impl = default_impl()
+    if v_pool is not None:
+        # split pools (keys in ``pool``, values in ``v_pool``, row =
+        # page * n_layer + layer): grouped KV heads (H a multiple of
+        # Hkv = pool width / Dk), values of another width than keys,
+        # ``window`` (keys q-window < j <= q), ``sink`` [H] (a logit per
+        # query head in the softmax's denominator only) and ``ring_top``
+        # [B] (the table is a ring of pages: see ``split_kv_rows``);
+        # ``kernel_name`` is the Mosaic call's name in a device trace
+        if scales is not None or not causal:
+            raise ValueError("ragged_decode_attention: split pools are "
+                             "causal and take no int8 scales")
+        args = (q, pool, v_pool, page_table, lengths, q_base, ring_top,
+                layer, n_layer, float(sm_scale), window, sink)
+        if impl in ("pallas", "pallas_interpret"):
+            return _split_pallas(
+                *args, interpret=(impl == "pallas_interpret"),
+                name=kernel_name or "ragged_paged_attn_gqa")
+        return _split_xla(*args)
+    if window is not None or sink is not None or ring_top is not None:
+        raise ValueError("ragged_decode_attention: window, sink and "
+                         "ring_top need split pools (v_pool)")
     if impl in ("pallas", "pallas_interpret"):
         return _ragged_pallas(q, pool, page_table, lengths, q_base, layer,
                               n_layer, causal, float(sm_scale),

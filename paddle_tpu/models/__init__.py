@@ -17,3 +17,20 @@ from . import (  # noqa: F401
     sentiment,
     word2vec,
 )
+
+
+# Decoder-only language models the paged engine serves
+# (``serving.paged_lm.PagedLMGenerator``): the module named like the
+# ``model_type`` of the published configuration gives ``config_from_dict``,
+# ``cache_specs``, ``param_shapes`` and ``build_serve_step``.
+DECODER_LMS = ("mimo_v2_flash",)
+
+
+def decoder_lm(model_type: str):
+    """The builder module of a decoder-only model, by its ``model_type``."""
+    import importlib
+
+    if model_type not in DECODER_LMS:
+        raise KeyError(f"no decoder-only model of model_type {model_type!r} "
+                       f"(known: {sorted(DECODER_LMS)})")
+    return importlib.import_module(f"{__name__}.{model_type}")

@@ -60,7 +60,8 @@ from .engine import InferenceEngine  # noqa: F401
 from .decoder import FullRerunDecoder, TransformerGenerator  # noqa: F401
 from .paged_decoder import (PagedTransformerGenerator,  # noqa: F401
                             copy_weights, kv_page_bytes)
-from .paging import PageAllocator, PoolCapacityError  # noqa: F401
+from .paged_lm import PagedLMGenerator  # noqa: F401
+from .paging import PageAllocator, PageGroup, PoolCapacityError  # noqa: F401
 from .scheduler import (ContinuousBatchingScheduler, Request,  # noqa: F401
                         RequestCancelled, SchedulerShutdown)
 from .constraints import (Constraint, DFAConstraint,  # noqa: F401
@@ -69,7 +70,8 @@ from .speculative import SpeculativeGenerator  # noqa: F401
 from .sessions import SessionStore  # noqa: F401
 
 __all__ = ["InferenceEngine", "TransformerGenerator", "FullRerunDecoder",
-           "PagedTransformerGenerator", "PageAllocator", "copy_weights",
+           "PagedTransformerGenerator", "PagedLMGenerator", "PageAllocator",
+           "PageGroup", "copy_weights",
            "kv_page_bytes", "PoolCapacityError",
            "ContinuousBatchingScheduler", "Request", "RequestCancelled",
            "SchedulerShutdown", "SpeculativeGenerator", "Constraint",

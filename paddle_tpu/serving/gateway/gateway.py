@@ -237,6 +237,10 @@ class Gateway:
             # below would only exercise the verify program
             inst.aot_warm(n_slots)
             return
+        if callable(getattr(inst, "step_variants", None)):
+            # a generator with several step programs (one per number of
+            # prefill chunks a step carries): resolve them all
+            inst.aot_warm(n_slots)
         if hasattr(inst, "lane_step"):
             inst.open_slots(n_slots)
             prompt = np.full(min(2, getattr(inst, "src_len", 2)),

@@ -49,6 +49,8 @@ from ..engine import DEFAULT_BATCH_BUCKETS, InferenceEngine
 from ..paged_decoder import (PagedTransformerGenerator, _CACHE_MARKERS,
                              build_manifest_program,
                              estimate_generator_hbm, model_axis_of)
+from ..paged_common import load_artifact_tensors
+from ..paged_lm import LM_CONFIG_KEYS, PagedLMGenerator, estimate_lm_hbm
 from ..scheduler import HBMBudgetError, suggest_model_axis
 from ..speculative import SpeculativeGenerator, estimate_speculative_hbm
 
@@ -303,6 +305,11 @@ class ModelRegistry:
             plan = estimate_generator_hbm(config,
                                           assume_donation=donation)
             return int(plan.peak_bytes), dict(plan.components)
+        if kind == "lm_generator":
+            # the decoder-only generator: parameters in the type they
+            # are resident in, a pool pair per kind of layer
+            plan = estimate_lm_hbm(config, assume_donation=donation)
+            return int(plan.peak_bytes), dict(plan.components)
         if kind == "engine" and dirname:
             model_path = os.path.join(dirname, "__model__")
             if os.path.isfile(model_path):
@@ -397,6 +404,8 @@ class ModelRegistry:
             self._charge(cost, key, components)
             if kind == "generator":
                 instance = self._build_generator(dirname, config)
+            elif kind == "lm_generator":
+                instance = self._build_lm_generator(dirname, config)
             elif kind == "engine":
                 exe = fluid.Executor(
                     self.place, compile_cache=_artifact_cache(dirname))
@@ -405,7 +414,8 @@ class ModelRegistry:
                     quantize=config.pop("quantize", "off"), **config)
             else:
                 raise ValueError(f"{dirname}: unknown artifact kind "
-                                 f"{kind!r} (engine or generator)")
+                                 f"{kind!r} (engine, generator or "
+                                 f"lm_generator)")
             with self._lock:
                 self._entries[key] = _Entry(key, name, version, kind,
                                             instance, cost, dirname)
@@ -503,14 +513,31 @@ class ModelRegistry:
                              compile_cache=_artifact_cache(dirname))
         gen = PagedTransformerGenerator(place=self.place, executor=exe,
                                         **config)
-        for n in os.listdir(dirname):
-            path = os.path.join(dirname, n)
-            if n == MANIFEST_NAME or not os.path.isfile(path):
-                continue
-            gen.scope.set_var(n, fluid.io.load_tensor(path))
+        load_artifact_tensors(gen.scope, dirname, skip=(MANIFEST_NAME,))
         # one upload at load, not per first request (the engine
         # to_device contract); the pool vars are already device zeros
         fluid.io.device_put_persistables(gen.scope, gen._unified[0])
+        return gen
+
+    def _build_lm_generator(self, dirname: str,
+                            config: Dict) -> PagedLMGenerator:
+        """A ``kind: "lm_generator"`` artifact: the decoder-only paged
+        generator.  The artifact holds float32 masters; each tensor goes
+        into the type the step program declares it in (bfloat16 residency
+        of the matrices under ``dtype: bfloat16``) ONE TENSOR AT A TIME,
+        so loading never holds the model twice."""
+        bad = set(config) - set(LM_CONFIG_KEYS)
+        if bad:
+            raise ValueError(f"{dirname}: unknown lm_generator config "
+                             f"keys {sorted(bad)}")
+        exe = fluid.Executor(self.place,
+                             compile_cache=_artifact_cache(dirname))
+        gen = PagedLMGenerator(place=self.place, executor=exe, **config)
+        load_artifact_tensors(
+            gen.scope, dirname, skip=(MANIFEST_NAME,),
+            cast=None if gen.layout["dtype"] == "float32"
+            else gen.param_dtypes())
+        fluid.io.device_put_persistables(gen.scope)
         return gen
 
     def register(self, name: str, version: str, instance,
@@ -537,6 +564,8 @@ class ModelRegistry:
         self._charge(int(hbm_bytes), key, components)
         kind = ("generator"
                 if isinstance(instance, PagedTransformerGenerator)
+                else "lm_generator"
+                if isinstance(instance, PagedLMGenerator)
                 else "speculative"
                 if isinstance(instance, SpeculativeGenerator)
                 else "engine" if isinstance(instance, InferenceEngine)

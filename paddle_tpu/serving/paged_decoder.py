@@ -47,6 +47,8 @@ from ..fluid.core.lod import SeqArray
 from ..observability import tracing as _obs_tracing
 from ..models import transformer as T
 from .decoder import _Cfg, dense_kv_bytes_per_slot
+from .paged_common import (ceil_div, pool_variable, token_slots,
+                           zero_pool)
 from .paging import (PageAllocator, PoolCapacityError, TRASH_PAGE,
                      chunk_hashes)
 
@@ -56,8 +58,6 @@ __all__ = ["PagedTransformerGenerator", "copy_weights", "kv_page_bytes",
            "model_axis_of", "check_shardable"]
 
 
-def _ceil_div(a: int, b: int) -> int:
-    return -(-a // b)
 
 
 _KV_ITEMSIZE = {"float32": 4, "bfloat16": 2, "int8": 1}
@@ -126,8 +126,8 @@ def default_num_pages(src_len: int, max_out_len: int,
                       page_size: int) -> int:
     """The ctor's pool-sizing default: room for ~8 worst-case requests
     (+ the trash page)."""
-    p_src = _ceil_div(src_len, page_size)
-    p_out = _ceil_div(max_out_len, page_size)
+    p_src = ceil_div(src_len, page_size)
+    p_out = ceil_div(max_out_len, page_size)
     return 8 * (2 * p_src + p_out) + 1
 
 
@@ -204,8 +204,8 @@ def build_unified_program(cfg: _Cfg, *, src_len: int, max_out_len: int,
     c = cfg
     C = int(chunk_size)
     K = int(verify_tokens)
-    p_src = _ceil_div(int(src_len), int(page_size))
-    p_out = _ceil_div(int(max_out_len), int(page_size))
+    p_src = ceil_div(int(src_len), int(page_size))
+    p_out = ceil_div(int(max_out_len), int(page_size))
     pool_shape = [int(num_pages) * c.n_layer * 2, int(page_size),
                   c.n_head * c.d_key]
     scales_shape = [1, int(num_pages) * c.n_layer * 2, int(page_size)]
@@ -435,8 +435,8 @@ class PagedTransformerGenerator:
         self.chunk = int(chunk_size)
         self.prefix_sharing = bool(prefix_sharing)
         self.topk_size = topk_size
-        self.p_src = _ceil_div(self.src_len, self.page_size)
-        self.p_out = _ceil_div(self.max_out_len, self.page_size)
+        self.p_src = ceil_div(self.src_len, self.page_size)
+        self.p_out = ceil_div(self.max_out_len, self.page_size)
         if num_pages is None:
             # shared with estimate_generator_hbm: the registry's static
             # admission plan must price the pool the ctor allocates
@@ -496,30 +496,25 @@ class PagedTransformerGenerator:
 
     # -- device pool ---------------------------------------------------------
     def _reset_pool(self):
-        import jax.numpy as jnp
-
-        pool = jnp.zeros(self._pool_shape, self.kv_dtype)
+        sharding = None
         if self.mesh is not None:
             # lay the pool out sharded from birth: a pool sized for the
             # MESH (num_pages beyond one chip's HBM) must never
             # materialise single-device
-            import jax
             from jax.sharding import NamedSharding, PartitionSpec
 
-            pool = jax.device_put(pool, NamedSharding(
-                self.mesh, PartitionSpec(None, None, self.shard_axis)))
-        self.scope.set_var(self._pool_name, pool)
+            sharding = NamedSharding(
+                self.mesh, PartitionSpec(None, None, self.shard_axis))
+        zero_pool(self.scope, self._pool_name, self._pool_shape,
+                  self.kv_dtype, sharding)
         if self.kv_dtype == "int8":
-            self.scope.set_var(self._scales_name,
-                               jnp.zeros(self._scales_shape, jnp.float32))
+            zero_pool(self.scope, self._scales_name, self._scales_shape,
+                      "float32")
 
     def _pool_var(self, block):
-        v = block.create_var(name=self._pool_name,
-                             shape=list(self._pool_shape),
-                             dtype=self.kv_dtype, persistable=True)
-        if self.shard_axis:
-            v.set_sharding((None, None, self.shard_axis))
-        return v
+        return pool_variable(
+            block, self._pool_name, self._pool_shape, self.kv_dtype,
+            (None, None, self.shard_axis) if self.shard_axis else None)
 
     def _scales_var(self, block):
         """The int8 pool's fp32 block-scale sidecar (None for float
@@ -528,9 +523,8 @@ class PagedTransformerGenerator:
         int8 bytes land in."""
         if self.kv_dtype != "int8":
             return None
-        return block.create_var(name=self._scales_name,
-                                shape=list(self._scales_shape),
-                                dtype="float32", persistable=True)
+        return pool_variable(block, self._scales_name, self._scales_shape,
+                             "float32")
 
     # -- program builders ----------------------------------------------------
     def _build_unified(self):
@@ -616,10 +610,10 @@ class PagedTransformerGenerator:
 
     # -- admission accounting ------------------------------------------------
     def _prompt_pages(self, n_tokens: int) -> int:
-        return _ceil_div(max(1, int(n_tokens)), self.page_size)
+        return ceil_div(max(1, int(n_tokens)), self.page_size)
 
     def _self_pages(self, max_new: int) -> int:
-        return _ceil_div(int(max_new), self.page_size) if max_new else 0
+        return ceil_div(int(max_new), self.page_size) if max_new else 0
 
     def _resolve_max_new(self, max_new: Optional[int]) -> int:
         """None -> the generator's cap; 0 stays 0 (beam reserves no self
@@ -932,7 +926,7 @@ class PagedTransformerGenerator:
         if rec is None:
             return False
         ps = self.page_size
-        n_self_used = _ceil_div(rec["pos"], ps) if rec["pos"] else 0
+        n_self_used = ceil_div(rec["pos"], ps) if rec["pos"] else 0
         ok = False
         try:
             cross = self._tier_download(rec["cross_table"])
@@ -1005,7 +999,7 @@ class PagedTransformerGenerator:
         n_cross = int(meta["n_cross"])
         n_self_used = int(meta["n_self"])
         n_self = min(self.p_out, max(n_self_used,
-                                     _ceil_div(pos + mn, ps)))
+                                     ceil_div(pos + mn, ps)))
         try:
             pages = self.alloc.alloc(n_cross + n_self)
         except PoolCapacityError:
@@ -1133,11 +1127,10 @@ class PagedTransformerGenerator:
             feed["pf_len"][slot] = done + m
             feed["enc_table"][slot, :len(lane.enc_table)] = lane.enc_table
             pos = done + np.arange(m)
-            feed["enc_pages"][slot, :m] = [lane.enc_table[p // ps]
-                                           for p in pos]
-            feed["cross_pages"][slot, :m] = [lane.cross_table[p // ps]
-                                             for p in pos]
-            feed["w_offsets"][slot, :m] = pos % ps
+            feed["enc_pages"][slot, :m], feed["w_offsets"][slot, :m] = \
+                token_slots(lane.enc_table, pos, ps)
+            feed["cross_pages"][slot, :m], _ = \
+                token_slots(lane.cross_table, pos, ps)
         return feed
 
     def _decode_arrays(self, n_tokens: int = 1) -> Dict[str, np.ndarray]:

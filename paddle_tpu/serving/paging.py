@@ -64,8 +64,8 @@ import numpy as np
 
 from ..utils.sync import RANK_COLLECTOR_INIT, OrderedLock
 
-__all__ = ["PageAllocator", "HostPool", "PoolCapacityError", "TRASH_PAGE",
-           "chunk_hashes", "affinity_key"]
+__all__ = ["PageAllocator", "PageGroup", "HostPool", "PoolCapacityError",
+           "TRASH_PAGE", "chunk_hashes", "affinity_key"]
 
 TRASH_PAGE = 0
 
@@ -545,3 +545,99 @@ class PageAllocator:
 
     def note_cow(self) -> None:
         self._stats["cow_copies"] += 1
+
+
+# ---------------------------------------------------------------------------
+# Page groups: one request, several kinds of cache (ISSUE 28)
+# ---------------------------------------------------------------------------
+# A model whose layers differ in what they keep of a token (every position
+# in a global-attention layer, the last ``window`` in a sliding-window one)
+# has one split pool pair per KIND of layer, and a request holds pages of
+# each.  A ``PageGroup`` is one kind's allocator: a free list over its own
+# pool and an account of what each holder may still take.  Admission
+# RESERVES a holder's worst case in every group, so a page taken inside a
+# reservation never fails and a step never has to preempt; the pages
+# themselves are taken when the context reaches them and, in a window
+# group, given back as soon as they fall behind the window (the ring).
+
+
+class PageGroup:
+    """Free list + reservations over ``num_pages`` pages of ``page_size``
+    tokens (page 0 is the reserved trash page)."""
+
+    def __init__(self, name: str, num_pages: int, page_size: int):
+        if num_pages < 2:
+            raise ValueError(f"page group {name!r} needs >= 2 pages (page "
+                             f"0 is the reserved trash page)")
+        self.name = name
+        self.num_pages = int(num_pages)
+        self.page_size = int(page_size)
+        self._free: List[int] = list(range(self.num_pages - 1, 0, -1))
+        self._reserved: Dict[object, int] = {}  # holder -> pages it may hold
+        self._held: Dict[object, int] = {}      # holder -> pages it holds
+        self.taken = 0                          # pages handed out, ever
+        self.recycled = 0                       # given back by a live holder
+
+    @property
+    def total_usable(self) -> int:
+        return self.num_pages - 1
+
+    def in_use(self) -> int:
+        return self.total_usable - len(self._free)
+
+    def unreserved(self) -> int:
+        """Pages no holder has a claim on."""
+        return self.total_usable - sum(self._reserved.values())
+
+    def can_reserve(self, n: int) -> bool:
+        return int(n) <= self.unreserved()
+
+    def reserve(self, holder, n: int) -> None:
+        if holder in self._reserved:
+            raise RuntimeError(f"page group {self.name!r}: {holder!r} "
+                               f"already holds a reservation")
+        if not self.can_reserve(n):
+            raise PoolCapacityError(
+                f"page group {self.name!r}: {n} pages asked, "
+                f"{self.unreserved()} of {self.total_usable} unreserved")
+        self._reserved[holder] = int(n)
+        self._held[holder] = 0
+
+    def shrink(self, holder, n: int) -> None:
+        """Lower a reservation to ``n`` (a window lane past its prefill
+        needs fewer pages than a chunk spans)."""
+        if n < self._held[holder]:
+            raise RuntimeError(f"page group {self.name!r}: {holder!r} "
+                               f"holds {self._held[holder]} pages, cannot "
+                               f"shrink to {n}")
+        self._reserved[holder] = min(self._reserved[holder], int(n))
+
+    def take(self, holder) -> int:
+        """One page, inside ``holder``'s reservation."""
+        if self._held[holder] >= self._reserved[holder]:
+            raise PoolCapacityError(
+                f"page group {self.name!r}: {holder!r} is at its "
+                f"reservation of {self._reserved[holder]} pages")
+        self._held[holder] += 1
+        self.taken += 1
+        return self._free.pop()
+
+    def give(self, holder, page: int) -> None:
+        """A page back while the holder lives on: a window page that fell
+        behind the window."""
+        self._held[holder] -= 1
+        self.recycled += 1
+        self._free.append(int(page))
+
+    def release(self, holder, pages: Sequence[int]) -> None:
+        """The holder is done: its pages and its reservation go."""
+        self._free.extend(int(p) for p in pages)
+        self._reserved.pop(holder, None)
+        self._held.pop(holder, None)
+
+    def stats(self) -> Dict[str, int]:
+        return {"num_pages": self.num_pages, "page_size": self.page_size,
+                "in_use": self.in_use(), "free": len(self._free),
+                "reserved": sum(self._reserved.values()),
+                "holders": len(self._reserved), "taken": self.taken,
+                "recycled": self.recycled}

@@ -561,6 +561,8 @@ class ContinuousBatchingScheduler:
             group = self._group_for(model)
         if group is None:
             raise KeyError(f"submit: no model registered for {model!r}")
+        # the prompt cap: an encoder-decoder model's source length, a
+        # decoder-only model's longest prompt (both under ``src_len``)
         src_cap = getattr(group.model, "src_len", None)
         if src_cap is not None and len(np.asarray(src_tokens)) > src_cap:
             # reject HERE, synchronously in the caller's thread — a
@@ -568,7 +570,7 @@ class ContinuousBatchingScheduler:
             # the loop for every other in-flight request
             raise ValueError(
                 f"submit: prompt length {len(np.asarray(src_tokens))} "
-                f"exceeds the model's src_len {src_cap}")
+                f"exceeds the model's prompt cap (src_len) {src_cap}")
         if decode is not None and \
                 not getattr(group.model, "speculative_aware", False):
             if decode.get("constraint") is None \
@@ -1268,6 +1270,12 @@ class ContinuousBatchingScheduler:
             if hasattr(model, "shard_plan"):
                 # mesh shape + per-shard pool residency for /statusz
                 out["kv"]["shard"] = model.shard_plan()
+        sole = default or (groups[0] if len(groups) == 1 else None)
+        if sole is not None and callable(getattr(sole.model, "counters",
+                                                 None)):
+            # what the engine's step has done since load (pairs routed
+            # here, pages in use and recycled by group)
+            out["engine"] = sole.model.counters()
         # latency percentiles cover successfully served requests only (a
         # request failed at admission has no admitted timestamp)
         ok = [r for r in done if r.error is None]

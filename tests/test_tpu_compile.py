@@ -105,3 +105,55 @@ def test_unified_step_updates_the_pool_in_place_on_v5e(one_chip,
     assert set(kinds) <= {"parameter", "bitcast"}, kinds
     pool_bytes = n_elems * 4
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
+
+
+@pytest.mark.parametrize("kind, hkv, ps, table, window", [
+    ("global", 4, 256, 33, None), ("window", 8, 128, 4, 128)])
+@pytest.mark.parametrize("b, c", [(64, 1), (16, 32)],
+                         ids=["decode", "prefill-tile"])
+def test_split_pool_kernel_compiles_for_v5e(kind, hkv, ps, table, window,
+                                            b, c, one_chip):
+    """The decoder-only model's attention (ISSUE 28) at its published
+    widths, in bfloat16: 64 query heads on 4 or 8 KV heads, keys 192 wide
+    (cut out of the token-major row in tile-aligned slices of 256) and
+    values 128, a window with a sink over a ring of 4 pages, for a decode
+    step's single query and for a prefill tile of 32."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels.flash_attention import ragged_decode_attention
+
+    ring = window is not None
+
+    def call(q, kp, vp, tbl, lengths, base, top, sink):
+        return ragged_decode_attention(
+            q, kp, tbl, lengths, base, layer=1, n_layer=2, impl="pallas",
+            v_pool=vp, window=window, sink=sink if ring else None,
+            ring_top=top if ring else None, kernel_name=f"paged_attn_{kind}")
+
+    ints = np.zeros(b, np.int32)
+    args = (jnp.zeros((b, c, 64, 192), jnp.bfloat16),
+            jnp.zeros((64, ps, hkv * 192), jnp.bfloat16),
+            jnp.zeros((64, ps, hkv * 128), jnp.bfloat16),
+            np.zeros((b, table), np.int32), ints, ints, ints,
+            np.zeros(64, np.float32))
+    hlo = jax.jit(call).lower(*_shapes(args, one_chip)).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"paged_attn_{kind}" in hlo
+
+
+@pytest.mark.parametrize("m, k, n", [(4608, 4096, 2048), (512, 2048, 4096)])
+def test_grouped_expert_product_compiles_for_v5e(m, k, n, one_chip):
+    """The expert layer's grouped product at its published widths: 8 held
+    experts' stacked bfloat16 matrices, rows for the worst case of a step
+    (576 tokens x 8) and of a decode-only one."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels.grouped_matmul import grouped_matmul
+
+    args = (jnp.zeros((m, k), jnp.bfloat16),
+            jnp.zeros((8, k, n), jnp.bfloat16), np.zeros(8, np.int32))
+    hlo = jax.jit(lambda a, w, g: grouped_matmul(a, w, g, impl="pallas")) \
+        .lower(*_shapes(args, one_chip)).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
