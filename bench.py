@@ -1889,8 +1889,7 @@ def bench_shardprop_child() -> None:
         pred = infer_sharding(prog, options=opts, fetch=fetch)
         best = min(best, _t.perf_counter() - t0)
 
-    feed = gen._prefill_arrays()
-    feed.update(gen._decode_arrays(1))
+    feed = gen._step_feed()
     with fluid.scope_guard(gen.scope), pmesh.mesh_guard(gen.mesh):
         meas = gen.exe.collective_analysis(prog, feed=feed,
                                            fetch_list=[next_ids],

@@ -29,7 +29,7 @@ signature, which is the static form of the zero-recompile guarantee.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Mapping, Optional, Sequence
 
 from .dataflow import ProgramView
 from .diagnostics import ERROR, INFO, WARNING, Diagnostics, Finding
@@ -81,7 +81,9 @@ def _dyn_axes(vd) -> List[int]:
 def enumerate_buckets(view: ProgramView,
                       batch_buckets: Sequence[int] = (),
                       time_buckets: Sequence[int] = (),
-                      block_idx: int = 0) -> List[Dict[str, Any]]:
+                      block_idx: int = 0,
+                      leading: Optional[Mapping[str, int]] = None
+                      ) -> List[Dict[str, Any]]:
     """Enumerate the closed set of feed signatures this program can
     compile to, given the declared bucket axes.
 
@@ -93,7 +95,13 @@ def enumerate_buckets(view: ProgramView,
     An open axis (dynamic but no buckets declared for it) is returned
     symbolically (``None``) — the signature set is NOT closed and the
     caller (plint / the AOT cache) must treat it as a hazard.
+
+    ``leading`` names batch-dynamic feeds whose leading extent is NOT
+    the shared batch bucket but the given one (the paged generator's
+    prefill tower leads with its own width, so its closed set is one
+    call of this function per width).
     """
+    leading = leading or {}
     feeds = feed_vars(view, block_idx)
     batch_dynamic = any(0 in _dyn_axes(vd) for vd in feeds.values())
     ragged = any(vd.lod_level > 0 for vd in feeds.values())
@@ -117,8 +125,8 @@ def enumerate_buckets(view: ProgramView,
                         if d is not None and d >= 0:
                             continue
                         if i == 0:
-                            shape[i] = bb
-                            closed = closed and bb is not None
+                            shape[i] = leading.get(name, bb)
+                            closed = closed and shape[i] is not None
                         else:
                             shape[i] = None
                             closed = False

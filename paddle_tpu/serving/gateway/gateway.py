@@ -238,8 +238,9 @@ class Gateway:
             inst.aot_warm(n_slots)
             return
         if callable(getattr(inst, "step_variants", None)):
-            # a generator with several step programs (one per number of
-            # prefill chunks a step carries): resolve them all
+            # a generator with several step executables (one per number
+            # of prefill chunks a step carries, or per width of the
+            # prefill tower): resolve them all
             inst.aot_warm(n_slots)
         if hasattr(inst, "lane_step"):
             inst.open_slots(n_slots)

@@ -20,7 +20,12 @@ Attention model (PAPERS.md, arxiv 2604.15464):
   lanes — admission no longer stalls decode behind a monolithic
   prefill dispatch, and there is no separate prefill executable to
   warm (feed the dense baseline ``make_attn_bias(..., causal=True)``
-  for exact parity);
+  for exact parity).  The prefill half is fed one row per lane that
+  PREFILLS, padded to one of a few widths derived from the lane count
+  (``tower_widths``: an eighth of the lanes, or all of them), so a
+  step whose lanes mostly decode does not encode, project and
+  page-write a chunk of dead rows for each of them; each width is
+  one executable of the same program, all resolved at load;
 * **prefix sharing**: full prompt chunks are content-addressed
   (chain hashes) so identical prompt prefixes — a common system
   prompt — map to the same physical pages with refcounts; beam lanes
@@ -37,7 +42,7 @@ from __future__ import annotations
 import contextlib
 import hashlib
 import json
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -55,7 +60,8 @@ from .paging import (PageAllocator, PoolCapacityError, TRASH_PAGE,
 __all__ = ["PagedTransformerGenerator", "copy_weights", "kv_page_bytes",
            "build_unified_program", "build_manifest_program",
            "estimate_generator_hbm", "default_num_pages",
-           "model_axis_of", "check_shardable"]
+           "model_axis_of", "check_shardable", "tower_widths",
+           "unified_bucket_set", "TOWER_FEEDS"]
 
 
 
@@ -129,6 +135,42 @@ def default_num_pages(src_len: int, max_out_len: int,
     p_src = ceil_div(src_len, page_size)
     p_out = ceil_div(max_out_len, page_size)
     return 8 * (2 * p_src + p_out) + 1
+
+
+# feeds of the chunked-prefill tower.  Their leading axis is the tower's
+# WIDTH (one row per prefilling lane, padded to a member of
+# ``tower_widths``); every other feed of the unified step keeps the
+# lane count
+TOWER_FEEDS = ("pf_word", "pf_pos", "pf_base", "pf_len", "enc_table",
+               "enc_pages", "cross_pages", "w_offsets")
+
+
+def tower_widths(n_slots: int) -> Tuple[int, ...]:
+    """The closed set of prefill-tower widths at a lane count, derived
+    and never configured: an eighth of the lanes, and the lanes.  The
+    largest is always the lane count, so a burst that admits every lane
+    at once still prefills them all in ONE step — no request ever waits
+    for a tower row, and scheduling is what it was when the tower ran
+    over every lane.  Each width is one executable (``aot_warm``
+    resolves them all; ``bucket_set`` lists them)."""
+    b = int(n_slots)
+    return tuple(sorted({max(1, b // 8), b}))
+
+
+def unified_bucket_set(program, n_slots: int):
+    """The closed compile-signature set of a unified step program
+    (``build_unified_program``, any ``verify_tokens``) at a lane count:
+    one signature per tower width — the tower's feeds lead with the
+    width, every other feed with the lane count (PR 10's
+    ``enumerate_buckets``)."""
+    from ..fluid.analysis.dataflow import ProgramView
+    from ..fluid.analysis.recompile import enumerate_buckets
+
+    view = ProgramView(program.desc)
+    return [entry for w in tower_widths(n_slots)
+            for entry in enumerate_buckets(
+                view, batch_buckets=(int(n_slots),),
+                leading=dict.fromkeys(TOWER_FEEDS, w))]
 
 
 # mesh axes reserved for batch (data) sharding on the serving mesh —
@@ -474,7 +516,11 @@ class PagedTransformerGenerator:
                                  page_bytes=self.page_bytes)
         self._lanes: List[_Lane] = []
         self._slots = 0
+        self._widths: Tuple[int, ...] = ()
+        self._tower_width = 0
         self._steps = 0
+        self._rows_fed = self._rows_live = 0
+        self._steps_by_width: Dict[int, int] = {}
         self._tracer = _obs_tracing.tracer()
         self._beam_steps: Dict[int, tuple] = {}
         self._decode_prog = None
@@ -529,10 +575,12 @@ class PagedTransformerGenerator:
     # -- program builders ----------------------------------------------------
     def _build_unified(self):
         """ONE program = one dispatch: the chunked-prefill tower (causal
-        encoder chunk + cross-KV page writes) AND the paged decode step
-        over every lane.  Lanes not in a given phase ride along with
-        trash-page writes and length-1 masks — so any mix of admitting /
-        prefilling / decoding lanes replays the same executable."""
+        encoder chunk + cross-KV page writes) over the lanes that
+        prefill AND the paged decode step over every lane.  Tower rows
+        beyond the prefilling lanes, and lanes that do not decode, ride
+        along with trash-page writes and length-1 masks — so any mix of
+        admitting / prefilling / decoding lanes replays one of a few
+        executables, one per tower width (``tower_widths``)."""
         self._unified = build_unified_program(
             self.cfg, src_len=self.src_len, max_out_len=self.max_out_len,
             page_size=self.page_size, num_pages=self.num_pages,
@@ -659,6 +707,11 @@ class PagedTransformerGenerator:
             for slot in range(len(self._lanes)):
                 self.clear_slot(slot)
         self._slots = int(n_slots)
+        self._widths = tower_widths(self._slots)
+        for width in self._widths:
+            # every key before the first step: ``counters()`` is read
+            # from other threads while the step loop counts
+            self._steps_by_width.setdefault(width, 0)
         self._lanes = [_Lane() for _ in range(self._slots)]
 
     def admit_slot(self, slot: int, src_tokens_1d,
@@ -1099,37 +1152,54 @@ class PagedTransformerGenerator:
         lane.enc_owned = []
         lane.enc_table = []
 
-    def _prefill_arrays(self) -> Dict[str, np.ndarray]:
-        """The chunked-prefill half of a unified-program feed: one
-        source chunk per lane in phase ``prefill`` (recording each
-        lane's ``pending_chunk``); every other lane rides trash-page
-        writes.  Pair with ``_absorb_prefill()`` AFTER the dispatch ran
-        — the split lets the speculative generator (ISSUE 15) drive the
-        same prefill machinery through its own verify/draft programs."""
-        B, C, ps = self._slots, self.chunk, self.page_size
-        feed = {"pf_word": np.zeros((B, C), np.int64),
-                "pf_pos": np.zeros((B, C), np.int64),
-                "pf_base": np.zeros(B, np.int32),
-                "pf_len": np.ones(B, np.int32),
-                "enc_table": np.zeros((B, self.p_src), np.int32),
-                "enc_pages": np.full((B, C), TRASH_PAGE, np.int32),
-                "cross_pages": np.full((B, C), TRASH_PAGE, np.int32),
-                "w_offsets": np.zeros((B, C), np.int32)}
-        for slot, lane in enumerate(self._lanes):
-            if lane.phase != "prefill":
-                continue
+    def _prefill_arrays(self, width: Optional[int] = None
+                        ) -> Dict[str, np.ndarray]:
+        """The chunked-prefill half of a unified-program feed: one ROW
+        per lane in phase ``prefill``, in slot order (recording each
+        lane's ``pending_chunk``), padded to the smallest member of
+        ``tower_widths`` that holds them; the padding rows ride
+        trash-page writes.  The tower's feeds (``TOWER_FEEDS``) carry
+        that width as their leading axis while the decode half keeps
+        the lane count, so a step pays for the lanes that prefill and
+        not for every lane.  ``width`` forces a (large enough) width:
+        warm-up resolves each one with it, and the lowering-only
+        reports price the widest.  Pair with ``_absorb_prefill()``
+        AFTER the dispatch ran — the split lets the speculative
+        generator (ISSUE 15) drive the same prefill machinery through
+        its own verify/draft programs."""
+        C, ps = self.chunk, self.page_size
+        slots = [slot for slot, lane in enumerate(self._lanes)
+                 if lane.phase == "prefill"]
+        if width is None:
+            P = next(w for w in self._widths if w >= len(slots))
+        elif len(slots) <= int(width):
+            P = int(width)
+        else:
+            raise ValueError(f"tower width {width} cannot hold "
+                             f"{len(slots)} prefilling lanes")
+        self._tower_width = P
+        feed = {"pf_word": np.zeros((P, C), np.int64),
+                "pf_pos": np.zeros((P, C), np.int64),
+                "pf_base": np.zeros(P, np.int32),
+                "pf_len": np.ones(P, np.int32),
+                "enc_table": np.zeros((P, self.p_src), np.int32),
+                "enc_pages": np.full((P, C), TRASH_PAGE, np.int32),
+                "cross_pages": np.full((P, C), TRASH_PAGE, np.int32),
+                "w_offsets": np.zeros((P, C), np.int32)}
+        for row, slot in enumerate(slots):
+            lane = self._lanes[slot]
             done = lane.enc_done
             m = min(C, lane.s_true - done)
             lane.pending_chunk = m
-            feed["pf_word"][slot, :m] = lane.src[done:done + m]
-            feed["pf_pos"][slot, :m] = np.arange(done, done + m)
-            feed["pf_base"][slot] = done
-            feed["pf_len"][slot] = done + m
-            feed["enc_table"][slot, :len(lane.enc_table)] = lane.enc_table
+            feed["pf_word"][row, :m] = lane.src[done:done + m]
+            feed["pf_pos"][row, :m] = np.arange(done, done + m)
+            feed["pf_base"][row] = done
+            feed["pf_len"][row] = done + m
+            feed["enc_table"][row, :len(lane.enc_table)] = lane.enc_table
             pos = done + np.arange(m)
-            feed["enc_pages"][slot, :m], feed["w_offsets"][slot, :m] = \
+            feed["enc_pages"][row, :m], feed["w_offsets"][row, :m] = \
                 token_slots(lane.enc_table, pos, ps)
-            feed["cross_pages"][slot, :m], _ = \
+            feed["cross_pages"][row, :m], _ = \
                 token_slots(lane.cross_table, pos, ps)
         return feed
 
@@ -1184,10 +1254,15 @@ class PagedTransformerGenerator:
         dec["src_lengths"][slot] = lane.s_true
 
     def _absorb_prefill(self) -> None:
-        """Post-dispatch bookkeeping for ``_prefill_arrays``: advance
-        each prefilling lane past its pending chunk (emitting the trace
-        instant AFTER the dispatch returned — a chunk that never ran
-        must not appear in the request timeline)."""
+        """Post-dispatch bookkeeping for ``_prefill_arrays``: count the
+        step and the tower rows it fed, and advance each prefilling
+        lane past its pending chunk (emitting the trace instant AFTER
+        the dispatch returned — a chunk that never ran must not appear
+        in the request timeline)."""
+        P = self._tower_width
+        self._steps += 1
+        self._steps_by_width[P] = self._steps_by_width.get(P, 0) + 1
+        self._rows_fed += P * self.chunk
         for slot, lane in enumerate(self._lanes):
             if lane.phase != "prefill":
                 continue
@@ -1197,21 +1272,25 @@ class PagedTransformerGenerator:
                 tokens=lane.pending_chunk,
                 done=lane.enc_done + lane.pending_chunk,
                 total=lane.s_true, **who)
+            self._rows_live += lane.pending_chunk
             lane.enc_done += lane.pending_chunk
             lane.pending_chunk = 0
             if lane.enc_done >= lane.s_true:
                 self._finish_prefill(lane)
 
-    def lane_step(self) -> Dict[int, int]:
+    def lane_step(self, tower_width: Optional[int] = None
+                  ) -> Dict[int, int]:
         """ONE dispatch over every lane: prefill lanes advance one
         source chunk, decode lanes emit one token.  Returns
-        {slot: token} for the lanes that decoded."""
+        {slot: token} for the lanes that decoded.  ``tower_width`` is
+        warm-up's (``aot_warm``): serving leaves the width to the
+        number of lanes that prefill."""
         B = self._slots
         if B == 0:
             raise RuntimeError("open_slots() before lane_step()")
         tr = self._tracer
         with tr.span("engine/feed_build", cat="serving"):
-            feed = self._prefill_arrays()
+            feed = self._prefill_arrays(tower_width)
             dec = self._decode_arrays()
             decoding: List[int] = []
             for slot, lane in enumerate(self._lanes):
@@ -1228,7 +1307,6 @@ class PagedTransformerGenerator:
         with tr.span("engine/fetch", cat="serving"):
             # the host blocks here until the device has finished the step
             ids = np.asarray(nxt).reshape(B)
-        self._steps += 1
         with tr.span("engine/absorb", cat="serving"):
             self._absorb_prefill()
             emitted: Dict[int, int] = {}
@@ -1407,33 +1485,35 @@ class PagedTransformerGenerator:
         return out_ids, np.asarray(out_scores)
 
     # -- AOT pre-resolution (ISSUE 14) ---------------------------------------
+    def step_variants(self) -> List[int]:
+        """The widths the prefill tower can take at the open lane count:
+        one executable of the unified step each.  A load path that
+        finds this resolves them all (``aot_warm``)."""
+        return list(self._widths)
+
     def bucket_set(self, n_slots: int):
         """The unified program's closed compile-signature set at the
-        given lane count — the batch axis is the ONLY dynamic feed
-        axis, so this enumerates to exactly one signature per serving
-        width (the static form of the zero-recompile guarantee, PR 10's
-        ``enumerate_buckets``)."""
-        from ..fluid.analysis.dataflow import ProgramView
-        from ..fluid.analysis.recompile import enumerate_buckets
-
-        return enumerate_buckets(ProgramView(self._unified[0].desc),
-                                 batch_buckets=(int(n_slots),))
+        given lane count: one signature per tower width (the static
+        form of the zero-recompile guarantee)."""
+        return unified_bucket_set(self._unified[0], n_slots)
 
     def aot_warm(self, n_slots: int) -> None:
-        """Resolve the unified executable AT THE SERVING LANE COUNT
-        without admitting any request: one all-idle ``lane_step`` —
-        every lane rides along with trash-page writes and length-1
-        masks, so no KV state or lane bookkeeping changes.  With a
-        persistent AOT cache attached to the executor this is a disk
-        load; without one it is the offline pre-compile that populates
-        the cache (``tools/aot_compile``).  Lanes are left open at
-        ``n_slots`` (the scheduler re-opens them at attach anyway)."""
+        """Resolve the unified executable AT THE SERVING LANE COUNT, at
+        every tower width, without admitting any request: one all-idle
+        ``lane_step`` each — every row rides along with trash-page
+        writes and length-1 masks, so no KV state or lane bookkeeping
+        changes.  With a persistent AOT cache attached to the executor
+        these are disk loads; without one they are the offline
+        pre-compile that populates the cache (``tools/aot_compile``).
+        Lanes are left open at ``n_slots`` (the scheduler re-opens them
+        at attach anyway)."""
         if any(lane.phase != "idle" for lane in self._lanes):
             raise RuntimeError(
                 "aot_warm: lanes are busy — pre-resolution is for "
                 "load/publish time, not mid-traffic")
         self.open_slots(int(n_slots))
-        self.lane_step()
+        for width in self._widths:
+            self.lane_step(tower_width=width)
 
     # -- accounting ----------------------------------------------------------
     def kv_bytes_per_slot_dense(self) -> int:
@@ -1479,6 +1559,17 @@ class PagedTransformerGenerator:
                             mesh_axes=self.mesh_axes)
         self._static_hbm_cache = (key, plan)
         return plan
+
+    def counters(self) -> Dict[str, object]:
+        """What the step has done since load, for ``sched.stats()``
+        ("engine") and the benchmark's per-layer readers: dispatches of
+        the unified program, by the tower width each took; the rows the
+        tower was fed (width x chunk a step) and how many of them were
+        a request's tokens."""
+        return {"steps": self._steps,
+                "steps_by_width": dict(self._steps_by_width),
+                "tower_rows_fed": self._rows_fed,
+                "tower_rows_live": self._rows_live}
 
     def cache_stats(self) -> Dict[str, object]:
         """Page / prefix / HBM accounting next to the executor's
@@ -1581,12 +1672,13 @@ class PagedTransformerGenerator:
         return out
 
     def _step_feed(self) -> Dict[str, np.ndarray]:
-        """A full unified-step feed at the open lane count, for the
-        lowering-only reports (call between requests: a prefilling
-        lane's pending chunk is recorded by ``_prefill_arrays``)."""
+        """A full unified-step feed at the open lane count and the
+        widest tower (the step's peak), for the lowering-only reports
+        (call between requests: a prefilling lane's pending chunk is
+        recorded by ``_prefill_arrays``)."""
         if not self._slots:
             raise RuntimeError("open_slots() before lowering the step")
-        feed = self._prefill_arrays()
+        feed = self._prefill_arrays(self._slots)
         feed.update(self._decode_arrays())
         return feed
 

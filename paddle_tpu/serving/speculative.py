@@ -65,7 +65,8 @@ from ..observability import tracing as _obs_tracing
 from ..utils.sync import RANK_CONSTRAINTS, OrderedLock
 from .constraints import Constraint, compile_constraint, masks_along
 from .paged_decoder import (HBM_ESTIMATE_LANES, PagedTransformerGenerator,
-                            build_unified_program, estimate_generator_hbm)
+                            build_unified_program, estimate_generator_hbm,
+                            unified_bucket_set)
 from .paging import TRASH_PAGE
 
 __all__ = ["SpeculativeGenerator", "estimate_speculative_hbm"]
@@ -504,15 +505,16 @@ class SpeculativeGenerator:
             st.d_pos = int(n_tokens)
 
     # -- dispatches ----------------------------------------------------------
-    def _dispatch_draft(self, plan: Dict[int, Tuple[int, object]]
-                        ) -> np.ndarray:
+    def _dispatch_draft(self, plan: Dict[int, Tuple[int, object]],
+                        tower_width: Optional[int] = None) -> np.ndarray:
         """One draft-program dispatch: draft prefill chunks for lanes
         still prefilling + one masked decode token per planned lane
         (``plan``: slot -> (input token, mask row or None)).  Returns
-        the [B] argmax ids."""
+        the [B] argmax ids.  ``tower_width`` is warm-up's, as in
+        ``PagedTransformerGenerator.lane_step``."""
         d = self.draft
         B = self._slots
-        feed = d._prefill_arrays()
+        feed = d._prefill_arrays(tower_width)
         dec = d._decode_arrays()
         mask = self._dmask
         for slot in self._dmask_dirty:
@@ -538,8 +540,8 @@ class SpeculativeGenerator:
         return np.asarray(out).reshape(B)
 
     def _dispatch_verify(self, rows: Dict[int, Tuple[List[int],
-                                                     Optional[List]]]
-                         ) -> np.ndarray:
+                                                     Optional[List]]],
+                         tower_width: Optional[int] = None) -> np.ndarray:
         """ONE target dispatch: chunked prefill for admitting lanes +
         k-token verification for ``rows`` (slot -> (input tokens, mask
         rows)).  Returns the [B, k+1] argmax ids."""
@@ -554,7 +556,7 @@ class SpeculativeGenerator:
             cow = self._cow_commit(cands, fresh)
             if cow:
                 self._dispatch_cow(cow)
-        feed = tgt._prefill_arrays()
+        feed = tgt._prefill_arrays(tower_width)
         dec = tgt._decode_arrays(K)
         mask = self._vmask
         for slot in self._vmask_dirty:
@@ -769,8 +771,9 @@ class SpeculativeGenerator:
 
     # -- AOT pre-resolution (ISSUE 14) ---------------------------------------
     def aot_warm(self, n_slots: int) -> None:
-        """Resolve the draft, verify, AND copy-on-write executables at
-        the serving lane count without admitting any request (all-idle
+        """Resolve the draft and verify executables at every width of
+        the prefill tower, AND the copy-on-write executable, at the
+        serving lane count without admitting any request (all-idle
         dispatches: trash-page writes, length-1 masks).  With persistent
         AOT caches mounted on the two executors these are disk loads —
         a pre-compiled version with a draft attached serves its first
@@ -781,8 +784,9 @@ class SpeculativeGenerator:
                 "aot_warm: lanes are busy — pre-resolution is for "
                 "load/publish time, not mid-traffic")
         self.open_slots(int(n_slots))
-        self._dispatch_draft({})
-        self._dispatch_verify({})
+        for width in self.target.step_variants():
+            self._dispatch_draft({}, tower_width=width)
+            self._dispatch_verify({}, tower_width=width)
         # one trash->trash pair: a no-op copy, but it forces the COW
         # executable through the compile/cache path (an empty pair list
         # dispatches nothing)
@@ -790,18 +794,17 @@ class SpeculativeGenerator:
 
     def bucket_set(self, n_slots: int):
         """The closed compile-signature set of the speculative pair at
-        the given lane count: the verify program, the draft program,
-        and the COW page-copy program — each with the batch axis as its
-        only dynamic feed axis (PR 10 ``enumerate_buckets``)."""
+        the given lane count: the verify program and the draft program,
+        each once per width of its prefill tower, and the COW page-copy
+        program (PR 10 ``enumerate_buckets``)."""
         from ..fluid.analysis.dataflow import ProgramView
         from ..fluid.analysis.recompile import enumerate_buckets
 
         prog = self._cow or self._build_cow()
-        out = []
-        for p in (self._verify[0], self._draft_prog[0], prog):
-            out.extend(enumerate_buckets(ProgramView(p.desc),
-                                         batch_buckets=(int(n_slots),)))
-        return out
+        return (unified_bucket_set(self._verify[0], n_slots)
+                + unified_bucket_set(self._draft_prog[0], n_slots)
+                + enumerate_buckets(ProgramView(prog.desc),
+                                    batch_buckets=(int(n_slots),)))
 
     # -- accounting ----------------------------------------------------------
     def static_hbm_estimate(self, assume_lanes: int = None):

@@ -55,7 +55,8 @@ def precompile(dirname: str, n_slots: int = 4,
     mounting are EXACTLY what serving does), then:
 
     * generator artifacts: ``aot_warm(n_slots)`` — the unified
-      prefill+decode executable at the serving lane count;
+      prefill+decode executable at the serving lane count, once per
+      step variant (``step_variants``: the prefill tower's widths);
     * engine artifacts: ``preresolve(max_time)`` — every enumerated
       batch/time bucket signature;
     * ``draft_dirname`` (ISSUE 15): warm the pair as a
@@ -146,7 +147,8 @@ def precompile(dirname: str, n_slots: int = 4,
     if callable(getattr(inst, "aot_warm", None)):
         kind = "generator"
         inst.aot_warm(int(n_slots))
-        signatures = 1
+        variants = getattr(inst, "step_variants", None)
+        signatures = len(variants()) if callable(variants) else 1
     else:
         kind = "engine"
         signatures = inst.preresolve(max_time=max_time)

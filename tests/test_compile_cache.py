@@ -244,7 +244,8 @@ def test_generator_registry_compiled_subdir_zero_compile_swap(tmp_path):
         report = precompile(
             fluid.io.model_version_dir(root, "m", version), n_slots=2)
         assert report["kind"] == "generator"
-        assert report["signatures"] == 1 and report["compiles"] == 1
+        # 2 lanes: the prefill tower is 1 or 2 rows wide, an executable each
+        assert report["signatures"] == 2 and report["compiles"] == 2
 
     reg = ModelRegistry(root=root, place=fluid.CPUPlace())
     gw = Gateway(registry=reg, n_slots=2, max_new_tokens=3)
@@ -333,6 +334,7 @@ def test_planner_prices_no_donation_dispatch():
 
 def test_generator_bucket_set_is_closed():
     from paddle_tpu.serving import PagedTransformerGenerator
+    from paddle_tpu.serving.paged_decoder import tower_widths
 
     gen = PagedTransformerGenerator(
         30, 30, n_layer=1, n_head=2, d_key=4, d_value=4, d_model=8,
@@ -340,8 +342,10 @@ def test_generator_bucket_set_is_closed():
         page_size=4, chunk_size=4, num_pages=32, param_prefix="tfd",
         place=fluid.CPUPlace())
     buckets = gen.bucket_set(n_slots=4)
-    assert len(buckets) == 1 and buckets[0]["closed"], \
-        "the unified program must enumerate to exactly ONE signature"
+    assert len(buckets) == len(tower_widths(4)) == 2 \
+        and all(b["closed"] for b in buckets), \
+        "the unified program must enumerate to exactly ONE signature " \
+        "per width of its prefill tower"
 
 
 def test_generator_publisher_ships_precompiled(tmp_path):
@@ -366,13 +370,14 @@ def test_generator_publisher_ships_precompiled(tmp_path):
     version = pub.publish(7)
     cdir = os.path.join(fluid.io.model_version_dir(root, "m", version),
                         "compiled")
-    assert os.path.isdir(cdir) and len(os.listdir(cdir)) == 1
+    # one executable per width of the prefill tower (1 and 2 rows)
+    assert os.path.isdir(cdir) and len(os.listdir(cdir)) == 2
     reg = ModelRegistry(root=root, place=fluid.CPUPlace())
     reg.load("m", version)
     inst = reg.instance("m")
     inst.aot_warm(2)
     st = inst.exe.cache_stats()["persistent"]
-    assert st["hits"] == 1 and st["misses"] == 0, st
+    assert st["hits"] == 2 and st["misses"] == 0, st
 
 
 # -- CLI ----------------------------------------------------------------------

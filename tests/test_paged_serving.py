@@ -17,6 +17,7 @@ from paddle_tpu.serving import (ContinuousBatchingScheduler,
                                 PageAllocator, PoolCapacityError,
                                 TransformerGenerator, copy_weights)
 from paddle_tpu.serving.decoder import pack_sources
+from paddle_tpu.serving.paged_decoder import TOWER_FEEDS, tower_widths
 from paddle_tpu.serving.paging import chunk_hashes
 
 V, NL, NH, DK, DM, DI = 24, 2, 2, 4, 16, 32
@@ -333,6 +334,173 @@ def test_prefill_and_decode_interleave_in_one_dispatch(paged_pair):
         paged.clear_slot(i)
     assert paged.cache_stats()["executable"]["misses"] == misses0
     paged.alloc.check_invariants()
+
+
+# -- the prefill tower's widths (ISSUE 29) ------------------------------------
+
+LANES = 16
+WIDTHS = tower_widths(LANES)          # (2, 16)
+
+
+@pytest.fixture(scope="module")
+def wide_pair():
+    """A 16-lane paged generator (tower widths 2 and 16, all resolved by
+    ``aot_warm``) beside the dense decoder, one scope.  No prefix cache:
+    a second run of the same prompts has to prefill them again."""
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    kw = dict(n_layer=NL, n_head=NH, d_key=DK, d_value=DK, d_model=DM,
+              d_inner_hid=DI, max_length=64, src_len=SRC, scope=scope,
+              executor=exe, param_prefix="tfw")
+    dense = TransformerGenerator(V, V, max_out_len=OUT,
+                                 causal_encoder=True, **kw)
+    paged = PagedTransformerGenerator(
+        V, V, max_out_len=OUT, page_size=PS, chunk_size=CHUNK,
+        num_pages=16 * LANES, prefix_sharing=False, **kw)
+    dense.init_params(seed=11)
+    paged.aot_warm(LANES)
+    return paged, dense
+
+
+def _bursts_peaking_at(width):
+    """Admission bursts, one a step, whose prefilling lanes a step peak
+    above the next smaller width and at most at ``width``."""
+    below = max([w for w in WIDTHS if w < width], default=0)
+    return [1, below + 1, 1] if below + 1 < width else [1, width, 1]
+
+
+def _drive(gen, seqs, bursts, force_width=None):
+    """Serve ``seqs`` on 16 lanes, admitting ``bursts`` lanes before
+    successive steps; returns (tokens per request, most lanes that
+    prefilled in one step)."""
+    gen.open_slots(LANES)
+    if force_width is not None:
+        gen._widths = (force_width,)
+    out = [[] for _ in seqs]
+    bursts, admitted, peak = list(bursts), 0, 0
+    while bursts or any(lane.phase in ("prefill", "decode")
+                        for lane in gen._lanes):
+        for _ in range(bursts.pop(0) if bursts else 0):
+            gen.admit_slot(admitted, seqs[admitted], max_new=OUT)
+            admitted += 1
+        peak = max(peak, sum(lane.phase == "prefill"
+                             for lane in gen._lanes))
+        for slot, tok in gen.lane_step().items():
+            out[slot].append(tok)
+        for slot, lane in enumerate(gen._lanes):
+            if lane.phase == "decode" and len(out[slot]) >= OUT:
+                lane.phase = "hold"
+    assert admitted == len(seqs)
+    gen.open_slots(LANES)           # lanes cleared, the derived widths back
+    return out, peak
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_tower_width_follows_prefilling_lanes_same_tokens(width,
+                                                          wide_pair):
+    """Staggered admissions that take the number of prefilling lanes up
+    to ``width`` and back: the tower is fed at the smallest width that
+    holds them, and every request gets token for token what the dense
+    decoder gives it and what a run held to the full width gives it."""
+    paged, dense = wide_pair
+    bursts = _bursts_peaking_at(width)
+    seqs, (tok, lens) = _sources(20 + width, n=sum(bursts))
+    ref = dense.greedy(tok, lens, max_new=OUT, stop_at_end=False)
+    before = paged.counters()["steps_by_width"]
+    got, peak = _drive(paged, seqs, bursts)
+    used = {w: n - before.get(w, 0)
+            for w, n in paged.counters()["steps_by_width"].items()
+            if n > before.get(w, 0)}
+    assert max(used) == width and peak <= width, (used, peak)
+    assert min(used) == WIDTHS[0], "an idle tower takes the least width"
+    full, _ = _drive(paged, seqs, bursts, force_width=LANES)
+    np.testing.assert_array_equal(np.asarray(got), ref)
+    np.testing.assert_array_equal(np.asarray(full), ref)
+    paged.alloc.check_invariants()
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_every_tower_width_is_warm_after_aot_warm(width, wide_pair):
+    """``aot_warm`` resolved one executable per width: traffic that
+    reaches ``width`` prefilling lanes a step, and every width below
+    it, adds no executable-cache miss."""
+    paged, _ = wide_pair
+    assert paged.step_variants() == list(WIDTHS)
+    misses0 = paged.cache_stats()["executable"]["misses"]
+    for w in [w for w in WIDTHS if w <= width]:
+        bursts = _bursts_peaking_at(w)
+        seqs, _ = _sources(40 + w, n=sum(bursts))
+        _drive(paged, seqs, bursts)
+    assert paged.cache_stats()["executable"]["misses"] == misses0
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_engine_counters_count_tower_rows(width, wide_pair):
+    """``counters()``: the live rows are the prompt tokens prefilled,
+    the rows fed are width x chunk a step, and the steps by width sum
+    to the steps."""
+    paged, _ = wide_pair
+    bursts = _bursts_peaking_at(width)
+    seqs, _ = _sources(60 + width, n=sum(bursts))
+    c0 = paged.counters()
+    _drive(paged, seqs, bursts)
+    c1 = paged.counters()
+    assert c1["tower_rows_live"] - c0["tower_rows_live"] == \
+        sum(len(s) for s in seqs)
+    by_width = {w: c1["steps_by_width"].get(w, 0)
+                - c0["steps_by_width"].get(w, 0) for w in WIDTHS}
+    assert sum(by_width.values()) == c1["steps"] - c0["steps"] > 0
+    assert sum(c1["steps_by_width"].values()) == c1["steps"]
+    assert c1["tower_rows_fed"] - c0["tower_rows_fed"] == \
+        sum(w * CHUNK * n for w, n in by_width.items())
+    assert by_width[width] > 0
+
+
+@pytest.mark.parametrize("lanes", [1, 4, 16, 64])
+def test_bucket_set_is_one_signature_per_tower_width(lanes, wide_pair):
+    """The closed set at a lane count: one signature per width, the
+    tower's eight feeds leading with the width and every other feed with
+    the lane count; nothing else."""
+    paged, _ = wide_pair
+    widths = tower_widths(lanes)
+    assert widths[-1] == lanes and widths[0] == max(1, lanes // 8)
+    buckets = paged.bucket_set(lanes)
+    assert len(buckets) == len(widths)
+    for width, entry in zip(widths, buckets):
+        assert entry["closed"] and entry["batch"] == lanes
+        lead = {name: f["shape"][0] for name, f in entry["feeds"].items()}
+        assert {n for n, d in lead.items() if d == width} >= \
+            set(TOWER_FEEDS)
+        assert all(d == lanes for n, d in lead.items()
+                   if n not in TOWER_FEEDS)
+        assert len(lead) == len(TOWER_FEEDS) + 9
+
+
+def test_prefill_feed_rows_are_prefilling_lanes_in_slot_order(wide_pair):
+    """The tower's feed is [width, chunk]: row i is the i-th prefilling
+    lane in slot order, the padding rows write the trash page, and a
+    width too small for the prefilling lanes is refused."""
+    paged, _ = wide_pair
+    seqs, _ = _sources(77, n=3)
+    paged.open_slots(LANES)
+    for slot, s in zip((9, 2, 5), seqs):
+        paged.admit_slot(slot, s, max_new=OUT)
+    feed = paged._prefill_arrays()
+    assert feed["pf_word"].shape == (LANES, CHUNK)      # 3 lanes > 2
+    for row, (slot, s) in enumerate(sorted(zip((9, 2, 5), seqs))):
+        m = min(CHUNK, len(s))
+        np.testing.assert_array_equal(feed["pf_word"][row, :m], s[:m])
+        assert feed["pf_len"][row] == m
+        assert paged._lanes[slot].pending_chunk == m
+    assert (feed["enc_pages"][3:] == 0).all() and \
+        (feed["pf_len"][3:] == 1).all()
+    with pytest.raises(ValueError, match="cannot hold"):
+        paged._prefill_arrays(width=2)
+    paged.clear_slot(5)
+    assert paged._prefill_arrays()["pf_word"].shape == (2, CHUNK)
+    assert paged._step_feed()["pf_word"].shape == (LANES, CHUNK)
+    for slot in (9, 2):
+        paged.clear_slot(slot)
 
 
 # -- prefix sharing -----------------------------------------------------------

@@ -460,8 +460,8 @@ def test_differential_sharded_decode_step(n):
     g.init_params(seed=1)
     g.open_slots(2)
     prog, _, next_ids, _ = g._unified
-    feed = g._prefill_arrays()
-    feed.update(g._decode_arrays(1))
+    feed = g._step_feed()      # the tower at its widest: the lane count
+    assert feed["pf_word"].shape[0] == feed["trg_word"].shape[0] == 2
     _assert_differential(f"decode model={n}", prog, ma, feed,
                          [next_ids], g.exe, g.scope, g.mesh, "infer", 2)
 
@@ -479,7 +479,7 @@ def test_differential_speculative_verify(n):
     sg.init_params(seed=1)
     sg.open_slots(2)
     vprog, _, vnext, _ = sg._verify
-    feed = tgt._prefill_arrays()
+    feed = tgt._prefill_arrays(width=2)   # the tower at its widest
     feed.update(tgt._decode_arrays(sg.verify_tokens))
     feed["logit_mask"] = sg._vmask
     _assert_differential(f"verify model={n}", vprog, ma, feed, [vnext],

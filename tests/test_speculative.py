@@ -25,6 +25,7 @@ from paddle_tpu.serving.constraints import (DFAConstraint, MASKED,
                                             TokenSetConstraint,
                                             compile_constraint)
 from paddle_tpu.serving.gateway import Gateway, ModelRegistry
+from paddle_tpu.serving.paged_decoder import tower_widths
 
 V, NL, NH, DK, DM, DI = 24, 2, 2, 4, 16, 32
 SRC, OUT, PS, CHUNK = 8, 8, 4, 4
@@ -143,6 +144,62 @@ def test_zero_recompiles_across_speculative_traffic(spec_pair):
     assert c1["executable"]["misses"] == c0["executable"]["misses"]
     assert c1["draft_executable"]["misses"] == \
         c0["draft_executable"]["misses"]
+
+
+SPEC_LANES = 16
+SPEC_WIDTHS = tower_widths(SPEC_LANES)        # (2, 16)
+
+
+@pytest.fixture(scope="module")
+def wide_spec():
+    """A 16-lane speculative pair (draft == target) whose ``aot_warm``
+    ran: the prefill tower of BOTH programs is 2 or 16 rows wide."""
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    kw = dict(KW, scope=scope, executor=exe, num_pages=16 * SPEC_LANES,
+              prefix_sharing=False)
+    target = PagedTransformerGenerator(V, V, param_prefix="wt", **kw)
+    draft = PagedTransformerGenerator(V, V, param_prefix="wd", **kw)
+    target.init_params(seed=5)
+    copy_weights(scope, scope, prefix="wt", dst_prefix="wd")
+    spec = SpeculativeGenerator(target, draft, k=3, draft_name="wd")
+    spec.aot_warm(SPEC_LANES)
+    return spec, target
+
+
+@pytest.mark.parametrize("width", SPEC_WIDTHS)
+def test_aot_warm_resolves_draft_and_verify_at_every_tower_width(
+        width, wide_spec):
+    """The pair's ``aot_warm`` resolved the draft AND the verify
+    program at every width of the prefill tower: a batch whose
+    prefilling lanes need ``width`` rows adds no executable-cache miss
+    on either executor, and decodes what the plain target decodes."""
+    spec, target = wide_spec
+    assert len(spec.bucket_set(SPEC_LANES)) == 2 * len(SPEC_WIDTHS) + 1
+    n = width if width < SPEC_LANES else SPEC_WIDTHS[-2] + 1
+    _, src, lens = _sources(seed=30 + width, n=SPEC_LANES)
+    lens[n:] = 0                                # the other lanes stay idle
+    c0 = spec.cache_stats()
+    by_width0 = dict(target.counters()["steps_by_width"])
+    spec.open_slots(SPEC_LANES)
+    for slot in range(n):
+        spec.admit_slot(slot, src[slot, :lens[slot]], max_new=OUT)
+    out = [[] for _ in range(n)]
+    while any(len(row) < OUT for row in out):
+        for slot, toks in spec.lane_step().items():
+            out[slot].extend(toks)
+    for slot in range(n):
+        spec.clear_slot(slot)
+    c1 = spec.cache_stats()
+    assert c1["executable"]["misses"] == c0["executable"]["misses"]
+    assert c1["draft_executable"]["misses"] == \
+        c0["draft_executable"]["misses"]
+    by_width = target.counters()["steps_by_width"]
+    assert by_width.get(width, 0) > by_width0.get(width, 0)
+    target.open_slots(SPEC_LANES)
+    ref = target.greedy(src[:n], lens[:n], max_new=OUT, stop_at_end=False)
+    np.testing.assert_array_equal(
+        np.asarray([row[:OUT] for row in out]), ref)
 
 
 # -- rollback / COW / invariants ---------------------------------------------
@@ -633,11 +690,14 @@ def test_registry_load_speculative_budget_and_aot(tmp_path):
     first = precompile(os.path.join(root, "big", "1"), n_slots=2,
                        draft_dirname=os.path.join(root, "small", "1"),
                        speculate_k=2)
-    assert first["kind"] == "speculative" and first["compiles"] == 3
+    # draft and verify at both widths of the prefill tower (1 and 2
+    # rows at 2 lanes), and the page copy
+    assert first["kind"] == "speculative" and first["compiles"] == 5
+    assert first["signatures"] == 5
     second = precompile(os.path.join(root, "big", "1"), n_slots=2,
                         draft_dirname=os.path.join(root, "small", "1"),
                         speculate_k=2)
-    assert second["compiles"] == 0 and second["loads"] == 3
+    assert second["compiles"] == 0 and second["loads"] == 5
     assert sorted(second["keys"]) == sorted(first["keys"])
 
     # a fresh registry load of the pre-compiled pair serves its first

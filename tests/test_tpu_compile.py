@@ -71,9 +71,12 @@ def test_ragged_kernel_compiles_for_v5e(kv_dtype, c, one_chip):
     assert hlo.count('custom_call_target="tpu_custom_call"') == 1
 
 
-def test_unified_step_updates_the_pool_in_place_on_v5e(one_chip,
+@pytest.mark.parametrize("tower", [1, 8])
+def test_unified_step_updates_the_pool_in_place_on_v5e(tower, one_chip,
                                                        monkeypatch):
-    """The donated unified step, compiled by the chip's compiler: every
+    """The donated unified step, compiled by the chip's compiler at both
+    widths its prefill tower takes at 8 lanes (ISSUE 29: one row, and a
+    row a lane, beside 8 decode lanes): every
     ragged attention is a Mosaic call, the pool is aliased to its output,
     no copy or transpose of pool size remains, and the step's temporaries
     are a fraction of the pool (the head-major pool needed six times
@@ -90,10 +93,13 @@ def test_unified_step_updates_the_pool_in_place_on_v5e(one_chip,
         executor=fluid.Executor(fluid.CPUPlace()))
     gen.init_params(seed=1)
     gen.open_slots(8)
+    assert gen.step_variants() == [1, 8]
     prog, _, next_ids, _ = gen._unified
+    feed = gen._prefill_arrays(tower)
+    feed.update(gen._decode_arrays())
     with fluid.scope_guard(gen.scope):
         feed, state, step = gen.exe._prepare_step(
-            prog, gen._step_feed(), [next_ids], gen.scope, "infer")
+            prog, feed, [next_ids], gen.scope, "infer")
     args = _shapes((feed, state, np.zeros(2, np.int32)), one_chip)
     compiled = jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
     hlo = compiled.as_text()

@@ -640,8 +640,7 @@ g = PagedTransformerGenerator(30, 30, n_layer=2, n_head=2, d_key=4,
 g.init_params(seed=1)
 g.open_slots(2)
 prog, _, next_ids, _ = g._unified
-feed = g._prefill_arrays()
-feed.update(g._decode_arrays(1))
+feed = g._step_feed()
 gate("decode-step model=2", prog, ma, feed, [next_ids], g.exe,
      g.scope, g.mesh, "infer", 2)
 
