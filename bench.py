@@ -1243,136 +1243,6 @@ def bench_release(trials: int, n_slots: int = 4, decode_len: int = 8):
         shutil.rmtree(tmp, ignore_errors=True)
 
 
-def bench_aot(trials: int, n_slots: int = 4, decode_len: int = 8):
-    """ISSUE 14: the persistent AOT executable cache's serving economics.
-
-    A generator artifact is published once; then, with everything
-    rebuilt fresh per phase (fresh programs, scopes, executors — the
-    in-process stand-in for a restarted process, honest because jax
-    keys its jit cache on function identity):
-
-    * **restart-to-first-token**, cold (empty ``compiled/``: the load
-      pays the XLA compile storm and STORES the executables) vs warm
-      (a second "process" deserializes them — the
-      ``run_supervised``-restart path);
-    * **swap-to-first-token**, cold (candidate ships no executables)
-      vs warm (candidate pre-compiled by ``tools/aot_compile``, the
-      publisher pipeline's path) — wall from ``swap_model`` entry to
-      the first token decoded by the new version;
-    * the contract flags: a warm process performs ZERO XLA compiles
-      before first token (``cache_stats()["persistent"]``) and
-      ``recompiles_after_warmup == 0`` holds across the warm swap.
-    """
-    import shutil
-    import tempfile
-
-    from paddle_tpu import fluid
-    from paddle_tpu.serving import PagedTransformerGenerator
-    from paddle_tpu.serving.gateway import ModelRegistry, Gateway
-    from paddle_tpu.tools.aot_compile import precompile
-
-    root = tempfile.mkdtemp(prefix="bench_aot_")
-    vocab, src_len = 2048, 32
-    kw = dict(n_layer=2, n_head=4, d_key=32, d_value=32, d_model=128,
-              d_inner_hid=256, max_length=src_len + decode_len + 2,
-              src_len=src_len, max_out_len=decode_len, page_size=8,
-              chunk_size=8, num_pages=4 * n_slots * 16 + 1)
-    try:
-        gen = PagedTransformerGenerator(vocab, vocab,
-                                        param_prefix="aot", **kw)
-        gen.init_params(seed=0)
-        for version in ("1", "2", "3"):
-            ModelRegistry.save_generator_artifact(gen, root, "m", version)
-        del gen
-        prompt = np.random.RandomState(0).randint(2, vocab, src_len // 2)
-
-        def first_token(version):
-            """Fresh registry+gateway (fresh executors) -> wall to the
-            first streamed token of ``version`` + its compile stats."""
-            reg = ModelRegistry(root=root, place=fluid.TPUPlace(0))
-            gw = Gateway(registry=reg, n_slots=n_slots,
-                         max_new_tokens=decode_len)
-            t0 = time.perf_counter()
-            gw.load_model("m", version)
-            gw.serve()
-            s = gw.submit_stream("m", prompt, timeout=300)
-            next(iter(s))
-            wall = time.perf_counter() - t0
-            list(s)
-            st = reg.instance("m").exe.cache_stats()["persistent"]
-            gw.shutdown(drain=True)
-            return wall, st
-
-        cold_walls, warm_walls = [], []
-        for t in range(max(2, trials)):
-            if t == 0:
-                # an EMPTY compiled/: the registry mounts the AOT tier
-                # only on artifacts that ship the directory
-                cdir = os.path.join(root, "m", "1", "compiled")
-                shutil.rmtree(cdir, ignore_errors=True)
-                os.makedirs(cdir)
-                cold_wall, cold_st = first_token("1")
-                cold_walls.append(cold_wall)
-            else:
-                wall, warm_st = first_token("1")
-                warm_walls.append(wall)
-        warm_wall = min(warm_walls)
-        cold_wall = min(cold_walls)
-
-        # swap legs: v1 serving, swap to v3 (cold) then restart the
-        # story and swap to v2 (pre-compiled offline)
-        precompile(fluid.io.model_version_dir(root, "m", "2"),
-                   n_slots=n_slots)
-
-        def swap_to(version):
-            reg = ModelRegistry(root=root, place=fluid.TPUPlace(0))
-            gw = Gateway(registry=reg, n_slots=n_slots,
-                         max_new_tokens=decode_len)
-            gw.load_model("m", "1")
-            gw.serve()
-            gw.generate("m", prompt, timeout=300)    # steady state
-            t0 = time.perf_counter()
-            gw.swap_model("m", version)
-            s = gw.submit_stream("m", prompt, timeout=300)
-            next(iter(s))
-            wall = time.perf_counter() - t0
-            list(s)
-            inst = reg.instance("m")
-            pst = inst.exe.cache_stats()["persistent"]
-            miss0 = inst.exe.cache_stats()["executable"]["misses"]
-            gw.generate("m", prompt, timeout=300)
-            recompiles = inst.exe.cache_stats()["executable"]["misses"] \
-                - miss0
-            gw.shutdown(drain=True)
-            return wall, pst, recompiles
-
-        swap_cold, _, _ = swap_to("3")
-        swap_warm, warm_swap_st, recompiles_after = swap_to("2")
-    finally:
-        shutil.rmtree(root, ignore_errors=True)
-    return {
-        "restart_to_first_token_s": {"cold": round(cold_wall, 3),
-                                     "warm": round(warm_wall, 3),
-                                     "speedup_x": round(
-                                         cold_wall / max(warm_wall, 1e-9),
-                                         2)},
-        "swap_to_first_token_s": {"cold": round(swap_cold, 3),
-                                  "warm": round(swap_warm, 3),
-                                  "speedup_x": round(
-                                      swap_cold / max(swap_warm, 1e-9),
-                                      2)},
-        "cold_process_compiles": int(cold_st["misses"]),
-        "warm_process_compiles": int(warm_st["misses"]),
-        "warm_persistent_hits": int(warm_st["hits"]),
-        "warm_swap_compiles": int(warm_swap_st["misses"]),
-        "recompiles_after_warmup": int(recompiles_after),
-        "zero_compile_contract": bool(
-            warm_st["misses"] == 0 and warm_st["hits"] > 0
-            and warm_swap_st["misses"] == 0
-            and recompiles_after == 0),
-    }
-
-
 def bench_fleet(trials: int, n_replicas: int = 2, decode_len: int = 8):
     """ISSUE 16: the multi-replica serving fleet's scaling and
     recovery story, measured at the FLEET layer (routing, health
@@ -2858,16 +2728,6 @@ def main() -> None:
         except Exception as e:
             print(f"release bench failed: {e}", file=sys.stderr)
 
-    aot_cmp = None
-    if os.environ.get("BENCH_SKIP_AOT", "") != "1":
-        try:
-            aot_cmp = bench_aot(
-                trials,
-                int(os.environ.get("BENCH_AOT_SLOTS", "4")),
-                int(os.environ.get("BENCH_AOT_DECODE", "8")))
-        except Exception as e:
-            print(f"aot bench failed: {e}", file=sys.stderr)
-
     fleet_cmp = None
     if os.environ.get("BENCH_SKIP_FLEET", "") != "1":
         try:
@@ -2988,12 +2848,6 @@ def main() -> None:
         # degraded-candidate auto-rollback cycle walls, with zero lost
         # requests and zero steady-state recompiles across both
         "release": release_cmp,
-        # persistent AOT executable cache (ISSUE 14): restart-to-first-
-        # token and swap-to-first-token cold vs warm, with the zero-
-        # compile contract (a warm process performs no XLA compiles
-        # before first token, and recompiles_after_warmup == 0 holds
-        # across a hot swap that loads a pre-compiled candidate)
-        "aot": aot_cmp,
         # multi-replica serving fleet (ISSUE 16): aggregate tok/s as
         # the replica count scales, affinity-vs-random prefix-chunk hit
         # rate, SIGKILL detect/rejoin wall clocks, and the exactly-once
@@ -3093,14 +2947,6 @@ def main() -> None:
             # the loop's safety contract IS the metric: a lost request
             # or a wrong verdict is a failed run, like a band violation
             missing.append("release_contract")
-    if os.environ.get("BENCH_SKIP_AOT", "") != "1":
-        if aot_cmp is None:
-            missing.append("aot")
-        elif not aot_cmp["zero_compile_contract"]:
-            # a warm process compiled, or a warm swap recompiled — the
-            # cache's entire contract failed; a failed run, like any
-            # perf regression
-            missing.append("aot_zero_compile_contract")
     if os.environ.get("BENCH_SKIP_FLEET", "") != "1":
         if fleet_cmp is None:
             missing.append("fleet")

@@ -709,7 +709,6 @@ def block_byte_plan(view: ProgramView, block_idx: int = 0,
                     assume_batch: int = 1,
                     sub_extra: Optional[Dict[int, int]] = None,
                     persistable_base: int = 0,
-                    assume_donation: bool = True,
                     mesh_axes: Optional[Dict[str, int]] = None
                     ) -> BlockBytePlan:
     """Build the liveness byte timeline for one block.
@@ -720,14 +719,9 @@ def block_byte_plan(view: ProgramView, block_idx: int = 0,
     aware aliasing, and per-op sub-block peaks (``sub_extra``: op idx ->
     extra transient bytes while that control-flow op runs).
     ``persistable_base`` is added to every timeline point (the resident
-    params/KV bytes the program-level planner accounts once).
-
-    ``assume_donation=False`` models an executable compiled WITHOUT
-    buffer donation (the persistent AOT cache's entries, ISSUE 14): a
-    written persistable no longer aliases its scope buffer in place, so
-    the new value is a fresh transient of full size live until the
-    dispatch returns — the pool/param write-back copy the donating jit
-    path avoids.  Dying-transient reuse still applies either way.
+    params/KV bytes the program-level planner accounts once).  Every
+    dispatch donates its state (``Executor._jit_step``), so a written
+    persistable aliases its scope buffer in place.
     """
     b = view.blocks[block_idx]
     plan = BlockBytePlan.__new__(BlockBytePlan)
@@ -799,7 +793,7 @@ def block_byte_plan(view: ProgramView, block_idx: int = 0,
                     continue
                 dies_here = live_range.get(r, (None, None))[1] == op.idx \
                     and r not in feed_last
-                donated = r_vd.persistable and assume_donation
+                donated = r_vd.persistable
                 if dies_here or donated:
                     aliases.union(r, n)
                     if donated:
@@ -829,28 +823,6 @@ def block_byte_plan(view: ProgramView, block_idx: int = 0,
         class_bytes[n] = nb
         class_members[n] = [n]
     plan.feed_bytes = feed_bytes_total
-
-    if not assume_donation:
-        # no-donation dispatch: every persistable the block WRITES
-        # (ParamOut in-place idiom — output name == persistable name —
-        # or a transient output the donating path would have aliased
-        # onto it) gets a FRESH output buffer of full size, live from
-        # its first write until the dispatch returns.  This is the
-        # pool/param write-back copy a persistent-AOT-cached executable
-        # really pays (ISSUE 14).
-        for op in b.ops:
-            for n in op.write_names():
-                vd = local.get(n)
-                if vd is None or not vd.persistable:
-                    continue
-                key = f"@nodonate@{n}"
-                if key in class_range:
-                    class_range[key][0] = min(class_range[key][0],
-                                              op.idx)
-                    continue
-                class_range[key] = [op.idx, max(0, len(b.ops) - 1)]
-                class_bytes[key] = vbytes(n)
-                class_members[key] = [key]
 
     sub_extra = sub_extra or {}
     n_ops = max(1, len(b.ops))
@@ -914,22 +886,17 @@ class ProgramMemoryPlan:
 
 
 def plan_program(view_or_program, assume_batch: int = 1,
-                 assume_donation: bool = True,
                  mesh_axes: Optional[Dict[str, int]] = None
                  ) -> ProgramMemoryPlan:
     """Peak-HBM plan over the whole program.  Persistables are counted
     once by name across every block (params vs KV state split via
     ``KV_POOL_MARKERS``); sub-block transient peaks are charged at
     their control-flow op's position in the parent timeline.
-    ``assume_donation=False`` prices the no-donation dispatch the
-    persistent AOT executable cache serves (see block_byte_plan) — the
-    gateway registry budgets with it whenever a version mounts a
-    ``compiled/`` cache, so admission never under-counts the write-back
-    copies real hardware will pay.  ``mesh_axes`` turns the plan into a
-    PER-SHARD footprint: vars with sharding annotations (params, the KV
-    pool) scale by their shard divisor while unannotated state (block
-    tables, feeds, activations) stays charged replicated — the
-    conservative side of GSPMD's actual partitioning."""
+    ``mesh_axes`` turns the plan into a PER-SHARD footprint: vars with
+    sharding annotations (params, the KV pool) scale by their shard
+    divisor while unannotated state (block tables, feeds, activations)
+    stays charged replicated — the conservative side of GSPMD's actual
+    partitioning."""
     view = view_or_program if isinstance(view_or_program, ProgramView) \
         else ProgramView(getattr(view_or_program, "desc", view_or_program))
     plan = ProgramMemoryPlan.__new__(ProgramMemoryPlan)
@@ -962,7 +929,6 @@ def plan_program(view_or_program, assume_batch: int = 1,
                  for op in b.ops if op.sub_blocks}
         bp = block_byte_plan(view, b.idx, assume_batch, sub_extra=extra,
                              persistable_base=0,
-                             assume_donation=assume_donation,
                              mesh_axes=mesh_axes)
         plan.approximate = plan.approximate or bp.approximate
         sub_peak[b.idx] = bp.peak_bytes

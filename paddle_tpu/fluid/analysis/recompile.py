@@ -15,14 +15,14 @@ visible in the descs:
   ``time_bucket``);
 * ops whose *output* shape or LoD depends on input values
   (``VALUE_SHAPE_OPS``) — no amount of input padding closes their
-  shape set, so they can never live inside an AOT-compiled bucket;
+  shape set, so they can never live inside a pre-compiled bucket;
 * a transient var with no recorded shape reached by shape inference —
   its extent is only knowable at run time.
 
 The flip side is the **closed bucket set**: once every dynamic axis is
 bucketed, the program's compilable signatures are a finite enumerable
-product — exactly the set an ahead-of-time executable cache must
-compile (ROADMAP item 4).  :func:`enumerate_buckets` produces it; a
+product — exactly the set a load-time warm-up must resolve to keep
+compiles out of traffic.  :func:`enumerate_buckets` produces it; a
 fully static program (the paged decode-step) enumerates to exactly ONE
 signature, which is the static form of the zero-recompile guarantee.
 """
@@ -94,7 +94,7 @@ def enumerate_buckets(view: ProgramView,
     shapes; a program with no dynamic axes returns exactly one entry.
     An open axis (dynamic but no buckets declared for it) is returned
     symbolically (``None``) — the signature set is NOT closed and the
-    caller (plint / the AOT cache) must treat it as a hazard.
+    caller (plint, a load-time warm-up) must treat it as a hazard.
 
     ``leading`` names batch-dynamic feeds whose leading extent is NOT
     the shared batch bucket but the given one (the paged generator's
@@ -191,7 +191,7 @@ def recompile_pass(ctx, diag: Diagnostics) -> None:
                 INFO, "recompile", "open-batch-axis",
                 f"feed '{name}' is batch-dynamic with no declared batch "
                 f"buckets — the bucket set is open (fine for training; "
-                f"a serving/AOT path must declare batch_buckets)",
+                f"a serving path must declare batch_buckets)",
                 block=0, var=name))
 
     # transient vars shape inference could not pin: their extents are
@@ -231,5 +231,5 @@ def recompile_pass(ctx, diag: Diagnostics) -> None:
         diag.add(Finding(
             INFO, "recompile", "bucket-set",
             f"bucket set is OPEN ({len(buckets)} enumerated "
-            f"signature(s), {hazards} hazard(s)) — an AOT cache cannot "
+            f"signature(s), {hazards} hazard(s)) — no warm-up can "
             f"pre-compile this program exhaustively"))

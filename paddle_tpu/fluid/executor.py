@@ -168,14 +168,8 @@ class Executor:
     # the block per step), so any bound here is strictly better
     CACHE_CAPACITY = 64
 
-    def __init__(self, place: Union[TPUPlace, CPUPlace, None] = None,
-                 compile_cache=None):
+    def __init__(self, place: Union[TPUPlace, CPUPlace, None] = None):
         self.place = place or TPUPlace(0)
-        # persistent AOT tier (fluid/compile_cache.py): None = use the
-        # process default (PADDLE_TPU_AOT_CACHE / set_default_cache),
-        # False = explicitly disabled, a CompileCache = use exactly it
-        # (the gateway registry mounts a per-version artifact cache)
-        self._compile_cache = compile_cache
         from collections import OrderedDict
 
         self._cache: "OrderedDict[tuple, Any]" = OrderedDict()
@@ -190,13 +184,6 @@ class Executor:
         self._stats = {
             "executable": {"hits": 0, "misses": 0, "evictions": 0},
             "structure": {"hits": 0, "misses": 0, "evictions": 0},
-            # the persistent AOT tier's view from THIS executor: hits =
-            # executables deserialized from disk instead of compiled,
-            # misses = XLA compiles paid while a cache was attached,
-            # stores = executables published back, bytes/load_ms = what
-            # the hits cost to read.  All zero when no cache is attached.
-            "persistent": {"hits": 0, "misses": 0, "stores": 0,
-                           "bytes": 0, "load_ms": 0.0},
             # pre-flight analysis (validate=...): "runs" = full analyses
             # performed, "cached" = dispatches that skipped re-analysis
             # because the (fingerprint, level) was already validated
@@ -242,12 +229,6 @@ class Executor:
                     "paddle_executor_cache_events_total", "counter",
                     (("cache", cache), ("event", ev)), float(st[ev]),
                     "Compiled-step / structure-classification cache events")
-        for ev in ("hits", "misses", "stores"):
-            yield Sample(
-                "paddle_executor_cache_events_total", "counter",
-                (("cache", "persistent"), ("event", ev)),
-                float(self._stats["persistent"][ev]),
-                "Compiled-step / structure-classification cache events")
         for cache, size in (("executable", len(self._cache)),
                             ("structure", len(self._cls_cache)),
                             ("validated", len(self._validated))):
@@ -287,13 +268,6 @@ class Executor:
         out = {k: dict(v) for k, v in self._stats.items()}
         out["executable"]["size"] = len(self._cache)
         out["structure"]["size"] = len(self._cls_cache)
-        out["persistent"]["load_ms"] = round(
-            out["persistent"]["load_ms"], 3)
-        aot = self._aot_cache()
-        if aot is not None:
-            # the attached directory's own view (shared with any other
-            # executor mounting the same dir) rides along for /statusz
-            out["persistent"]["cache"] = aot.stats()
         out["validate"]["size"] = len(self._validated)
         out["validate"]["by_level"] = {
             lv: dict(c) for lv, c in self._validate_by_level.items()}
@@ -455,80 +429,15 @@ class Executor:
                   f"evictions {st['evictions']})", file=sys.stderr)
         return None
 
-    def set_compile_cache(self, cache) -> None:
-        """Attach (or with False, disable; with None, defer to the
-        process default) the persistent AOT executable cache this
-        executor consults before compiling."""
-        self._compile_cache = cache
-
-    def _aot_cache(self):
-        if self._compile_cache is False:
-            return None
-        if self._compile_cache is not None:
-            return self._compile_cache
-        from . import compile_cache as _cc
-
-        return _cc.default_cache()
-
-    def _aot_compile(self, mem_key, step, example_args,
-                     in_shardings=None, mesh=None):
-        """Resolve one executable for ``step`` at ``example_args``'
-        signature, consulting the persistent AOT tier between the
-        in-memory cache (already missed) and XLA:
-
-        * persistent hit  -> deserialize_and_load, zero XLA compiles;
-        * persistent miss -> AOT lower+compile, then serialize + store
-          (compile-without-store when the backend can't serialize);
-        * no cache attached / multi-host / a lowering corner the AOT
-          path can't express -> the plain ``jax.jit`` wrapper, exactly
-          the pre-cache behavior.
-
-        The returned object is callable with the same (feeds, state,
-        rng_bits) calling convention either way.
-
-        Persistent-tier executables are compiled WITHOUT buffer
-        donation.  This is deliberate: jaxlib's
-        serialize_executable/deserialize_and_load mishandles donated-
-        input buffer ownership — a deserialized donating executable
-        chained over its own outputs returns nondeterministically
-        corrupted values and double-frees at teardown (found by this
-        repo's parity tests; the donating in-memory jit path is
-        untouched).  The cost is one extra output copy per aliased
-        state buffer per dispatch; the win is zero steady-state
-        compiles across restarts and swaps.  ``"donate": False`` rides
-        the entry key so a future donating scheme can never collide
-        with these entries."""
+    @staticmethod
+    def _jit_step(step, in_shardings=None):
+        """The one place a step function becomes an executable: state
+        (argument 1) is ALWAYS donated, so parameters, optimizer moments
+        and KV pools update in place.  Persistence across processes is
+        JAX's compilation cache (``paddle_tpu._place_compile_cache``)."""
         kwargs = {} if in_shardings is None else \
             {"in_shardings": in_shardings}
-        aot = self._aot_cache()
-        if aot is None or jax.process_count() > 1:
-            return jax.jit(step, donate_argnums=(1,), **kwargs)
-        pstats = self._stats["persistent"]
-        akey = aot.entry_key((mem_key, ("donate", False)))
-        read0 = aot._stats["bytes_read"]
-        t0 = time.perf_counter()
-        # the devices the entry was compiled for: the mesh's under SPMD
-        # (mem_key carries the mesh, so the entry cannot be another
-        # mesh's), else the one default device an unsharded jit targets
-        devices = (jax.devices()[:1] if mesh is None
-                   else list(mesh.devices.flat))
-        loaded = aot.load(akey, devices)
-        if loaded is not None:
-            pstats["hits"] += 1
-            pstats["bytes"] += aot._stats["bytes_read"] - read0
-            pstats["load_ms"] += (time.perf_counter() - t0) * 1e3
-            return loaded
-        pstats["misses"] += 1
-        try:
-            compiled = jax.jit(step, **kwargs).lower(
-                *example_args).compile()
-        except Exception:
-            # can't AOT-express this dispatch (exotic backend/tracing
-            # corner): serve it the way the pre-cache executor did
-            return jax.jit(step, donate_argnums=(1,), **kwargs)
-        if aot.store(akey, compiled):
-            pstats["stores"] += 1
-        return compiled
+        return jax.jit(step, donate_argnums=(1,), **kwargs)
 
     def _store_executable(self, key, entry) -> None:
         """Insert + LRU-evict with eviction accounting/narration."""
@@ -800,14 +709,12 @@ class Executor:
         accessed', ...} — WITHOUT executing it (jax lowering only).  The
         honest-MFU primitive VERDICT r1 weak#1 calls for: measured step
         time + these flops ⇒ delivered FLOP/s ÷ chip peak."""
-        import jax
-
         feed, state_vals, step = self._prepare_step(program, feed,
                                                     fetch_list, scope, mode)
         import numpy as _np
 
         # fixed rng bits: analysis must not advance the scope's rng counter
-        lowered = jax.jit(step, donate_argnums=(1,)).lower(
+        lowered = self._jit_step(step).lower(
             feed, state_vals, _np.zeros(2, _np.int32))
         ca = lowered.cost_analysis()
         if isinstance(ca, (list, tuple)):
@@ -831,13 +738,11 @@ class Executor:
         pair is what bench.py's ``cost_model`` section gates against
         each other.  Returns {} when the PJRT plugin exposes no memory
         stats."""
-        import jax
-
         feed, state_vals, step = self._prepare_step(program, feed,
                                                     fetch_list, scope, mode)
         import numpy as _np
 
-        lowered = jax.jit(step, donate_argnums=(1,)).lower(
+        lowered = self._jit_step(step).lower(
             feed, state_vals, _np.zeros(2, _np.int32))
         try:
             ma = lowered.compile().memory_analysis()
@@ -878,7 +783,7 @@ class Executor:
         program = program or default_main_program()
         feed, state_vals, step = self._prepare_step(program, feed,
                                                     fetch_list, scope, mode)
-        kwargs = {}
+        in_sh = None
         if mesh is not None:
             block = program.desc.global_block()
             feed_sh = {n: _pmesh.feed_sharding(mesh, v)
@@ -888,8 +793,8 @@ class Executor:
                     mesh, v,
                     block.vars[n].sharding if n in block.vars else None)
                 for n, v in state_vals.items()}
-            kwargs["in_shardings"] = (
-                feed_sh, state_sh, NamedSharding(mesh, PartitionSpec()))
+            in_sh = (feed_sh, state_sh,
+                     NamedSharding(mesh, PartitionSpec()))
             # run()'s re-layout rule: state whose current placement
             # disagrees with its annotation (e.g. loaded replicated)
             # moves first, or lowering rejects the arg/sharding mismatch
@@ -900,7 +805,7 @@ class Executor:
                         and cur != target:
                     state_vals[n] = jax.device_put(v, target)
         # fixed rng bits: analysis must not advance the scope's rng counter
-        lowered = jax.jit(step, donate_argnums=(1,), **kwargs).lower(
+        lowered = self._jit_step(step, in_sh).lower(
             feed, state_vals, np.zeros(2, np.int32))
         return lowered.compile().as_text()
 
@@ -1148,13 +1053,7 @@ class Executor:
                              NamedSharding(mesh, PartitionSpec()))
                 else:
                     feed_sh = None
-                # the rng placeholder shares the real rng_bits' signature
-                # (int32[2]); the persistent tier keys on the same mem_key
-                # the in-memory cache just missed on
-                compiled = self._aot_compile(
-                    key, step,
-                    (feed, state_vals, np.zeros(2, np.int32)),
-                    in_shardings=in_sh, mesh=mesh)
+                compiled = self._jit_step(step, in_sh)
                 self._store_executable(key, (compiled, state_sh
                                              if mesh is not None else None,
                                              feed_sh))
@@ -1455,8 +1354,7 @@ class Executor:
                 return jax.lax.scan(body, state, (stacked_feeds, rng_stack))
 
             multi.__name__ = f"{mode}_scan{k}"
-            compiled = self._aot_compile(
-                key, multi, (stacked_feeds, state_vals, rng_stack))
+            compiled = self._jit_step(multi)
             self._store_executable(key, (compiled, None, None))
 
         from .profiler import record_event

@@ -27,29 +27,11 @@ Both are duck-typed to the ``ResilientTrainer`` hook:
 
 from __future__ import annotations
 
-import sys
 from typing import Callable, Dict, List, Optional
 
 from ..fluid import io as fio
 
 __all__ = ["CandidatePublisher", "GeneratorPublisher"]
-
-
-def _aot_prewarm(dirname: str, **kw) -> None:
-    """Pre-compile the just-published version's bucket set into its
-    ``compiled/`` subdir (ISSUE 14) so candidates arrive at the release
-    controller pre-compiled — ``Gateway._warm`` on the canary then
-    loads executables instead of compiling.  ADVISORY like the publish
-    hook itself: the cache is exactly that, so a failed pre-warm logs
-    and the (complete, loadable) version stands."""
-    from ..tools.aot_compile import precompile
-
-    try:
-        precompile(dirname, **kw)
-    except Exception as e:
-        print(f"paddle_tpu.lifecycle: aot pre-warm of {dirname} failed "
-              f"({type(e).__name__}: {e}); the version will compile at "
-              f"load instead", file=sys.stderr)
 
 
 class CandidatePublisher:
@@ -58,9 +40,7 @@ class CandidatePublisher:
     def __init__(self, root: str, name: str, feed_names: List[str],
                  target_vars, executor, main_program=None, scope=None,
                  int8: bool = False,
-                 version_fn: Optional[Callable[[int], str]] = None,
-                 aot_warm: bool = False,
-                 aot_max_time: Optional[int] = None):
+                 version_fn: Optional[Callable[[int], str]] = None):
         self.root = str(root)
         self.name = str(name)
         self.feed_names = list(feed_names)
@@ -70,11 +50,6 @@ class CandidatePublisher:
         self.scope = scope
         self.int8 = bool(int8)
         self.version_fn = version_fn or str
-        # ISSUE 14: pre-compile the published version's bucket set so
-        # the candidate ships its executables (aot_max_time closes
-        # ragged feeds' time axis for the enumeration)
-        self.aot_warm = bool(aot_warm)
-        self.aot_max_time = aot_max_time
 
     def manifest(self) -> Optional[Dict]:
         if not self.int8:
@@ -83,13 +58,11 @@ class CandidatePublisher:
 
     def publish(self, step: int, program=None, scope=None) -> str:
         version = str(self.version_fn(int(step)))
-        dirname = fio.save_versioned_inference_model(
+        fio.save_versioned_inference_model(
             self.root, self.name, version, self.feed_names,
             self.target_vars, self.executor,
             main_program=program or self.main_program,
             scope=scope or self.scope, manifest=self.manifest())
-        if self.aot_warm:
-            _aot_prewarm(dirname, max_time=self.aot_max_time)
         return version
 
 
@@ -100,8 +73,7 @@ class GeneratorPublisher:
 
     def __init__(self, root: str, name: str, generator_config: Dict,
                  scope=None, place=None,
-                 version_fn: Optional[Callable[[int], str]] = None,
-                 aot_warm: Optional[int] = None):
+                 version_fn: Optional[Callable[[int], str]] = None):
         self.root = str(root)
         self.name = str(name)
         # the PagedTransformerGenerator constructor surface (the same
@@ -111,9 +83,6 @@ class GeneratorPublisher:
         self.scope = scope
         self.place = place
         self.version_fn = version_fn or str
-        # ISSUE 14: lane count to pre-compile each published version at
-        # (match the gateway's n_slots); None = ship uncompiled
-        self.aot_warm = aot_warm
         self._gen = None            # built lazily: one clone, reused
 
     def _generator(self):
@@ -136,8 +105,6 @@ class GeneratorPublisher:
                              "(pass one at construction or publish)")
         copy_weights(src_scope, gen.scope,
                      prefix=self.generator_config.get("param_prefix"))
-        dirname = ModelRegistry.save_generator_artifact(
+        ModelRegistry.save_generator_artifact(
             gen, self.root, self.name, version)
-        if self.aot_warm:
-            _aot_prewarm(dirname, n_slots=int(self.aot_warm))
         return version

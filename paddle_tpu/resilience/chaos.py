@@ -35,11 +35,6 @@ through the distributed stack (all no-ops unless configured):
                         version loaded+warmed but before the alias flip
                         (the old version must keep serving, the orphan
                         must not linger);
-  * ``aot.corrupt``   — truncate a persistent AOT cache entry's bytes
-                        as they are read (fluid/compile_cache.py): the
-                        checksum must fail and the entry degrade to a
-                        compile-and-overwrite MISS — never a crash,
-                        never garbage loaded into the device;
   * ``net.partition`` — client-side: raise a transient ChaosError
                         instead of sending a pod-coordinator RPC
                         (parallel/coordinator.py PodClient — exercises

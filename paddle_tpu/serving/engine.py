@@ -308,20 +308,16 @@ class InferenceEngine:
         """Static peak-HBM plan of the served program at ``batch``
         (default: the largest configured batch bucket — the worst
         signature this engine will ever dispatch).  The gateway
-        registry and the scheduler budget with this number.  Priced
-        without donation aliasing when the executor mounts a
-        persistent AOT cache (its executables really dispatch that
-        way — ISSUE 14)."""
+        registry and the scheduler budget with this number."""
         from ..fluid.analysis.cost import plan_program
 
         b = int(batch) if batch is not None else max(self.batch_buckets)
-        return plan_program(self.program, assume_batch=b,
-                            assume_donation=self.exe._aot_cache() is None)
+        return plan_program(self.program, assume_batch=b)
 
     def bucket_set(self, max_time: Optional[int] = None):
         """Enumerate the closed set of compile signatures this engine
         can dispatch — the recompile-hazard lint's enumeration (ISSUE
-        11), and exactly what an AOT executable cache must pre-compile.
+        11).
         Ragged (SeqArray) feeds need ``max_time`` to close the time
         axis: the time buckets are the multiples of ``time_bucket`` up
         to it."""
@@ -338,65 +334,6 @@ class InferenceEngine:
         return enumerate_buckets(ProgramView(self.program.desc),
                                  batch_buckets=self.batch_buckets,
                                  time_buckets=time_buckets)
-
-    # -- AOT pre-resolution (ISSUE 14) ---------------------------------------
-    def aot_bucket_feeds(self, max_time: Optional[int] = None):
-        """One synthetic zero feed per enumerated compile signature —
-        each lands EXACTLY on its bucket (batch == bucket, time already
-        a time_bucket multiple), so dispatching them resolves the
-        engine's whole closed executable set.  Raises on an open bucket
-        set (ragged feeds with no ``max_time``, dynamic inner dims):
-        an AOT cache cannot pre-compile an open set."""
-        feeds = []
-        for entry in self.bucket_set(max_time=max_time):
-            if not entry["closed"]:
-                raise ValueError(
-                    "aot_bucket_feeds: the bucket set is OPEN "
-                    f"(entry {entry['batch']}x{entry['time']}); pass "
-                    "max_time= for ragged feeds, and keep value-shaped "
-                    "axes out of the served program")
-            feed = {}
-            for name, spec in entry["feeds"].items():
-                shape = [int(d) for d in spec["shape"]]
-                if spec["lod_level"] > 0:
-                    feed[name] = SeqArray(
-                        np.zeros(shape, spec["dtype"]),
-                        np.full(shape[0], shape[1], np.int32))
-                else:
-                    feed[name] = np.zeros(shape, spec["dtype"])
-            feeds.append(feed)
-        return feeds
-
-    def preresolve(self, max_time: Optional[int] = None,
-                   stop_on_compile: bool = False) -> int:
-        """Dispatch every signature in the closed bucket set once (via
-        ``warmup`` — registers buckets without skewing hit counters).
-        With a persistent AOT cache attached to the executor each
-        dispatch deserializes a stored executable instead of compiling;
-        without one, this is the offline pre-compilation pass that
-        POPULATES the cache.  Returns the number of signatures
-        resolved.
-
-        ``stop_on_compile=True`` bounds the pass to what the cache
-        actually holds: the first signature that MISSES the persistent
-        tier (i.e. pays a real XLA compile) ends the sweep, leaving the
-        remaining buckets to lazy per-request compilation — the caller
-        wanted to LOAD a shipped set, not synchronously compile an
-        unshipped one (``Gateway._warm`` on a partially pre-warmed
-        artifact).  The one compile performed is stored back, so each
-        restart heals one more bucket."""
-        feeds = self.aot_bucket_feeds(max_time=max_time)
-        if not stop_on_compile:
-            self.warmup(feeds)
-            return len(feeds)
-        n = 0
-        for feed in feeds:
-            before = self.exe.cache_stats()["persistent"]["misses"]
-            self.warmup([feed])
-            n += 1
-            if self.exe.cache_stats()["persistent"]["misses"] > before:
-                break
-        return n
 
     def cache_stats(self) -> Dict[str, Any]:
         """{'bucket_hits', 'bucket_misses', 'buckets': {key: count},

@@ -6,10 +6,9 @@ own journal file.  A SIGKILLed replica respawns in place (restart
 budget permitting), replays what is left of its journal — the router
 already migrated the tail, so a respawn replays only what arrived after
 migration — and rejoins rotation at the router's next probe.  Cold
-start is cheap by construction: replicas load artifacts through the
-registry, whose ``compiled/`` AOT cache turns the respawn's compiles
-into disk loads (PR 13), so crash-replace and scale-up pay I/O, not
-XLA.
+start is cheap by construction: a respawn finds its executables in
+JAX's compilation cache (``paddle_tpu._place_compile_cache``), which the
+replicas share, so crash-replace and scale-up pay I/O, not XLA.
 
 The supervisor owns processes; the router owns rotation.  They meet in
 ``replica_specs()``: the spec list (name, address, journal path) a

@@ -255,23 +255,6 @@ class Gateway:
             # engines need a shaped sample; without one we at least
             # upload the weights so the first request pays no H2D.
             inst.place_weights()
-            # when the artifact SHIPS a compiled bucket set (ISSUE 14:
-            # registry-mounted compiled/ cache with entries), resolve
-            # it now — each dispatch is a disk load, so the first real
-            # request of those buckets pays zero compiles.  A cold
-            # cache keeps the old lazy behavior, and stop_on_compile
-            # bounds a PARTIALLY-shipped set to at most one synchronous
-            # compile (the rest stay lazy): pre-compiling every bucket
-            # at load time would turn load_model into the compile
-            # storm this cache exists to kill.
-            aot = getattr(getattr(inst, "exe", None), "_aot_cache",
-                          lambda: None)()
-            if callable(getattr(inst, "preresolve", None)) \
-                    and aot is not None and aot.keys():
-                try:
-                    inst.preresolve(stop_on_compile=True)
-                except ValueError:
-                    pass    # open bucket set — nothing enumerable
 
     def load_model(self, name: str, version: str,
                    dirname: Optional[str] = None,

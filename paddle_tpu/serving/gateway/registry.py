@@ -54,16 +54,9 @@ from ..paged_lm import LM_CONFIG_KEYS, PagedLMGenerator, estimate_lm_hbm
 from ..scheduler import HBMBudgetError, suggest_model_axis
 from ..speculative import SpeculativeGenerator, estimate_speculative_hbm
 
-__all__ = ["HBMBudgetError", "ModelRegistry", "MANIFEST_NAME",
-           "COMPILED_SUBDIR"]
+__all__ = ["HBMBudgetError", "ModelRegistry", "MANIFEST_NAME"]
 
 MANIFEST_NAME = "gateway.json"
-# per-version persistent AOT executable cache (ISSUE 14): a published
-# version MAY ship its compiled bucket set here (tools/aot_compile
-# pre-warms it offline).  Only an artifact that ships the directory
-# mounts the tier; every other load compiles through the donating jit
-# and JAX's own persistent compilation cache (paddle_tpu/__init__.py).
-COMPILED_SUBDIR = "compiled"
 
 # the paged generator's constructor surface a manifest may carry — kept
 # explicit so a stale manifest key fails loudly at load, not deep in the
@@ -120,34 +113,6 @@ def _register_registry_collector() -> None:
 
         _m().register_collector(_collect_registry_metrics)
         _collector_registered = True
-
-
-def _ships_compiled(dirname: Optional[str]) -> bool:
-    """True when a load of ``dirname`` mounts the private AOT tier: the
-    artifact ships a ``compiled/`` directory (what ``tools/aot_compile``
-    produces) and the tier is not disabled
-    (``PADDLE_TPU_AOT_DISABLE=1``).  Executables served from the tier
-    dispatch WITHOUT donation, so this is also what the HBM planner
-    keys its ``assume_donation`` on."""
-    return bool(dirname) \
-        and os.environ.get("PADDLE_TPU_AOT_DISABLE", "") != "1" \
-        and os.path.isdir(os.path.join(dirname, COMPILED_SUBDIR))
-
-
-def _artifact_cache(dirname: str):
-    """The artifact's shipped ``compiled/`` executable cache (mounted
-    read-write: a bucket the shipped set misses is compiled once and
-    published back), or None — no artifact tier; the executor defers
-    to the process default, normally none — when the artifact ships
-    none.  A fresh artifact never grows a ``compiled/`` behind the
-    publisher's back: its step compiles through
-    ``jax.jit(step, donate_argnums=(1,))``, so the KV pool updates in
-    place."""
-    if not _ships_compiled(dirname):
-        return None
-    from ...fluid.compile_cache import CompileCache
-
-    return CompileCache(os.path.join(dirname, COMPILED_SUBDIR))
 
 
 def _artifact_bytes(dirname: str) -> int:
@@ -294,21 +259,14 @@ class ModelRegistry:
         the manifest config (the KV pool and its int8 scale sidecar are
         persistable vars with recorded shapes — no separate
         kv_page_bytes term), an engine's saved ``__model__`` program is
-        planned at its largest declared batch bucket.  Artifact loads
-        that mount a shipped ``compiled/`` AOT cache (ISSUE 14) are
-        priced WITHOUT donation aliasing — their executables really
-        dispatch with write-back copies, and a budget computed from the
-        donating ideal would admit models that OOM the chip
-        mid-traffic."""
-        donation = not _ships_compiled(dirname)
+        planned at its largest declared batch bucket."""
         if kind == "generator":
-            plan = estimate_generator_hbm(config,
-                                          assume_donation=donation)
+            plan = estimate_generator_hbm(config)
             return int(plan.peak_bytes), dict(plan.components)
         if kind == "lm_generator":
             # the decoder-only generator: parameters in the type they
             # are resident in, a pool pair per kind of layer
-            plan = estimate_lm_hbm(config, assume_donation=donation)
+            plan = estimate_lm_hbm(config)
             return int(plan.peak_bytes), dict(plan.components)
         if kind == "engine" and dirname:
             model_path = os.path.join(dirname, "__model__")
@@ -321,8 +279,7 @@ class ModelRegistry:
                 buckets = config.get("batch_buckets") \
                     or DEFAULT_BATCH_BUCKETS
                 plan = plan_program(prog,
-                                    assume_batch=int(max(buckets)),
-                                    assume_donation=donation)
+                                    assume_batch=int(max(buckets)))
                 return int(plan.peak_bytes), dict(plan.components)
         # no program to plan (adopted instance, bare artifact dir):
         # artifact bytes are the only static signal left
@@ -407,10 +364,8 @@ class ModelRegistry:
             elif kind == "lm_generator":
                 instance = self._build_lm_generator(dirname, config)
             elif kind == "engine":
-                exe = fluid.Executor(
-                    self.place, compile_cache=_artifact_cache(dirname))
                 instance = InferenceEngine(
-                    dirname=dirname, place=self.place, executor=exe,
+                    dirname=dirname, place=self.place,
                     quantize=config.pop("quantize", "off"), **config)
             else:
                 raise ValueError(f"{dirname}: unknown artifact kind "
@@ -448,10 +403,7 @@ class ModelRegistry:
         and the HBM budget charges the PAIR jointly (target priced at
         its k+1-token verify shape, draft at its masked decode shape,
         both pools and parameter sets resident at once) BEFORE either
-        model is built.  Each artifact that ships a ``compiled/`` AOT
-        cache mounts its own, so a pre-compiled pair serves its
-        draft/verify/cow executables from disk (zero process
-        compiles)."""
+        model is built."""
         name, version = str(name), str(version)
         key = f"{name}@{version}"
         self._reserve_load(key)
@@ -483,10 +435,7 @@ class ModelRegistry:
                     f"draft kind {d_manifest.get('kind')!r})")
             t_cfg = dict(t_manifest.get("config", {}))
             d_cfg = dict(d_manifest.get("config", {}))
-            donation = not (_ships_compiled(t_dir)
-                            or _ships_compiled(d_dir))
-            plan = estimate_speculative_hbm(t_cfg, d_cfg, k=int(k),
-                                            assume_donation=donation)
+            plan = estimate_speculative_hbm(t_cfg, d_cfg, k=int(k))
             cost = int(plan.peak_bytes)
             self._charge(cost, key, dict(plan.components))
             target = self._build_generator(t_dir, t_cfg)
@@ -509,10 +458,7 @@ class ModelRegistry:
         if bad:
             raise ValueError(f"{dirname}: unknown generator config keys "
                              f"{sorted(bad)}")
-        exe = fluid.Executor(self.place,
-                             compile_cache=_artifact_cache(dirname))
-        gen = PagedTransformerGenerator(place=self.place, executor=exe,
-                                        **config)
+        gen = PagedTransformerGenerator(place=self.place, **config)
         load_artifact_tensors(gen.scope, dirname, skip=(MANIFEST_NAME,))
         # one upload at load, not per first request (the engine
         # to_device contract); the pool vars are already device zeros
@@ -530,9 +476,7 @@ class ModelRegistry:
         if bad:
             raise ValueError(f"{dirname}: unknown lm_generator config "
                              f"keys {sorted(bad)}")
-        exe = fluid.Executor(self.place,
-                             compile_cache=_artifact_cache(dirname))
-        gen = PagedLMGenerator(place=self.place, executor=exe, **config)
+        gen = PagedLMGenerator(place=self.place, **config)
         load_artifact_tensors(
             gen.scope, dirname, skip=(MANIFEST_NAME,),
             cast=None if gen.layout["dtype"] == "float32"

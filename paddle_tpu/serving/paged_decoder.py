@@ -316,7 +316,6 @@ HBM_ESTIMATE_LANES = 8
 
 
 def estimate_generator_hbm(config: Dict, assume_lanes: int = None,
-                           assume_donation: bool = True,
                            verify_tokens: int = 1,
                            logit_masks: bool = False,
                            mesh_axes: Optional[Dict[str, int]] = None):
@@ -324,9 +323,7 @@ def estimate_generator_hbm(config: Dict, assume_lanes: int = None,
     gateway manifest config — built and planned as a DESC, before any
     device allocation.  Params, the KV pool, and the int8 scale sidecar
     are persistable vars with recorded shapes; activations price at
-    ``assume_lanes`` in-flight lanes.  ``assume_donation=False`` prices
-    the no-donation dispatch of a persistent-AOT-cached executable
-    (pool/param write-backs get fresh buffers — ISSUE 14).
+    ``assume_lanes`` in-flight lanes.
     ``verify_tokens``/``logit_masks`` (ISSUE 15) price the speculative
     VERIFY shape of the program — K-token activations and the
     [lanes, K, vocab] mask feed are real peak-HBM contributors the
@@ -343,9 +340,7 @@ def estimate_generator_hbm(config: Dict, assume_lanes: int = None,
         mesh_axes=mesh_axes)
     lanes = HBM_ESTIMATE_LANES if assume_lanes is None \
         else int(assume_lanes)
-    return plan_program(prog, assume_batch=lanes,
-                        assume_donation=assume_donation,
-                        mesh_axes=mesh_axes)
+    return plan_program(prog, assume_batch=lanes, mesh_axes=mesh_axes)
 
 
 def build_manifest_program(config: Dict, verify_tokens: int = 1,
@@ -532,8 +527,8 @@ class PagedTransformerGenerator:
         """Every device dispatch of a sharded generator runs under its
         mesh: the executor keys executables on the mesh content and
         applies the program's sharding annotations as jit in_shardings
-        (the pjit path — one compile per mesh shape, cached and
-        AOT-persistable like any other executable)."""
+        (the pjit path — one compile per mesh shape, cached like any
+        other executable)."""
         if self.mesh is None:
             return contextlib.nullcontext()
         from ..parallel.mesh import mesh_guard
@@ -1484,7 +1479,7 @@ class PagedTransformerGenerator:
                 mode="infer")
         return out_ids, np.asarray(out_scores)
 
-    # -- AOT pre-resolution (ISSUE 14) ---------------------------------------
+    # -- load-time warm-up ---------------------------------------------------
     def step_variants(self) -> List[int]:
         """The widths the prefill tower can take at the open lane count:
         one executable of the unified step each.  A load path that
@@ -1502,9 +1497,8 @@ class PagedTransformerGenerator:
         every tower width, without admitting any request: one all-idle
         ``lane_step`` each — every row rides along with trash-page
         writes and length-1 masks, so no KV state or lane bookkeeping
-        changes.  With a persistent AOT cache attached to the executor
-        these are disk loads; without one they are the offline
-        pre-compile that populates the cache (``tools/aot_compile``).
+        changes.  Each is a compile, or a load from JAX's compilation
+        cache when an earlier process left the executable there.
         Lanes are left open at ``n_slots`` (the scheduler re-opens them
         at attach anyway)."""
         if any(lane.phase != "idle" for lane in self._lanes):
@@ -1535,19 +1529,14 @@ class PagedTransformerGenerator:
         + KV pool + int8 sidecar + per-dispatch activations at
         ``assume_lanes``) — the number the gateway registry budgets
         with and the scheduler surfaces per lane group (ISSUE 11:
-        admission runs on the planner, not a byte-count heuristic).
-        A generator whose executor mounts a persistent AOT cache is
-        priced WITHOUT donation aliasing (its dispatches really run
-        that way — ISSUE 14): the admission budget must cover the
-        pool/param write-back copies, not the donating ideal."""
+        admission runs on the planner, not a byte-count heuristic)."""
         from ..fluid.analysis.cost import plan_program
 
         lanes = HBM_ESTIMATE_LANES if assume_lanes is None \
             else int(assume_lanes)
-        donation = self.exe._aot_cache() is None
         mesh_key = None if self.mesh_axes is None \
             else tuple(sorted(self.mesh_axes.items()))
-        key = ("_hbm_plan", lanes, donation, mesh_key)
+        key = ("_hbm_plan", lanes, mesh_key)
         cached = getattr(self, "_static_hbm_cache", None)
         if cached is not None and cached[0] == key:
             return cached[1]
@@ -1555,7 +1544,6 @@ class PagedTransformerGenerator:
         # holds (the admission criterion ISSUE 17 flips from "fits one
         # chip" to "fits one shard")
         plan = plan_program(self._unified[0], assume_batch=lanes,
-                            assume_donation=donation,
                             mesh_axes=self.mesh_axes)
         self._static_hbm_cache = (key, plan)
         return plan

@@ -146,8 +146,7 @@ def _build(layout: Dict, n_prefill: int):
         impl=layout["impl"])
 
 
-def estimate_lm_hbm(config: Dict, assume_donation: bool = True,
-                    builder=None):
+def estimate_lm_hbm(config: Dict, builder=None):
     """Static peak-HBM plan of the largest serve step a manifest config
     describes (every prefill slot full), from its DESC: parameters in the
     type they are resident in, both pool pairs, and the step's
@@ -158,8 +157,7 @@ def estimate_lm_hbm(config: Dict, assume_donation: bool = True,
         raise _refuse("a mesh_axes artifact")
     layout = lm_pool_layout(config, builder)
     prog = _build(layout, layout["prefill_slots"])[0]
-    return plan_program(prog, assume_batch=1,
-                        assume_donation=assume_donation)
+    return plan_program(prog, assume_batch=1)
 
 
 class _Lane:
@@ -567,8 +565,7 @@ class PagedLMGenerator:
     def static_hbm_estimate(self, assume_lanes: int = None):
         cached = getattr(self, "_hbm_plan", None)
         if cached is None:
-            cached = self._hbm_plan = estimate_lm_hbm(
-                self.config, assume_donation=self.exe._aot_cache() is None)
+            cached = self._hbm_plan = estimate_lm_hbm(self.config)
         return cached
 
     def counters(self) -> Dict[str, object]:

@@ -5,9 +5,9 @@ at any instant ~99% of sessions are idle, yet pre-tier each one either
 pinned its KV pages in HBM forever or lost them and paid a full
 re-prefill on the next turn.  This module is the storage half of the
 fix — a suspended lane's pages + lengths + position become ONE framed,
-fingerprint-keyed, sha256-checksummed artifact (the PR 13
-``compile_cache.py`` entry format: magic + JSON header + blob, written
-tmp-file + fsync + atomic-rename), held in a bytes-capped host-RAM LRU
+fingerprint-keyed, sha256-checksummed artifact (magic + JSON header +
+blob, written tmp-file + fsync + atomic-rename as ``utils/journal``
+rewrites its file), held in a bytes-capped host-RAM LRU
 and optionally mirrored to disk so sessions survive a process restart.
 
 Integrity contract (satellite 3): a torn/flipped/truncated artifact —

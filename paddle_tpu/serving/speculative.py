@@ -92,18 +92,16 @@ class _CombinedPlan:
 
 
 def estimate_speculative_hbm(target_config: Dict, draft_config: Dict,
-                             k: int = 4, assume_lanes: int = None,
-                             assume_donation: bool = True) -> _CombinedPlan:
+                             k: int = 4, assume_lanes: int = None
+                             ) -> _CombinedPlan:
     """Static peak-HBM plan of a speculative pair from two gateway
     manifest configs — what ``ModelRegistry.load_speculative`` budgets
     BEFORE any construction.  The target is priced at its VERIFY shape
     (k+1-token activations + the mask feed), the draft at its masked
     1-token decode shape; both pools and parameter sets count."""
     t = estimate_generator_hbm(target_config, assume_lanes=assume_lanes,
-                               assume_donation=assume_donation,
                                verify_tokens=int(k) + 1, logit_masks=True)
     d = estimate_generator_hbm(draft_config, assume_lanes=assume_lanes,
-                               assume_donation=assume_donation,
                                verify_tokens=1, logit_masks=True)
     return _CombinedPlan(t, d)
 
@@ -769,15 +767,13 @@ class SpeculativeGenerator:
             "exclusive — serve beam requests from a plain paged "
             "generator group")
 
-    # -- AOT pre-resolution (ISSUE 14) ---------------------------------------
     def aot_warm(self, n_slots: int) -> None:
         """Resolve the draft and verify executables at every width of
         the prefill tower, AND the copy-on-write executable, at the
         serving lane count without admitting any request (all-idle
-        dispatches: trash-page writes, length-1 masks).  With persistent
-        AOT caches mounted on the two executors these are disk loads —
-        a pre-compiled version with a draft attached serves its first
-        request with zero process compiles."""
+        dispatches: trash-page writes, length-1 masks), so a version
+        with a draft attached serves its first request with no compile
+        in traffic."""
         if any(lane.phase != "idle" for lane in self.target._lanes) or \
                 any(lane.phase != "idle" for lane in self.draft._lanes):
             raise RuntimeError(
@@ -811,9 +807,7 @@ class SpeculativeGenerator:
         """Joint static peak-HBM plan: the target priced at its VERIFY
         program shape + the draft at its masked decode shape — both
         pools, parameter sets and per-dispatch activations are resident
-        at once, so the registry/scheduler budget is the sum.  Each half
-        prices no-donation when ITS executor mounts a persistent AOT
-        cache (ISSUE 14)."""
+        at once, so the registry/scheduler budget is the sum."""
         from ..fluid.analysis.cost import plan_program
 
         lanes = HBM_ESTIMATE_LANES if assume_lanes is None \
@@ -822,18 +816,14 @@ class SpeculativeGenerator:
             else tuple(sorted(self.target.mesh_axes.items()))
         dmesh = None if self.draft.mesh_axes is None \
             else tuple(sorted(self.draft.mesh_axes.items()))
-        key = ("_spec_hbm", lanes,
-               self.target.exe._aot_cache() is None,
-               self.draft.exe._aot_cache() is None, tmesh, dmesh)
+        key = ("_spec_hbm", lanes, tmesh, dmesh)
         cached = getattr(self, "_static_hbm_cache", None)
         if cached is not None and cached[0] == key:
             return cached[1]
         t = plan_program(self._verify[0], assume_batch=lanes,
-                         assume_donation=self.target.exe._aot_cache()
-                         is None, mesh_axes=self.target.mesh_axes)
+                         mesh_axes=self.target.mesh_axes)
         d = plan_program(self._draft_prog[0], assume_batch=lanes,
-                         assume_donation=self.draft.exe._aot_cache()
-                         is None, mesh_axes=self.draft.mesh_axes)
+                         mesh_axes=self.draft.mesh_axes)
         plan = _CombinedPlan(t, d)
         self._static_hbm_cache = (key, plan)
         return plan

@@ -55,7 +55,7 @@ def test_refuses_without_a_chip():
     assert "no TPU" in proc.stderr
 
 
-def test_compile_cache_defaults_to_the_checkout():
+def test_place_compile_cache_defaults_to_the_checkout():
     """Importing the package (conftest did) placed the cache — unless
     the environment had named a directory, which then stands."""
     import jax

@@ -515,31 +515,12 @@ def test_registry_costs_with_static_plan(tmp_path, small_gen):
                                       "gateway.json")))["config"]
     art = fluid.io.model_version_dir(root, "m", "1")
     cost = ModelRegistry._estimate_cost("generator", art, cfg)
-    # the manifest-built desc and the live generator agree exactly.
-    # A fresh artifact compiles through the donating jit, so the
-    # registry prices the donating plan; one that SHIPS a compiled/ AOT
-    # cache (ISSUE 14) mounts it and is priced for the no-donation
-    # dispatch its executables really run.  The live instance
-    # self-selects the same model once a cache is mounted on its
-    # executor — compare like for like both ways.
-    from paddle_tpu.fluid.compile_cache import CompileCache
+    # the manifest-built desc and the live generator agree exactly
     from paddle_tpu.serving.paged_decoder import estimate_generator_hbm
 
-    plan = small_gen.static_hbm_estimate()       # no cache: donating
+    plan = small_gen.static_hbm_estimate()
     assert plan.peak_bytes == \
         estimate_generator_hbm(cfg).peak_bytes == cost
-    os.makedirs(os.path.join(art, "compiled"))
-    shipped = ModelRegistry._estimate_cost("generator", art, cfg)
-    os.rmdir(os.path.join(art, "compiled"))
-    assert shipped == \
-        estimate_generator_hbm(cfg, assume_donation=False).peak_bytes
-    assert shipped > plan.peak_bytes             # write-backs priced in
-    small_gen.exe.set_compile_cache(
-        CompileCache(os.path.join(root, "unused-cache")))
-    try:
-        assert shipped == small_gen.static_hbm_estimate().peak_bytes
-    finally:
-        small_gen.exe.set_compile_cache(None)
     # …and the plan covers more than the old artifact-byte heuristic:
     # pool + activations, not just weight bytes on disk
     assert plan.components["kv_pool"] == \

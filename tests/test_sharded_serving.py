@@ -252,8 +252,8 @@ def test_registry_refusal_carries_mesh_suggestion(tmp_path):
 
 def test_artifact_records_mesh_axes(tmp_path):
     """A sharded generator's saved manifest carries its mesh shape, so
-    a registry load (and aot_compile --mesh round-trips) rebuild the
-    same partitioning without a side channel."""
+    a registry load rebuilds the same partitioning without a side
+    channel."""
     from paddle_tpu.serving.gateway.registry import ModelRegistry
 
     gen = _sharded(2)

@@ -101,7 +101,7 @@ def test_unified_step_updates_the_pool_in_place_on_v5e(tower, one_chip,
         feed, state, step = gen.exe._prepare_step(
             prog, feed, [next_ids], gen.scope, "infer")
     args = _shapes((feed, state, np.zeros(2, np.int32)), one_chip)
-    compiled = jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
+    compiled = gen.exe._jit_step(step).lower(*args).compile()
     hlo = compiled.as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 3 * NL
     assert "input_output_alias" in hlo.splitlines()[0]

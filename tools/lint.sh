@@ -6,7 +6,6 @@
 #   tools/lint.sh --ruff     # ruff only
 #   tools/lint.sh --plint    # program lint only
 #   tools/lint.sh --sync     # concurrency lint + lock-order graph only
-#   tools/lint.sh --aot      # AOT executable-cache sweep only
 #
 # ruff is optional in the hermetic CI container (no network installs);
 # when absent we warn and still run the program linter, which needs
@@ -18,14 +17,12 @@ cd "$(dirname "$0")/.."
 want_ruff=1
 want_plint=1
 want_sync=1
-want_aot=1
 case "${1:-}" in
-  --ruff)  want_plint=0; want_sync=0; want_aot=0 ;;
-  --plint) want_ruff=0; want_sync=0; want_aot=0 ;;
-  --sync)  want_ruff=0; want_plint=0; want_aot=0 ;;
-  --aot)   want_ruff=0; want_plint=0; want_sync=0 ;;
+  --ruff)  want_plint=0; want_sync=0 ;;
+  --plint) want_ruff=0; want_sync=0 ;;
+  --sync)  want_ruff=0; want_plint=0 ;;
   "") ;;
-  *) echo "usage: tools/lint.sh [--ruff|--plint|--sync|--aot]" >&2; exit 64 ;;
+  *) echo "usage: tools/lint.sh [--ruff|--plint|--sync]" >&2; exit 64 ;;
 esac
 
 rc=0
@@ -666,107 +663,6 @@ feed = {"x": rng.rand(8, 16).astype("float32"),
 gate("training dp=4", t.get_trainer_program(), {"dp": 4}, feed,
      [loss], exe, scope, pmesh.make_mesh({"dp": 4}), "train", 8)
 EOF
-fi
-
-if [ "$want_aot" = 1 ]; then
-  # AOT executable-cache sweep (ISSUE 14): publish a book program as a
-  # versioned inference artifact, aot_compile it TWICE into its
-  # compiled/ cache, and assert the second run performs zero XLA
-  # compiles with byte-stable cache keys — the deployable-executable
-  # contract the serving restart path depends on
-  echo "== aot sweep: book program through tools.aot_compile twice"
-  aot_tmp="$(mktemp -d)"
-  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python - "$aot_tmp" <<'EOF' || rc=1
-import json, os, subprocess, sys
-
-tmpdir = sys.argv[1]
-from paddle_tpu import fluid
-from paddle_tpu.models import recognize_digits
-
-main, startup = fluid.Program(), fluid.Program()
-with fluid.program_guard(main, startup), fluid.unique_name.guard():
-    img = fluid.layers.data(name="img", shape=[1, 28, 28], dtype="float32")
-    label = fluid.layers.data(name="label", shape=[1], dtype="int64")
-    predict, _, _ = recognize_digits.conv_net(img, label)
-exe = fluid.Executor(fluid.CPUPlace())
-scope = fluid.Scope()
-with fluid.scope_guard(scope):
-    exe.run(startup)
-    fluid.io.save_versioned_inference_model(
-        tmpdir, "digits", "1", ["img"], [predict], exe,
-        main_program=main)
-dirname = fluid.io.model_version_dir(tmpdir, "digits", "1")
-
-env = dict(os.environ, JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"))
-reports = []
-for run in (1, 2):
-    p = subprocess.run(
-        [sys.executable, "-m", "paddle_tpu.tools.aot_compile",
-         "--dirname", dirname, "--batch-bucket", "1", "--json"],
-        env=env, capture_output=True, text=True)
-    assert p.returncode == 0, f"aot_compile run {run}: {p.stderr[-2000:]}"
-    reports.append(json.loads(p.stdout))
-first, second = reports
-assert first["compiles"] >= 1 and first["stores"] == first["compiles"], first
-assert second["compiles"] == 0, \
-    f"second aot_compile run recompiled: {second}"
-assert second["loads"] == second["signatures"], second
-assert second["keys"] == first["keys"], \
-    f"cache keys not byte-stable: {first['keys']} vs {second['keys']}"
-print(f"aot sweep: {first['compiles']} compiled once, "
-      f"{second['loads']} loaded on rerun, keys byte-stable")
-EOF
-  rm -rf "$aot_tmp"
-
-  # sharded AOT round-trip (ISSUE 17): publish a paged generator
-  # artifact, aot_compile it with --mesh model=2 on a 2-virtual-device
-  # CPU mesh TWICE — the second run must perform zero compiles (the
-  # cache salts entry keys with the mesh, so sharded executables ship
-  # exactly like single-chip ones)
-  echo "== aot sweep: sharded generator through aot_compile --mesh twice"
-  aot_tmp="$(mktemp -d)"
-  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python - "$aot_tmp" <<'EOF' || rc=1
-import json, os, subprocess, sys
-
-tmpdir = sys.argv[1]
-from paddle_tpu import fluid
-from paddle_tpu.serving import PagedTransformerGenerator
-from paddle_tpu.serving.gateway import ModelRegistry
-
-gen = PagedTransformerGenerator(30, 30, n_layer=2, n_head=2, d_key=4,
-                                d_value=4, d_model=16, d_inner_hid=32,
-                                max_length=64, src_len=8, max_out_len=8,
-                                page_size=4, chunk_size=4, num_pages=32,
-                                param_prefix="tfsh",
-                                place=fluid.CPUPlace())
-gen.init_params(seed=0)
-ModelRegistry.save_generator_artifact(gen, tmpdir, "shgen", "1")
-dirname = fluid.io.model_version_dir(tmpdir, "shgen", "1")
-
-env = dict(os.environ,
-           JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
-           XLA_FLAGS="--xla_force_host_platform_device_count=2 "
-                     + os.environ.get("XLA_FLAGS", ""))
-reports = []
-for run in (1, 2):
-    p = subprocess.run(
-        [sys.executable, "-m", "paddle_tpu.tools.aot_compile",
-         "--dirname", dirname, "--n-slots", "2", "--mesh", "model=2",
-         "--json"],
-        env=env, capture_output=True, text=True)
-    assert p.returncode == 0, \
-        f"aot_compile --mesh run {run}: {p.stderr[-2000:]}"
-    reports.append(json.loads(p.stdout))
-first, second = reports
-assert first["compiles"] >= 1, first
-assert second["compiles"] == 0, \
-    f"second aot_compile --mesh run recompiled: {second}"
-assert second["keys"] == first["keys"], \
-    f"sharded cache keys not byte-stable: {first['keys']} vs {second['keys']}"
-print(f"sharded aot sweep: {first['compiles']} compiled once, "
-      f"{second['loads']} loaded on rerun, keys byte-stable")
-EOF
-  rm -rf "$aot_tmp"
 fi
 
 exit $rc
