@@ -186,10 +186,10 @@ def phase_train(cfg, on_chip: bool):
                 calls = exe.compiled_hlo(main, feed=feed,
                                          fetch_list=[avg_cost]
                                          ).count(CUSTOM_CALL)
-            want = n_attn * (1 if i == 0 else 3)
-            check(calls >= want,
+            want = n_attn * (1 if i == 0 else 3)    # the forward once an op
+            check(calls == want,
                   f"train s={seq_len}: {calls} tpu_custom_call in the "
-                  f"compiled step, expected >= {want}")
+                  f"compiled step, expected {want}")
         say("train", batch=batch, seq_len=seq_len, losses=losses,
             setup_s=round(setup_s, 1), steady_s=round(steady_s, 2),
             steady_steps=steps - 1, tpu_custom_calls=calls)

@@ -10,8 +10,9 @@ reverse, emit one ``*_grad`` OpDesc per differentiable forward op, insert
 ``(parameter, gradient)`` pairs for the optimizer.
 
 The grad ops themselves need no hand-written kernels: lowering.py derives
-their math with jax.vjp over the forward emitter (ops may still register
-custom grad makers for sparser adjoints).
+their math with jax.vjp over the forward emitter.  An op may register a
+``<op>_grad`` emitter of its own, and a ``grad_maker`` that hands it the
+forward's results (core/registry.py says when it has to).
 """
 
 from __future__ import annotations
@@ -161,14 +162,12 @@ def append_backward(loss: Variable,
         if not targets:
             continue
 
-        # custom desc-level grad maker hook
-        if info is not None and info.grad_maker is not None:
-            info.grad_maker(op, block, grad_inputs, targets, pending,
-                            _make_grad_var)
-            continue
-
         g_inputs = {slot: [block.var(n) for n in names if n]
                     for slot, names in op.desc.inputs.items()}
+        if info is not None and info.grad_maker is not None:
+            # what this op's own grad emitter takes of the forward's
+            # results (the generic emitter reads only the inputs)
+            g_inputs.update(info.grad_maker(op, block))
         g_inputs.update(grad_inputs)
         # grad outputs stay POSITIONALLY aligned with the forward slot's
         # entries ("" = hole for a non-differentiable entry) so the generic

@@ -16,10 +16,17 @@ Gradients: the reference pairs each op with a hand-written grad op
 keep the *desc-level* contract — ``append_backward`` emits real ``*_grad`` ops
 into the program — but the default grad emitter derives the math with
 ``jax.vjp`` over the forward emitter, recomputing the forward inside the grad
-op.  XLA CSE/fusion dedupes the recompute inside one compiled block, so this
-costs ~nothing at runtime while keeping every op differentiable by
-construction (no per-op grad kernels to hand-maintain).  Ops with cheaper
-adjoints (e.g. ones whose grad only needs Out) can register a custom grad.
+op.  XLA CSE/fusion dedupes the recompute inside one compiled block WHERE THE
+FORWARD IS XLA OPS, so this costs ~nothing at runtime there while keeping
+every op differentiable by construction (no per-op grad kernels to
+hand-maintain).  CSE does not merge custom calls: an op whose forward is a
+Pallas (Mosaic) kernel runs that kernel a second time inside its generic
+grad op (the trace of a Transformer step showed two ``flash_fwd`` calls an
+attention op).  Such an op registers a grad emitter of its own
+(``<op>_grad``, which preempts the generic one) and, for what that emitter
+needs beyond the forward's inputs and the cotangents, a ``grad_maker``:
+``fused_attention`` (ops/nn_ops.py) hands its grad op the forward's ``Out``
+and row statistics.
 """
 
 from __future__ import annotations
@@ -56,35 +63,49 @@ class EmitCtx:
     executor recursion in while_op.cc / recurrent_op.cc).
     """
 
-    __slots__ = ("op", "attrs", "rng", "lower_block", "mode")
+    __slots__ = ("op", "attrs", "rng", "lower_block", "mode", "noted")
 
-    def __init__(self, op, rng=None, lower_block=None, mode="train"):
+    def __init__(self, op, rng=None, lower_block=None, mode="train",
+                 noted=None):
         self.op = op
         self.attrs = op.attrs
         self.rng = rng
         self.lower_block = lower_block  # callable(block_idx, env) -> env
         self.mode = mode                # "train" | "infer"
+        self.noted = noted              # the step's list of (what, args)
 
     def attr(self, name: str, default: Any = None) -> Any:
         return self.attrs.get(name, default)
+
+    def note(self, what: str, **args) -> None:
+        """Say how this op was lowered, where that was a choice: a tracer
+        instant ``lowering/<what>`` (once a trace, nothing a step; under
+        the executor's ``executor/compile`` span) and an entry in the
+        list of the step being built (``build_step_fn(...).noted``)."""
+        from ...observability.tracing import tracer
+
+        tracer().instant(f"lowering/{what}", cat="lowering", **args)
+        if self.noted is not None:
+            self.noted.append((what, args))
 
 
 class OpInfo:
     """Registered semantics for one op type."""
 
     __slots__ = ("type", "emit", "no_grad", "grad_maker", "stop_grad_slots",
-                 "needs_out_slots", "doc")
+                 "doc")
 
     def __init__(self, type: str, emit: Callable, no_grad: bool = False,
                  grad_maker: Optional[Callable] = None,
-                 stop_grad_slots: Sequence[str] = (),
-                 needs_out_slots: bool = False, doc: str = ""):
+                 stop_grad_slots: Sequence[str] = (), doc: str = ""):
         self.type = type
         self.emit = emit                      # (ctx, ins: dict[str, list]) -> dict[str, list]
         self.no_grad = no_grad
-        self.grad_maker = grad_maker          # custom desc-level grad maker
+        # (op, block) -> {slot: [Variable]}: forward-side inputs of the
+        # grad op beyond the forward's own inputs (its outputs, say); may
+        # add an output slot to the forward op it is given
+        self.grad_maker = grad_maker
         self.stop_grad_slots = tuple(stop_grad_slots)
-        self.needs_out_slots = needs_out_slots
         self.doc = doc
 
 
@@ -117,7 +138,8 @@ def registered_ops() -> List[str]:
 
 def _parse_slot(spec: str):
     """Slot spec mini-language: "X" required single, "Bias?" optional single,
-    "X*" variadic list."""
+    "X*" variadic list.  An optional OUTPUT is left unwritten when the
+    emitter returns None for it."""
     if spec.endswith("*"):
         return spec[:-1], "list"
     if spec.endswith("?"):
@@ -127,7 +149,8 @@ def _parse_slot(spec: str):
 
 def primitive(op_type: str, inputs: Sequence[str] = ("X",),
               outputs: Sequence[str] = ("Out",), no_grad: bool = False,
-              stop_grad_slots: Sequence[str] = (), seq_transparent: bool = False):
+              stop_grad_slots: Sequence[str] = (), seq_transparent: bool = False,
+              grad_maker: Optional[Callable] = None):
     """Decorator: register a function of (ctx, *input_slots) -> output value(s)
     as an op emitter.
 
@@ -142,7 +165,7 @@ def primitive(op_type: str, inputs: Sequence[str] = ("X",),
     the reference (they copy lod from input to output).
     """
     in_specs = [_parse_slot(s) for s in inputs]
-    out_names = list(outputs)
+    out_specs = [_parse_slot(s) for s in outputs]
 
     def deco(fn):
         def emit(ctx: EmitCtx, ins: Dict[str, list]) -> Dict[str, list]:
@@ -172,13 +195,15 @@ def primitive(op_type: str, inputs: Sequence[str] = ("X",),
                             f"op {op_type}: missing required input slot {name}")
                     args.append(vals[0])
             result = fn(ctx, *args)
-            if len(out_names) == 1:
+            if len(out_specs) == 1:
                 result = (result,)
             elif not isinstance(result, tuple):
                 raise ValueError(f"op {op_type}: expected tuple of "
-                                 f"{len(out_names)} outputs")
+                                 f"{len(out_specs)} outputs")
             out = {}
-            for slot, val in zip(out_names, result):
+            for (slot, kind), val in zip(out_specs, result):
+                if val is None and kind == "optional":
+                    continue
                 vals = list(val) if isinstance(val, list) else [val]
                 if lengths is not None:
                     vals = [SeqArray(v, lengths)
@@ -188,6 +213,7 @@ def primitive(op_type: str, inputs: Sequence[str] = ("X",),
 
         info = OpInfo(type=op_type, emit=emit, no_grad=no_grad,
                       stop_grad_slots=stop_grad_slots,
+                      grad_maker=grad_maker,
                       doc=inspect.getdoc(fn) or "")
         register(info)
         return fn

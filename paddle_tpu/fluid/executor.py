@@ -1025,7 +1025,7 @@ class Executor:
             # equal digests, within one process
             digest = hashlib.sha1(repr(key).encode()).hexdigest()[:12]
             with tr.span("executor/compile", cat="executor", mode=mode,
-                         key=digest):
+                         key=digest) as compile_args:
                 if policy is not None:
                     from ..resilience.guardrails import build_guarded_step_fn
 
@@ -1054,6 +1054,15 @@ class Executor:
                 else:
                     feed_sh = None
                 compiled = self._jit_step(step, in_sh)
+                # trace here, once (the dispatch below finds the trace
+                # cached), so that what the lowering notes lies under
+                # this span: which road each attention gradient took
+                compiled.trace(feed, state_vals,
+                               jax.ShapeDtypeStruct((2,), np.int32))
+                routes = [a["route"] for what, a in step.noted
+                          if what == "attn_grad"]
+                compile_args["attn_grad_direct"] = routes.count("direct")
+                compile_args["attn_grad_vjp"] = routes.count("vjp")
                 self._store_executable(key, (compiled, state_sh
                                              if mesh is not None else None,
                                              feed_sh))

@@ -577,6 +577,8 @@ _TEST_SENSITIVE_OPS = {
     "dropout": ("is_test",),
     "batch_norm": ("is_test",),
     "fused_attention": ("is_test",),
+    # with its forward: the two agree on dropout, whose mask they share
+    "fused_attention_grad": ("is_test",),
 }
 
 

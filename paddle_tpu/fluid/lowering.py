@@ -13,7 +13,10 @@ math under one SPMD partitioner.
 
 Gradient ops (``*_grad``) without a custom emitter are lowered generically via
 ``jax.vjp`` over the forward emitter (see core/registry.py for why this is
-sound and fast under XLA CSE).
+sound, and fast where XLA's CSE merges the recomputed forward with the
+forward op's: it does for XLA ops, it does NOT for custom calls, so an op
+whose forward is a Pallas kernel brings a grad emitter of its own, as
+``fused_attention`` does).
 
 RNG: each random op carries a build-time ``__rng_salt__`` attr; its key is
 ``fold_in(step_key, salt)``.  Grad ops inherit the salt, so a vjp-recomputed
@@ -23,7 +26,7 @@ gets by saving the mask tensor (dropout_op.cc) we get by key determinism.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, List, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -97,7 +100,7 @@ def _emit_generic_grad(ctx: EmitCtx, op: OpDesc, ins: Dict[str, list]):
 
     def fwd_selected(p):
         fctx = EmitCtx(fwd_op, rng=ctx.rng, lower_block=ctx.lower_block,
-                       mode=ctx.mode)
+                       mode=ctx.mode, noted=ctx.noted)
         outs = info.emit(fctx, p)
         sel = []
         for slot in grad_slot_order:
@@ -144,20 +147,22 @@ def _fix_grad(primal, g):
 
 
 def run_block_ops(desc: ProgramDesc, block_idx: int, env: Dict[str, Any],
-                  step_key, mode: str = "train") -> Dict[str, Any]:
+                  step_key, mode: str = "train",
+                  noted: Optional[list] = None) -> Dict[str, Any]:
     """Trace every op of a block into the caller's env (the in-trace analog of
-    the executor loop at executor.cc:116-138)."""
+    the executor loop at executor.cc:116-138).  ``noted`` receives what
+    emitters say of their lowering (``EmitCtx.note``)."""
     block = desc.block(block_idx)
 
     def lower_sub(idx: int, sub_env: Dict[str, Any]) -> Dict[str, Any]:
-        return run_block_ops(desc, idx, sub_env, step_key, mode)
+        return run_block_ops(desc, idx, sub_env, step_key, mode, noted)
 
     for idx, op in enumerate(block.ops):
         if op.type in MARKER_OPS or op.type in HOST_OPS:
             continue
         ins = _gather_inputs(op, env)
         ctx = EmitCtx(op, rng=_op_rng(op, idx, step_key),
-                      lower_block=lower_sub, mode=mode)
+                      lower_block=lower_sub, mode=mode, noted=noted)
         if has_op(op.type):
             outs = get_op_info(op.type).emit(ctx, ins)
         elif is_grad_op_type(op.type) and has_op(base_op_type(op.type)):
@@ -185,6 +190,9 @@ def build_step_fn(desc: ProgramDesc, block_idx: int,
 
     ``rng_bits`` is an int32[2] (seed, step) from which the step key is
     derived *inside* the computation — no host-side key splitting per step.
+
+    ``step.noted`` lists what the emitters said of their lowering
+    (``EmitCtx.note``: ``(what, args)``), filled as the step is traced.
     """
     feed_names = tuple(feed_names)
     state_in = tuple(state_in)
@@ -196,7 +204,8 @@ def build_step_fn(desc: ProgramDesc, block_idx: int,
         env: Dict[str, Any] = {}
         env.update(state)
         env.update(feeds)
-        env = run_block_ops(desc, block_idx, env, step_key, mode)
+        env = run_block_ops(desc, block_idx, env, step_key, mode,
+                            step.noted)
         fetches = [env[n] for n in fetch_names]
         new_state = {n: env[n] for n in state_out if n in env}
         return fetches, new_state
@@ -204,4 +213,5 @@ def build_step_fn(desc: ProgramDesc, block_idx: int,
     # the executable's name in a profile (``jit_train_step``): by mode, so
     # that a trace says which step ran and a refactor renames nothing
     step.__name__ = f"{mode}_step"
+    step.noted = []
     return step

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.registry import primitive
+from ..core.registry import OpInfo, primitive, register
 
 
 def _match_conv_dtype(x, w):
@@ -308,8 +308,75 @@ def im2sequence(ctx, x):
     return patches.reshape(b, f, oh * ow).transpose(0, 2, 1)
 
 
+def _attention_args(ctx):
+    """The kernel arguments a ``fused_attention`` op and its grad op both
+    read off their (shared) attributes and salted key: the grad op
+    inherits ``__rng_salt__``, so its dropout seed — the in-kernel hash
+    mask — is the forward's."""
+    rate = ctx.attr("dropout_rate", 0.0)
+    if ctx.attr("is_test", False) or ctx.mode == "infer":
+        rate = 0.0
+    seed = jax.random.bits(ctx.rng, (), jnp.uint32) if rate else None
+    return dict(causal=ctx.attr("causal", False),
+                sm_scale=ctx.attr("sm_scale", None),
+                impl=ctx.attr("impl", None), dropout_rate=rate,
+                dropout_seed=seed, layout=ctx.attr("layout", "bhld"))
+
+
+def _attention_backward_half(q_shape, k_shape, has_bias, impl, layout):
+    """``(direct, wants_lse)``: whether ``fused_attention_grad`` calls the
+    kernel's backward half on its forward op's results
+    (``kernels.flash_attention.backward_half``) and whether ``Lse`` is
+    among them.  Not direct — ``jax.vjp`` over the forward emitter —
+    under an active mesh (the sharded and sequence-parallel forms have
+    no backward half of their own yet) and at lengths the kernel pads.
+    Decided by what both ops of the pair see alike."""
+    from ...kernels.flash_attention import backward_half
+    from ...parallel import mesh as _pmesh
+
+    if _pmesh.current_mesh() is not None:
+        return False, False
+    return backward_half(q_shape, k_shape, has_bias, impl, layout)
+
+
+def _lse_shape(q_shape, layout):
+    """[b*h, lq] of a q in either layout (negative where b is dynamic)."""
+    seq, head = (1, 2) if layout == "blhd" else (2, 1)
+    return [q_shape[0] * q_shape[head], q_shape[seq]]
+
+
+def _attention_grad_inputs(op, block):
+    """Grad maker: the grad op takes the forward's ``Out`` and, where the
+    Pallas backward would read them, its row statistics — for which the
+    forward op it is given gains the output ``Lse``, float32 [b*h, lq].
+    Decided from the shapes alone, as for a TPU: the program is the same
+    whatever host built it, and the lowering, which sees the backend and
+    the mesh, leaves the slot unused where the statistics are not."""
+    from ...kernels.flash_attention import backward_half
+
+    out = block.var(op.desc.outputs["Out"][0])
+    extra = {"Out": [out]}
+    layout = op.attr("layout", "bhld")
+    q, k = (block.var(op.desc.inputs[s][0]) for s in ("Q", "K"))
+    if not q.shape or not k.shape:
+        return extra
+    shape = _lse_shape(q.shape, layout)
+    if shape[1] < 0 or _lse_shape(k.shape, layout)[1] < 0:
+        return extra
+    if backward_half(q.shape, k.shape, bool(op.desc.inputs.get("Bias")),
+                     op.attr("impl") or "pallas", layout)[1]:
+        if not op.desc.outputs.get("Lse"):
+            lse = block.create_var(
+                name=out.name + "@LSE", dtype="float32",
+                shape=shape if shape[0] > 0 else None, stop_gradient=True)
+            lse.op = op
+            op.desc.outputs["Lse"] = [lse.name]
+        extra["Lse"] = [block.var(op.desc.outputs["Lse"][0])]
+    return extra
+
+
 @primitive("fused_attention", inputs=["Q", "K", "V", "Bias?"],
-           outputs=["Out"])
+           outputs=["Out", "Lse?"], grad_maker=_attention_grad_inputs)
 def fused_attention(ctx, q, k, v, bias):
     """Fused scaled-dot-product attention over [b, h, l, d] tensors.
 
@@ -323,24 +390,28 @@ def fused_attention(ctx, q, k, v, bias):
     sequence parallelism the 2018 reference had no analog for.  Under any
     other mesh (data / tensor parallel) the kernel maps over the mesh's
     batch and head axes (flash_attention_sharded).
+
+    ``Lse`` exists only on an op whose grad maker added the slot (a
+    training program, where the Pallas backward will run): the row
+    statistics [b*h, lq] in a training step that takes the direct route,
+    and NaN in their place wherever ``fused_attention_grad`` will not
+    read them (XLA drops the dead array).  Every other op — every
+    inference program — is the primal-only call.
     """
     from ...kernels import flash_attention as _flash
     from ...kernels import flash_attention_sharded as _flash_sharded
     from ...kernels import ring_attention_sharded as _ring
     from ...kernels import ulysses_attention_sharded as _ulysses
+    from ...kernels.flash_attention import flash_attention_stats
 
-    causal = ctx.attr("causal", False)
-    sm_scale = ctx.attr("sm_scale", None)
-    impl = ctx.attr("impl", None)
-    layout = ctx.attr("layout", "bhld")
-    rate = ctx.attr("dropout_rate", 0.0)
-    if ctx.attr("is_test", False) or ctx.mode == "infer":
-        rate = 0.0
-    seed = None
-    if rate:
-        # per-op salted key; identical in the vjp-recomputed backward, so
-        # the in-kernel hash mask matches between forward and gradient
-        seed = jax.random.bits(ctx.rng, (), jnp.uint32)
+    kw = _attention_args(ctx)
+    layout = kw["layout"]
+    lse = None
+    if ctx.op.outputs.get("Lse"):
+        if ctx.mode == "train" and _attention_backward_half(
+                q.shape, k.shape, bias is not None, kw["impl"], layout)[1]:
+            return flash_attention_stats(q, k, v, **kw)
+        lse = jnp.full(_lse_shape(q.shape, layout), jnp.nan, jnp.float32)
     from ...parallel import mesh as _pmesh
 
     mesh = _pmesh.current_mesh()
@@ -357,22 +428,51 @@ def fused_attention(ctx, q, k, v, bias):
         shard_fn = _ring if sp_impl == "ring" else _ulysses
         if layout == "blhd":  # sp shards the seq axis of [b, h, l, d]
             q, k, v = (jnp.transpose(x, (0, 2, 1, 3)) for x in (q, k, v))
-        out = shard_fn(mesh, q, k, v, bias=bias, causal=causal,
-                       sm_scale=sm_scale,
-                       dp_axis="dp", mp_axis="mp", sp_axis="sp",
-                       dropout_rate=rate, dropout_seed=seed, impl=impl)
+        out = shard_fn(mesh, q, k, v, bias=bias, dp_axis="dp",
+                       mp_axis="mp", sp_axis="sp",
+                       **{a: kw[a] for a in kw if a != "layout"})
         if layout == "blhd":
             out = jnp.transpose(out, (0, 2, 1, 3))
-        return out
+        return out, lse
     if mesh is not None:
         b_ax, h_ax = _pmesh.kernel_axes(
             mesh, batch=q.shape[0],
             heads=q.shape[2 if layout == "blhd" else 1])
         return _flash_sharded(mesh, q, k, v, bias, batch_axis=b_ax,
-                              head_axis=h_ax, causal=causal,
-                              sm_scale=sm_scale, impl=impl,
-                              dropout_rate=rate, dropout_seed=seed,
-                              layout=layout)
-    return _flash(q, k, v, bias=bias, causal=causal, sm_scale=sm_scale,
-                  impl=impl, dropout_rate=rate, dropout_seed=seed,
-                  layout=layout)
+                              head_axis=h_ax, **kw), lse
+    return _flash(q, k, v, bias=bias, **kw), lse
+
+
+def _emit_fused_attention_grad(ctx, ins):
+    """Hand-written adjoint of ``fused_attention`` (preempts the generic
+    vjp, which would run the flash forward kernel a second time to get
+    what the forward op already produced): the kernel's backward half on
+    the forward's own ``Out`` and, at Pallas-backward lengths, ``Lse``.
+    Where ``_attention_backward_half`` says the direct route does not apply —
+    or the program was built without the slots it reads — the generic vjp
+    over the forward emitter, as for any other op."""
+    from ...kernels.flash_attention import flash_attention_grad
+    from ..lowering import _emit_generic_grad
+
+    q, k, v = (ins[s][0] for s in ("Q", "K", "V"))
+    bias, out, lse = (ins.get(s, [None])[0] for s in ("Bias", "Out", "Lse"))
+    kw = _attention_args(ctx)
+    direct, use_lse = False, False
+    if out is not None:
+        direct, use_lse = _attention_backward_half(
+            q.shape, k.shape, bias is not None, kw["impl"], kw["layout"])
+    if not direct or (use_lse and (lse is None or ctx.mode != "train")):
+        ctx.note("attn_grad", route="vjp", lse=False)
+        return _emit_generic_grad(
+            ctx, ctx.op,
+            {s: vals for s, vals in ins.items() if s not in ("Out", "Lse")})
+    ctx.note("attn_grad", route="direct", lse=use_lse)
+    grads = flash_attention_grad(
+        q, k, v, bias, out, lse if use_lse else None,
+        ins["Out@GRAD"][0].astype(out.dtype), **kw)
+    return {slot + "@GRAD": [g] for slot, g in
+            zip(("Q", "K", "V", "Bias"), grads) if g is not None}
+
+
+register(OpInfo("fused_attention_grad", _emit_fused_attention_grad,
+                no_grad=True, doc=_emit_fused_attention_grad.__doc__))

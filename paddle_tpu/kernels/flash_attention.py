@@ -47,6 +47,10 @@ LANES = 128   # stat tiles are [block, LANES] so no sublane transposes occur
 # logsumexp residual costs more HBM than recomputing the row stats, and
 # XLA can fuse the scan with the surrounding step (measured at s=256:
 # pallas bwd end-to-end was ~12% slower; at L >= 1024 it is 2-4x faster).
+# Measured when either backward paid for a second forward kernel call; a
+# program's grad op now takes the forward's own results (the two halves
+# below) and holds the statistics as [bh, lq], so the crossover is due a
+# new sweep (ROADMAP D8).
 # Tests monkeypatch this to 0 to exercise the kernels at tiny shapes.
 PALLAS_BWD_MIN_L = 1024
 
@@ -1572,15 +1576,18 @@ def _flash(q, k, v, bias, seed, offsets, sm_scale, causal, block_q,
     return _swap_lh(out, layout)
 
 
-def _use_pallas_bwd(impl, bias, q, layout) -> bool:
+def _use_pallas_bwd(impl, has_bias: bool, lq: int) -> bool:
     """Static routing: the dq/dkv Pallas kernels serve the bias-free path
     at long L; short sequences keep the XLA-scan backward (the [bh,lq,128]
     lse residual costs more than recomputing the stats there, and XLA
     fuses the scan into the surrounding step)."""
-    if impl not in ("pallas", "pallas_interpret") or bias is not None:
-        return False
-    lq = q.shape[1] if layout == "blhd" else q.shape[2]
-    return lq >= PALLAS_BWD_MIN_L
+    return (impl in ("pallas", "pallas_interpret") and not has_bias
+            and lq >= PALLAS_BWD_MIN_L)
+
+
+def _lq(x, layout) -> int:
+    """Sequence length of a q / k / v array in either layout."""
+    return x.shape[1] if layout == "blhd" else x.shape[2]
 
 
 def _flash_fwd(q, k, v, bias, seed, offsets, sm_scale, causal, block_q,
@@ -1590,7 +1597,8 @@ def _flash_fwd(q, k, v, bias, seed, offsets, sm_scale, causal, block_q,
         # save the lse residual only when the Pallas backward will read it;
         # otherwise the XLA backward recomputes the row stats blockwise
         # (cheaper than the [bh, lq, 128] HBM round-trip at short L)
-        need_lse = _use_pallas_bwd(impl, bias, q, layout)
+        need_lse = _use_pallas_bwd(impl, bias is not None,
+                                   _lq(q, layout))
         out, lse = _pallas_forward(q, k, v, bias, seed, off, sm_scale,
                                    causal, kv_len, block_q, block_k,
                                    dropout_rate, layout,
@@ -1610,7 +1618,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, impl, dropout_rate,
     q, k, v, bias, seed, offsets, out, lse = res
     off = offsets if use_offsets else None
     zero_off = jnp.zeros_like(offsets)   # int-carrier operand: zero cotangent
-    if _use_pallas_bwd(impl, bias, q, layout):
+    if _use_pallas_bwd(impl, bias is not None, _lq(q, layout)):
         dq, dk, dv = _pallas_backward(
             q, k, v, do, out, lse, seed, off, sm_scale, causal, kv_len,
             block_q, block_k, dropout_rate, layout,
@@ -1645,6 +1653,39 @@ def _default_block(l: int) -> int:
     if l >= 1024 and l % 512 == 0:
         return 512
     return 256
+
+
+def _plan(q, k, bias, causal, sm_scale, block_q, block_k, impl,
+          dropout_rate, dropout_seed, layout, block_offsets=None):
+    """What ``flash_attention`` and its two halves settle before a kernel
+    runs, from the same arguments in the same way: the seed and offset
+    carriers, the static arguments of ``_flash`` up to ``dropout_rate``,
+    whether offsets are in use, and the rows of padding (queries, keys)
+    that a block multiple needs."""
+    if layout not in ("bhld", "blhd"):
+        raise ValueError(f"unknown layout {layout!r}")
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    lq, lk = _lq(q, layout), _lq(k, layout)
+    if block_q is None:
+        block_q = _default_block(lq)
+    if block_k is None:
+        block_k = _default_block(lk)
+    if impl is None:
+        impl = default_impl()
+    if bias is not None and bias.ndim != 4:
+        raise ValueError(f"bias must be 4-d, got {bias.shape}")
+    dropout_rate = float(dropout_rate)
+    seed = dropout_carrier(dropout_rate, dropout_seed)
+    use_offsets = block_offsets is not None
+    if use_offsets:
+        offsets = offsets_carrier(*block_offsets)
+    else:
+        offsets = jnp.zeros(2, jnp.float32)
+    static = (float(sm_scale), bool(causal), int(block_q), int(block_k),
+              impl, dropout_rate)
+    return (seed, offsets, static, use_offsets,
+            (-lq) % min(block_q, lq), (-lk) % min(block_k, lk))
 
 
 def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
@@ -1683,56 +1724,112 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     such calls safely: the +inf lse makes their contribution vanish
     in the merged softmax.
     """
-    if layout not in ("bhld", "blhd"):
-        raise ValueError(f"unknown layout {layout!r}")
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    lq = q.shape[1] if layout == "blhd" else q.shape[2]
-    lk = k.shape[1] if layout == "blhd" else k.shape[2]
-    if block_q is None:
-        block_q = _default_block(lq)
-    if block_k is None:
-        block_k = _default_block(lk)
-    if impl is None:
-        impl = default_impl()
-    if bias is not None and bias.ndim != 4:
-        raise ValueError(f"bias must be 4-d, got {bias.shape}")
-    dropout_rate = float(dropout_rate)
-    seed = dropout_carrier(dropout_rate, dropout_seed)
-    use_offsets = block_offsets is not None
-    if use_offsets:
-        offsets = offsets_carrier(*block_offsets)
-    else:
-        offsets = jnp.zeros(2, jnp.float32)
-    pq = (-lq) % min(block_q, lq)
-    pk = (-lk) % min(block_k, lk)
+    seed, offsets, static, use_offsets, pq, pk = _plan(
+        q, k, bias, causal, sm_scale, block_q, block_k, impl, dropout_rate,
+        dropout_seed, layout, block_offsets)
     kv_len = None
     if pq or pk:
         # pad to block multiples: padded KEYS are masked in-kernel by the
         # static kv_len bound (no synthetic bias tensor — r3 built one and
         # paid its HBM reads); padded query rows are sliced off (their
         # cotangent is zero, so they can't contaminate dk/dv)
+        lq = _lq(q, layout)
         seq_axis = 1 if layout == "blhd" else 2
         padq = [(0, 0)] * 4
         padq[seq_axis] = (0, pq)
         padk = [(0, 0)] * 4
         padk[seq_axis] = (0, pk)
+        if pk:
+            kv_len = k.shape[seq_axis]
         q = jnp.pad(q, padq)
         k = jnp.pad(k, padk)
         v = jnp.pad(v, padk)
-        if pk:
-            kv_len = lk
         if bias is not None:
             bias = jnp.pad(bias, ((0, 0), (0, 0), (0, pq), (0, pk)))
-        out = _flash(q, k, v, bias, seed, offsets, float(sm_scale),
-                     bool(causal), int(block_q), int(block_k), impl,
-                     dropout_rate, kv_len, layout, use_offsets)
+        out = _flash(q, k, v, bias, seed, offsets, *static, kv_len, layout,
+                     use_offsets)
         if layout == "blhd":
             return out[:, :lq]
         return out[:, :, :lq, :]
-    return _flash(q, k, v, bias, seed, offsets, float(sm_scale),
-                  bool(causal), int(block_q), int(block_k), impl,
-                  dropout_rate, kv_len, layout, use_offsets)
+    return _flash(q, k, v, bias, seed, offsets, *static, kv_len, layout,
+                  use_offsets)
+
+
+# ---------------------------------------------------------------------------
+# The two halves, for a caller that keeps the forward's results itself
+# ---------------------------------------------------------------------------
+#
+# ``jax.vjp`` over ``flash_attention`` runs ``_flash_fwd``: a second forward
+# kernel call where the caller already ran the primal (a fluid program's
+# grad op, lowered apart from its forward op), and XLA's CSE does not merge
+# Mosaic custom calls.  A caller that holds the forward's output, and its
+# row statistics where the backward kernels read them, calls the backward
+# half directly instead.
+
+def backward_half(q_shape, k_shape, has_bias: bool,
+                  impl: Optional[str] = None, layout: str = "bhld"):
+    """``(applies, wants_lse)`` for ``flash_attention_grad`` at these
+    shapes.  It applies unless the lengths need padding to a block
+    multiple (that pair goes through ``jax.vjp``); it wants the forward's
+    row statistics (``flash_attention_stats``) where the Pallas dq/dkv
+    kernels read them, and finds them itself in the XLA scan (any bias,
+    short sequences)."""
+    seq_axis = 1 if layout == "blhd" else 2
+    lq, lk = q_shape[seq_axis], k_shape[seq_axis]
+    if lq % min(_default_block(lq), lq) or lk % min(_default_block(lk), lk):
+        return False, False
+    return True, _use_pallas_bwd(impl or default_impl(), has_bias, lq)
+
+
+def flash_attention_stats(q, k, v, causal: bool = False,
+                          sm_scale: Optional[float] = None,
+                          impl: Optional[str] = None,
+                          dropout_rate: float = 0.0, dropout_seed=None,
+                          layout: str = "bhld"):
+    """``flash_attention`` once, bias-free, with its row statistics:
+    ``(out, lse)``, ``lse`` float32 ``[B*H, Lq]`` — lane 0 of the
+    kernel's lane-broadcast ``[B*H, Lq, 128]`` output, which is what
+    lives from the forward to the backward (1 MB where the broadcast
+    form is 134 MB).  Only where ``backward_half`` wants them; not
+    differentiable (``flash_attention_grad`` is its other half)."""
+    seed, offsets, static, use_offsets, pq, pk = _plan(
+        q, k, None, causal, sm_scale, None, None, impl, dropout_rate,
+        dropout_seed, layout)
+    if pq or pk:
+        raise ValueError(f"lengths {q.shape}, {k.shape} need padding: "
+                         f"backward_half says it does not apply")
+    out, res = _flash_fwd(q, k, v, None, seed, offsets, *static, None,
+                          layout, use_offsets)
+    # tied to ``out``: whatever reads the output waits for the slice, so
+    # the broadcast form dies here and not when the backward first asks
+    # (left alone, XLA schedules the slice there: +0.8 GB at the peak of
+    # a 6+6-layer step at 8 x 2048, by the TPU compiler's own count)
+    return jax.lax.optimization_barrier((out, res[-1][:, :, 0]))
+
+
+def flash_attention_grad(q, k, v, bias, out, lse, do, causal: bool = False,
+                         sm_scale: Optional[float] = None,
+                         impl: Optional[str] = None,
+                         dropout_rate: float = 0.0, dropout_seed=None,
+                         layout: str = "bhld"):
+    """The backward half of ``flash_attention`` alone: ``(dq, dk, dv,
+    dbias)`` from the forward's own ``out`` (and ``lse`` where
+    ``backward_half`` wants it, else None) and the cotangent ``do``,
+    with the forward's arguments.  The same function the custom vjp
+    runs, on the same residuals, so the gradients are those of
+    ``jax.vjp`` over ``flash_attention`` — without its second forward."""
+    seed, offsets, static, use_offsets, pq, pk = _plan(
+        q, k, bias, causal, sm_scale, None, None, impl, dropout_rate,
+        dropout_seed, layout)
+    if pq or pk:
+        raise ValueError(f"lengths {q.shape}, {k.shape} need padding: "
+                         f"backward_half says it does not apply")
+    if lse is not None:
+        # back to the lane-broadcast tiles the dq/dkv kernels read, as
+        # short-lived as when the forward kernel wrote them for this call
+        lse = jnp.broadcast_to(lse[:, :, None], (*lse.shape, LANES))
+    return _flash_bwd(*static, None, layout, use_offsets,
+                      (q, k, v, bias, seed, offsets, out, lse), do)[:4]
 
 
 def flash_attention_sharded(mesh: Mesh, q, k, v,

@@ -290,6 +290,7 @@ def build_guarded_step_fn(desc, block_idx: int, feed_names: Sequence[str],
         return [outs[idx[n]] for n in fetch_names], gated, healthy
 
     step.__name__ = f"{mode}_step"      # as build_step_fn names its own
+    step.noted = base.noted
     return step
 
 

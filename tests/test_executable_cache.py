@@ -46,6 +46,7 @@ def resolving():
                 seen.append(first[0])
             return jitted(*args)
 
+        call.trace = jitted.trace       # the executor traces inside its span
         return call
 
     with pytest.MonkeyPatch.context() as mp:

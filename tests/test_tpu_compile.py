@@ -163,3 +163,57 @@ def test_grouped_expert_product_compiles_for_v5e(m, k, n, one_chip):
     hlo = jax.jit(lambda a, w, g: grouped_matmul(a, w, g, impl="pallas")) \
         .lower(*_shapes(args, one_chip)).compile().as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("seq, backward_kernels", [(256, 0), (1024, 1)],
+                         ids=["xla-backward", "pallas-backward"])
+def test_training_step_calls_the_flash_forward_once_an_op_on_v5e(
+        seq, backward_kernels, one_chip, monkeypatch):
+    """The training step of a one-layer Transformer (heads of 64, bfloat16
+    activations, no bias tensors: the train cells' attention), lowered
+    and compiled for the chip at a length under and a length at
+    ``PALLAS_BWD_MIN_L``: three attention ops, three ``flash_fwd`` Mosaic
+    calls (ISSUE 31; the generic gradient made six), the dq and dkv
+    kernels once each where they run, and the statistics between forward
+    and backward one float a row."""
+    import re
+
+    import jax
+
+    from paddle_tpu.models import transformer as T
+
+    monkeypatch.setattr(sys.modules["paddle_tpu.kernels.flash_attention"],
+                        "default_impl", lambda: "pallas")
+    b, heads = 2, 2
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        cost, _, _ = T.transformer(
+            src_vocab_size=96, trg_vocab_size=96, max_length=seq + 1,
+            n_layer=1, n_head=heads, d_key=D, d_value=D, d_model=heads * D,
+            d_inner_hid=256, dropout_rate=0.0, src_seq_len=seq,
+            trg_seq_len=seq, fused=True, materialize_attn_bias=False,
+            amp_dtype="bfloat16")
+        fluid.optimizer.Adam(learning_rate=1e-4).minimize(cost)
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    feed = {n: np.zeros((b, seq), np.int32) for n in
+            ("src_word", "trg_word", "lbl_word", "src_pos", "trg_pos")}
+    feed["lbl_weight"] = np.ones((b, seq), np.float32)
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        feed, state, step = exe._prepare_step(main, feed, [cost], scope,
+                                              "train")
+    args = _shapes((feed, state, np.zeros(2, np.int32)), one_chip)
+    lowered = exe._jit_step(step).lower(*args)
+    kernels = re.findall(r'kernel_name = "(\w+)"', lowered.as_text())
+    assert kernels.count("flash_fwd") == 3
+    assert kernels.count("flash_bwd_dq") == 3 * backward_kernels
+    assert kernels.count("flash_bwd_dkv") == 3 * backward_kernels
+    hlo = lowered.compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == len(kernels)
+    entry = hlo[hlo.index("ENTRY "):]
+    # what the forward kernel writes and the backward kernels read, and
+    # what is held between them
+    broadcast = hlo_results_of_size(entry, b * heads * seq * 128)
+    compact = re.findall(rf"= f32\[{b * heads},{seq}\]\S* fusion\(", entry)
+    assert broadcast.get("broadcast", 0) == 3 * backward_kernels
+    assert len(compact) == 3 * backward_kernels
