@@ -23,7 +23,8 @@ __all__ = [
     "nce", "im2sequence", "beam_search", "beam_search_decode", "batch_gather",
     "gather", "expand", "multiplex", "fused_attention", "decode_attention",
     "ragged_decode_attention", "rms_norm", "rotary_embedding", "swiglu",
-    "routed_experts", "vocab_logits", "quantize", "dequantize", "quantized_mul",
+    "routed_experts", "gated_ffn", "latent_absorb", "vocab_logits",
+    "quantize", "dequantize", "quantized_mul",
     "quantized_matmul", "quantized_conv2d",
     "pad", "crop", "lod_reset", "lrn", "label_smooth", "rank_loss",
     "margin_rank_loss", "log_loss", "conv_shift", "row_conv",
@@ -887,7 +888,7 @@ def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
                             layer=0, n_layer=1, causal=True, sm_scale=None,
                             impl=None, scales=None, name=None, v_pool=None,
                             window=None, sink=None, ring_top=None,
-                            out_scale=None, scope=None):
+                            out_scale=None, scope=None, latent_values=None):
     """Attention of per-lane query blocks against the paged KV pool,
     walking each lane's page list (ops/cache_ops.ragged_decode_attention;
     the Pallas kernel lives in kernels/flash_attention).  q [B, C, H, D]
@@ -903,7 +904,12 @@ def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
     q-window < j <= q), ``sink`` ([H], a logit per query head in the
     softmax's denominator only), ``ring_top`` ([B]: the table is a ring
     of pages), ``out_scale`` (the result times a constant) and ``scope``
-    (the name its device operations carry in a trace)."""
+    (the name its device operations carry in a trace).
+
+    With ``latent_values`` the cache is ONE pool of latent rows ([R, page,
+    Dk], one KV head all H query heads share) whose leading
+    ``latent_values`` columns are the values: q [B, C, H, Dk] gives
+    [B, C, H, latent_values]; ``out_scale`` and ``scope`` as above."""
     helper = LayerHelper("ragged_decode_attention", name=name)
     out = helper.create_tmp_variable(q.dtype, stop_gradient=True)
     attrs = {"layer": int(layer), "n_layer": int(n_layer),
@@ -923,7 +929,7 @@ def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
         if var is not None:
             inputs[slot] = var
     for key, val in (("window", window), ("out_scale", out_scale),
-                     ("scope", scope)):
+                     ("scope", scope), ("latent_values", latent_values)):
         if val is not None:
             attrs[key] = val
     helper.append_op("ragged_decode_attention", inputs, {"Out": out}, attrs)
@@ -983,13 +989,15 @@ def swiglu(gate, up, name=None):
 
 def routed_experts(x, n_experts, held, first_expert, top_k, d_inner,
                    param_prefix, dtype=None, live=None, impl=None,
-                   name=None):
+                   name=None, routed_scale=None):
     """A device's share of a routed-expert layer (ops/llm_ops.
     routed_experts): routes over all ``n_experts`` in float32 (``x`` is
     the float32 norm output; sigmoid scores, a selection bias, ``top_k`` a
     token), computes the ``held`` experts from ``first_expert`` on in
     ``dtype``.  ``live`` [T] marks the rows that are a request's tokens;
-    the others make no pair.  Parameters, under ``param_prefix``:
+    the others make no pair.  ``routed_scale`` (a model's
+    ``routed_scaling_factor``) multiplies the normalised weights.
+    Parameters, under ``param_prefix``:
     ``router.w`` [d, n_experts] and ``router.bias`` [n_experts] (float32),
     ``experts.gate.w`` / ``experts.up.w`` [held, d, d_inner],
     ``experts.down.w`` [held, d_inner, d] (``dtype``).  Returns (out
@@ -1018,9 +1026,45 @@ def routed_experts(x, n_experts, held, first_expert, top_k, d_inner,
     attrs = {"top_k": int(top_k), "first_expert": int(first_expert)}
     if impl is not None:
         attrs["impl"] = impl
+    if routed_scale is not None:
+        attrs["routed_scale"] = float(routed_scale)
     helper.append_op("routed_experts", inputs, {"Out": out, "Load": load},
                      attrs)
     return out, load
+
+
+def gated_ffn(x, d_inner, param_prefix, dtype=None, scope=None, name=None):
+    """A SiLU-gated feed-forward as one op (ops/llm_ops.gated_ffn), whose
+    device operations carry ``scope`` in a trace.  Parameters, under
+    ``param_prefix`` and in ``dtype``: ``gate.w`` / ``up.w`` [d, d_inner],
+    ``down.w`` [d_inner, d].  ``x`` of any float type; out in ``dtype``."""
+    from ..param_attr import ParamAttr
+
+    helper = LayerHelper("gated_ffn", name=name)
+    dtype = dtype or x.dtype
+    d = x.shape[-1]
+    shapes = {"WGate": ("gate.w", [d, d_inner]), "WUp": ("up.w", [d, d_inner]),
+              "WDown": ("down.w", [d_inner, d])}
+    inputs = {"X": x}
+    for slot, (suffix, shape) in shapes.items():
+        inputs[slot] = helper.create_parameter(
+            ParamAttr(name=f"{param_prefix}.{suffix}", keep_dtype=True),
+            shape=shape, dtype=dtype)
+    out = helper.create_tmp_variable(dtype, stop_gradient=True)
+    helper.append_op("gated_ffn", inputs, {"Out": out},
+                     {} if scope is None else {"scope": str(scope)})
+    return out
+
+
+def latent_absorb(x, w, side, d_nope, name=None):
+    """Latent attention's up-projection ``w`` [r, H * (d_nope + dv)]
+    absorbed (ops/llm_ops.latent_absorb): ``side`` ``"query"`` takes x
+    [T, H, d_nope] to [T, H, r], ``"output"`` x [T, H, r] to [T, H * dv]."""
+    helper = LayerHelper("latent_absorb", name=name)
+    out = helper.create_tmp_variable(x.dtype, stop_gradient=True)
+    helper.append_op("latent_absorb", {"X": x, "W": w}, {"Out": out},
+                     {"side": str(side), "d_nope": int(d_nope)})
+    return out
 
 
 # ---------------------------------------------------------------------------

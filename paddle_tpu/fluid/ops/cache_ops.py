@@ -196,19 +196,27 @@ def ragged_decode_attention(ctx, q, pool, page_table, lengths, q_base,
               sm_scale=ctx.attr("sm_scale", None),
               impl=ctx.attr("impl", None), scales=scales)
     mesh = _pmesh.current_mesh()
-    if v_pool is not None:
+    latent = ctx.attr("latent_values", None)
+    if v_pool is not None or latent is not None:
         # a split pool pair (keys in Pool, values in VPool): grouped KV
         # heads, a window, a sink, a ring of pages; attr ``out_scale``
-        # multiplies the result (a model's value scale)
+        # multiplies the result (a model's value scale).  Attr
+        # ``latent_values`` in VPool's place: Pool holds latent rows whose
+        # leading that-many columns are the values (ONE pool)
         if mesh is not None:
             raise NotImplementedError(
                 "ragged_decode_attention: split pools are not mapped over "
                 "a mesh")
         scope = ctx.attr("scope", None) or "attn/paged"
+        if latent is not None:
+            # the absorbed form: queries and outputs carry the latent's
+            # up-projection, attention runs against the rows themselves
+            ctx.note("attn_latent", form="absorbed", tile=int(q.shape[1]),
+                     row=int(pool.shape[2]), values=int(latent))
         with jax.named_scope(scope):
             out = _ra(q, pool, page_table, lengths, q_base, v_pool=v_pool,
                       window=ctx.attr("window", None), sink=sink,
-                      ring_top=ring_top,
+                      ring_top=ring_top, latent_values=latent,
                       kernel_name="paged_" + scope.replace("/", "_"), **kw)
             scale = ctx.attr("out_scale", None)
             return out if scale is None else \

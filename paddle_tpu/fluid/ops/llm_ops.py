@@ -1,7 +1,9 @@
 """Ops of a decoder-only language-model block as today's open models build
 it: RMS normalisation, partial rotary position embedding, the SiLU-gated
-feed-forward's gate, a routed-expert layer that computes ITS OWN experts'
-part of the result, and the row write of a split paged KV pool.
+feed-forward (its gate alone, and whole), a routed-expert layer that
+computes ITS OWN experts' part of the result, latent attention's
+up-projection absorbed into queries and outputs, and the row write of a
+paged KV pool (one of a split pair, or a latent kind's only one).
 
 All are inference ops (``no_grad``).  Products take the activations' type
 (bfloat16 in serving) with float32 accumulation; normalisation, rotary
@@ -61,24 +63,33 @@ def swiglu(ctx, gate, up):
 @primitive("paged_row_write", inputs=["Pool", "Value", "Pages", "Offsets"],
            outputs=["Out"], no_grad=True)
 def paged_row_write(ctx, pool, value, pages, offsets):
-    """Write one row a token into ONE pool of a split pair
+    """Write one row a token into ONE pool: one of a split pair, or the
+    only pool of a latent kind
     (``kernels.flash_attention.split_kv_rows``): Value [T, ...] flattens
-    to the pool's row width, Pages / Offsets [T] int32 say where (page 0
-    is the trash page).  Out aliases Pool: an in-place row scatter under
+    to the pool's row width (or less: the rest of the row is zero), Pages
+    / Offsets [T] int32 say where (page 0 is the trash page).  Out aliases
+    Pool: an in-place row scatter under
     donation, the one ``paged_cache_write`` does for K and V each."""
     from ...kernels.flash_attention import split_kv_rows
 
     rows = split_kv_rows(pages, int(ctx.attr("layer", 0)),
                          int(ctx.attr("n_layer", 1)))
+    width = value.size // value.shape[0]
+    if width < pool.shape[2]:
+        # a latent row in whole lane tiles: zero past its own columns
+        value = jnp.pad(value.reshape(value.shape[0], width),
+                        ((0, 0), (0, pool.shape[2] - width)))
     return _scatter_tokens(pool, rows.reshape(-1),
                            jnp.asarray(offsets).astype(jnp.int32).reshape(-1),
                            value)
 
 
-def route_top_k(x, router_w, router_bias, top_k: int):
+def route_top_k(x, router_w, router_bias, top_k: int, routed_scale=None):
     """Sigmoid scores over every expert, the ``top_k`` largest of score +
     selection bias, and their weights (scores over the selected scores'
-    sum: the bias selects and never weighs), all in float32 as the
+    sum: the bias selects and never weighs; with ``routed_scale`` the
+    published ``routed_scaling_factor`` times scores over that sum + 1e-20,
+    the published denominator), all in float32 as the
     published gate computes them: ``x`` is the float32 norm output and
     the product is a float32 product (on a TPU the default would round
     both operands to bfloat16; a selection turns on the eighth score
@@ -89,7 +100,11 @@ def route_top_k(x, router_w, router_bias, top_k: int):
     scores = jax.nn.sigmoid(logits)
     _, idx = jax.lax.top_k(scores + router_bias.astype(jnp.float32), top_k)
     sel = jnp.take_along_axis(scores, idx, axis=-1)
-    return idx.astype(jnp.int32), sel / jnp.sum(sel, axis=-1, keepdims=True)
+    total = jnp.sum(sel, axis=-1, keepdims=True)
+    if routed_scale is None:
+        return idx.astype(jnp.int32), sel / total
+    return idx.astype(jnp.int32), \
+        sel / (total + 1e-20) * jnp.float32(routed_scale)
 
 
 @primitive("routed_experts",
@@ -108,7 +123,8 @@ def routed_experts(ctx, x, router_w, router_bias, w_gate, w_up, w_down,
     products; pairs for absent experts cost nothing and nothing stands in
     for them, so Out [T, d] is this device's part of the sum (the 32
     shares of a 32-way layer add up to the whole).  No capacity: no pair
-    is ever dropped.  Live [T] (optional; nonzero = a request's token):
+    is ever dropped.  Attr ``routed_scale`` (optional) multiplies the
+    normalised weights.  Live [T] (optional; nonzero = a request's token):
     the rows of no request (an idle lane, a chunk's padding) make no
     pair, cost nothing and come out zero.  Load [held] int32 is the
     pairs each held expert got."""
@@ -121,7 +137,8 @@ def routed_experts(ctx, x, router_w, router_bias, w_gate, w_up, w_down,
     dtype = w_gate.dtype
     t, d = x.shape
     with jax.named_scope("moe/route"):
-        experts, weights = route_top_k(x, router_w, router_bias, top_k)
+        experts, weights = route_top_k(x, router_w, router_bias, top_k,
+                                       ctx.attr("routed_scale", None))
         local = experts - first
         here = jnp.logical_and(local >= 0, local < held)         # [T, k]
         if live is not None:
@@ -149,6 +166,47 @@ def routed_experts(ctx, x, router_w, router_bias, w_gate, w_up, w_down,
         out = jnp.sum((y[place].astype(jnp.float32) * w)
                       .reshape(t, top_k, d), axis=1)
     return out.astype(dtype), load
+
+
+@primitive("gated_ffn", inputs=["X", "WGate", "WUp", "WDown"],
+           outputs=["Out"], no_grad=True)
+def gated_ffn(ctx, x, w_gate, w_up, w_down):
+    """``(silu(x WGate) * (x WUp)) WDown`` in the weights' type with
+    float32 accumulation (the gate itself float32): a dense feed-forward,
+    or the shared experts every token passes.  Attr ``scope`` names its
+    device operations in a trace."""
+    dtype = w_gate.dtype
+    with jax.named_scope(ctx.attr("scope", None) or "ffn"):
+        xs = x.astype(dtype)
+        gate = jnp.matmul(xs, w_gate, preferred_element_type=jnp.float32)
+        up = jnp.matmul(xs, w_up, preferred_element_type=jnp.float32)
+        hid = (jax.nn.silu(gate) * up).astype(dtype)
+        return jnp.matmul(hid, w_down,
+                          preferred_element_type=jnp.float32).astype(dtype)
+
+
+@primitive("latent_absorb", inputs=["X", "W"], outputs=["Out"], no_grad=True)
+def latent_absorb(ctx, x, w):
+    """Latent attention's up-projection absorbed into what surrounds the
+    cache, so that attention runs against the latent rows themselves.  W
+    [r, H * (d_nope + dv)] maps a token's latent (r wide) to every head's
+    keys (its first ``d_nope`` columns of a head: W_UK[h]) and values
+    (the rest: W_UV[h]).  Attr ``side``: ``"query"`` takes X [T, H, d_nope]
+    to X[h] W_UK[h]^T [T, H, r] (a score against the latent is the score
+    against the expanded key); ``"output"`` takes X [T, H, r], the
+    probabilities' sum over latents, to X[h] W_UV[h] [T, H * dv].
+    Products in X's type, float32 accumulation."""
+    d_nope = int(ctx.attr("d_nope"))
+    t, h = x.shape[0], x.shape[1]
+    wh = w.reshape(w.shape[0], h, -1).astype(x.dtype)
+    with jax.named_scope("mla/absorb"):
+        if ctx.attr("side") == "query":
+            out = jnp.einsum("thd,rhd->thr", x, wh[:, :, :d_nope],
+                             preferred_element_type=jnp.float32)
+            return out.astype(x.dtype)
+        out = jnp.einsum("thr,rhd->thd", x, wh[:, :, d_nope:],
+                         preferred_element_type=jnp.float32)
+        return out.reshape(t, -1).astype(x.dtype)
 
 
 @primitive("vocab_logits", inputs=["X", "W"], outputs=["Out"], no_grad=True)

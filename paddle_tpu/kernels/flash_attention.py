@@ -360,14 +360,25 @@ def _ragged_pallas(q, pool, page_table, lengths, q_base, layer, n_layer,
 
 
 # ---------------------------------------------------------------------------
-# Split pools: grouped KV heads, unequal key/value widths, window, sink
+# Split pools: grouped KV heads, unequal key/value widths, window, sink;
+# and the latent form, ONE pool whose rows hold keys and values both
 # ---------------------------------------------------------------------------
 #
-# A decoder-only model whose layers differ in cache shape declares one
-# pool pair per KIND of layer: keys in ``[R, page, Hkv*Dk]``, values in
-# ``[R, page, Hkv*Dv]``, both token-major, physical row = page *
-# n_layer + layer (``split_kv_rows``; page 0 is the trash page).  H query
-# heads read Hkv KV heads (head h reads h // (H/Hkv)).  A window layer
+# A decoder-only model whose layers differ in cache shape declares a pool,
+# or a pool pair, per KIND of layer.  A pair: keys in ``[R, page,
+# Hkv*Dk]``, values in ``[R, page, Hkv*Dv]``, both token-major, physical
+# row = page * n_layer + layer (``split_kv_rows``; page 0 is the trash
+# page).  H query heads read Hkv KV heads (head h reads h // (H/Hkv)).
+# ONE pool (latent attention, ``latent_values``): a token's row is one KV
+# head that all H query heads share, and its leading ``latent_values``
+# columns are the values too: the page block is read once, scores contract
+# the whole row, no second pool and no second DMA.  The pool is ``[R,
+# page, latent_row_width(Dk)]``: the row in whole lane tiles, zero past
+# its Dk columns.  (A bfloat16 ``[R, 256, 576]`` array is not token-major
+# on the chip at all: the TPU lays it out page-dimension-minor to save
+# the padding, and every kernel call then begins with a transposing copy
+# of the whole pool.  Token-major tiles hold 640 columns either way.)
+# A window layer
 # keeps its pages as a RING: the table's slot i holds the newest logical
 # page congruent to i, so the walk is as long as the ring, never as the
 # context; ``ring_top`` [B] is the logical page of each lane's newest
@@ -375,9 +386,16 @@ def _ragged_pallas(q, pool, page_table, lengths, q_base, layer, n_layer,
 
 
 def split_kv_rows(page_table, layer: int, n_layer: int):
-    """Logical page table -> physical rows of one layer in a split pool
-    pair (the same rows in the key pool and in the value pool)."""
+    """Logical page table -> physical rows of one layer in a kind's pool,
+    or pool pair (the same rows in the key pool and in the value pool)."""
     return jnp.asarray(page_table).astype(jnp.int32) * n_layer + layer
+
+
+def latent_row_width(d_key: int) -> int:
+    """The width a latent kind's pool rows are allocated in: ``d_key``
+    in whole lane tiles (576 -> 640), so that the pool is token-major on
+    the chip like every other pool."""
+    return -(-int(d_key) // LANES) * LANES
 
 
 def _ring_page(slot, top, n_slots):
@@ -387,18 +405,26 @@ def _ring_page(slot, top, n_slots):
 
 
 def _split_xla(q, k_pool, v_pool, page_table, lengths, q_base, ring_top,
-               layer, n_layer, sm_scale, window, sink):
+               layer, n_layer, sm_scale, window, sink, latent_values=None):
     """Gather form: every addressed page, masked.  The reference for the
-    kernel below and the path of a host without Mosaic."""
+    kernel below and the path of a host without Mosaic.  ``v_pool`` None:
+    the latent form, values = the key rows' leading ``latent_values``
+    columns."""
     b, c, h, dk = q.shape
     _r, ps, kw = k_pool.shape
-    hkv = kw // dk
-    dv = v_pool.shape[2] // hkv
-    g = h // hkv
     n_pages = page_table.shape[1]
     rows = split_kv_rows(page_table, layer, n_layer)
-    k = k_pool[rows].reshape(b, n_pages * ps, hkv, dk).astype(q.dtype)
-    v = v_pool[rows].reshape(b, n_pages * ps, hkv, dv).astype(q.dtype)
+    if v_pool is None:
+        hkv, dv = 1, int(latent_values)
+        k = k_pool[rows][..., :dk].reshape(b, n_pages * ps, 1, dk) \
+            .astype(q.dtype)
+        v = k[..., :dv]
+    else:
+        hkv = kw // dk
+        dv = v_pool.shape[2] // hkv
+        k = k_pool[rows].reshape(b, n_pages * ps, hkv, dk).astype(q.dtype)
+        v = v_pool[rows].reshape(b, n_pages * ps, hkv, dv).astype(q.dtype)
+    g = h // hkv
     slot = jnp.arange(n_pages, dtype=jnp.int32)[None, :]
     if ring_top is not None:
         page = _ring_page(slot, ring_top.astype(jnp.int32)[:, None],
@@ -455,7 +481,8 @@ def _split_kernel(rows_ref, meta_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
                   v_width, c, ps, n_pages, window, ring, sm_scale):
     """grid (B, P) like ``_ragged_kernel``; q rides [Hkv, G*C, k_width]:
     KV head j's rows stack the C queries of each of its G query heads,
-    zero outside the head's own lanes of its slice."""
+    zero outside the head's own lanes of its slice.  ``v_ref`` None (the
+    latent form): the values are columns of the key block already here."""
     b = pl.program_id(0)
     p = pl.program_id(1)
     hkv = len(k_starts)
@@ -480,7 +507,7 @@ def _split_kernel(rows_ref, meta_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
     @pl.when(live)
     def _page():
         k = k_ref[0]                       # [ps, Hkv*Dk]
-        v = v_ref[0]                       # [ps, Hkv*Dv]
+        v = k if v_ref is None else v_ref[0]               # [ps, Hkv*Dv]
         for j in range(hkv):               # static KV-head loop
             q = q_ref[0, j]                # [G*C, k_width]
             kj = k[:, k_starts[j]:k_starts[j] + k_width]
@@ -531,12 +558,12 @@ def _split_kernel(rows_ref, meta_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
 
 def _split_pallas(q, k_pool, v_pool, page_table, lengths, q_base, ring_top,
                   layer, n_layer, sm_scale, window, sink, interpret,
-                  name="ragged_paged_attn_gqa"):
+                  name="ragged_paged_attn_gqa", latent_values=None):
     b, c, h, dk = q.shape
     _r, ps, kw = k_pool.shape
-    hkv = kw // dk
-    vw = v_pool.shape[2]
-    dv = vw // hkv
+    latent = v_pool is None
+    hkv = 1 if latent else kw // dk
+    dv = int(latent_values) if latent else v_pool.shape[2] // hkv
     g = h // hkv
     n_pages = page_table.shape[1]
     rows = split_kv_rows(page_table, layer, n_layer)
@@ -546,7 +573,9 @@ def _split_pallas(q, k_pool, v_pool, page_table, lengths, q_base, ring_top,
     lengths = jnp.asarray(lengths, jnp.int32).reshape(b)
     meta = jnp.stack([lengths, jnp.asarray(q_base, jnp.int32).reshape(b),
                       top])
-    k_starts, k_width, k_offs = _head_slices(hkv, dk)
+    # a latent row is taken whole: q is zero past its own Dk columns
+    k_starts, k_width, k_offs = ([0], kw, [0]) if latent \
+        else _head_slices(hkv, dk)
     v_starts, v_width, v_offs = _head_slices(hkv, dv)
     # [B, C, Hkv, G, Dk] -> per KV head [G*C, k_width], the head's Dk
     # lanes at their place in its slice
@@ -565,9 +594,11 @@ def _split_pallas(q, k_pool, v_pool, page_table, lengths, q_base, ring_top,
         return (rw[bi, pi], 0, 0)
 
     in_specs = [pl.BlockSpec((1, hkv, g * c, k_width), q_map),
-                pl.BlockSpec((1, ps, kw), kv_map),
-                pl.BlockSpec((1, ps, vw), kv_map)]
-    args = [qk, k_pool, v_pool]
+                pl.BlockSpec((1, ps, kw), kv_map)]
+    args = [qk, k_pool]
+    if not latent:
+        in_specs.append(pl.BlockSpec((1, ps, v_pool.shape[2]), kv_map))
+        args.append(v_pool)
     if have_sink:
         sk = jnp.broadcast_to(
             jnp.asarray(sink, jnp.float32).reshape(hkv, g, 1, 1),
@@ -592,8 +623,9 @@ def _split_pallas(q, k_pool, v_pool, page_table, lengths, q_base, ring_top,
         n_pages=n_pages, window=None if window is None else int(window),
         ring=ring, sm_scale=sm_scale)
 
-    def kernel(rows_ref, meta_ref, q_ref, k_ref, v_ref, *rest):
+    def kernel(rows_ref, meta_ref, q_ref, k_ref, *rest):
         rest = list(rest)
+        v_ref = None if latent else rest.pop(0)
         sink_ref = rest.pop(0) if have_sink else None
         return base(rows_ref, meta_ref, q_ref, k_ref, v_ref, sink_ref, *rest)
 
@@ -618,18 +650,21 @@ SPLIT_VMEM_BYTES = 16 * 1024 * 1024
 
 def split_query_tile(chunk: int, n_head: int, kv_heads: int, d_key: int,
                      d_value: int, page_size: int, itemsize: int,
-                     vmem_bytes: Optional[int] = None) -> int:
+                     vmem_bytes: Optional[int] = None,
+                     latent: bool = False) -> int:
     """How many queries of a prompt chunk one lane of the split kernel
     takes: the largest of chunk, chunk / 2, chunk / 4 ... whose blocks fit
     ``vmem_bytes`` (default ``SPLIT_VMEM_BYTES``).  A lane holds, per query and query head: q and the
     output (double-buffered blocks), the running max, sum and float32
     accumulator, the sink's block; besides a page of keys and values
-    (double-buffered) and one KV head's scores, mask and probabilities."""
+    (double-buffered; ``latent``: ONE row holds both, so a page of key
+    rows alone) and one KV head's scores, mask and probabilities."""
     _, k_width, _ = _head_slices(kv_heads, d_key)
     _, v_width, _ = _head_slices(kv_heads, d_value)
     per_row = 2 * (k_width + v_width) * itemsize \
         + (2 * LANES + v_width) * 4 + 2 * LANES * 4
-    pages = 2 * page_size * kv_heads * (d_key + d_value) * itemsize
+    pages = 2 * page_size * kv_heads \
+        * (d_key + (0 if latent else d_value)) * itemsize
 
     def need(c):
         return n_head * c * per_row + pages \
@@ -659,7 +694,9 @@ def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
                             scales=None, v_pool=None,
                             window: Optional[int] = None, sink=None,
                             ring_top=None,
-                            kernel_name: Optional[str] = None) -> jax.Array:
+                            kernel_name: Optional[str] = None,
+                            latent_values: Optional[int] = None
+                            ) -> jax.Array:
     """Attention of per-lane query blocks against a paged KV pool.
 
     Shapes:
@@ -683,24 +720,36 @@ def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
     q_base = _resolve_q_base(q, q_base, causal)
     if impl is None:
         impl = default_impl()
-    if v_pool is not None:
+    if v_pool is not None or latent_values is not None:
         # split pools (keys in ``pool``, values in ``v_pool``, row =
         # page * n_layer + layer): grouped KV heads (H a multiple of
         # Hkv = pool width / Dk), values of another width than keys,
         # ``window`` (keys q-window < j <= q), ``sink`` [H] (a logit per
         # query head in the softmax's denominator only) and ``ring_top``
         # [B] (the table is a ring of pages: see ``split_kv_rows``);
-        # ``kernel_name`` is the Mosaic call's name in a device trace
+        # ``kernel_name`` is the Mosaic call's name in a device trace.
+        # ``latent_values`` in ``v_pool``'s place: ONE pool of latent
+        # rows [R, page, >= Dk] (``latent_row_width``) whose leading
+        # ``latent_values`` columns are the values; q [B, C, H, Dk] ->
+        # [B, C, H, latent_values]
         if scales is not None or not causal:
             raise ValueError("ragged_decode_attention: split pools are "
                              "causal and take no int8 scales")
+        if latent_values is not None and (
+                v_pool is not None or pool.shape[2] < q.shape[-1]
+                or not 0 < int(latent_values) <= q.shape[-1]):
+            raise ValueError(
+                "ragged_decode_attention: latent_values is the width of "
+                "the values inside ONE pool's rows (no v_pool), which "
+                "are at least as wide as the queries")
         args = (q, pool, v_pool, page_table, lengths, q_base, ring_top,
                 layer, n_layer, float(sm_scale), window, sink)
         if impl in ("pallas", "pallas_interpret"):
             return _split_pallas(
                 *args, interpret=(impl == "pallas_interpret"),
-                name=kernel_name or "ragged_paged_attn_gqa")
-        return _split_xla(*args)
+                name=kernel_name or "ragged_paged_attn_gqa",
+                latent_values=latent_values)
+        return _split_xla(*args, latent_values=latent_values)
     if window is not None or sink is not None or ring_top is not None:
         raise ValueError("ragged_decode_attention: window, sink and "
                          "ring_top need split pools (v_pool)")

@@ -29,13 +29,16 @@ TILE_M = 128        # rows a tile; M must be a multiple of it on the TPU path
 
 def _tile(n: int, want: int) -> int:
     """The largest multiple of 128 that divides ``n`` and is at most
-    ``want`` (``n`` itself below one lane tile: the tests' sizes)."""
+    ``want`` (``n`` itself below one lane tile: the tests' sizes).  Where
+    no such divisor reaches half of ``want`` and ``n`` is under twice it,
+    ``n`` whole: 1408 = 11 x 128 has no divisor between 128 and itself,
+    and one tile of 1408 is one transfer where eleven of 128 are eleven."""
     if n % 128:
         return n
     t = min(want, n) // 128 * 128
     while n % t:
         t -= 128
-    return t
+    return n if 2 * t < min(want, n) and n <= 2 * want else t
 
 
 def grouped_matmul(lhs, rhs, group_sizes, *, out_dtype=None,

@@ -22,8 +22,9 @@ from . import (  # noqa: F401
 # Decoder-only language models the paged engine serves
 # (``serving.paged_lm.PagedLMGenerator``): the module named like the
 # ``model_type`` of the published configuration gives ``config_from_dict``,
-# ``cache_specs``, ``param_shapes`` and ``build_serve_step``.
-DECODER_LMS = ("mimo_v2_flash",)
+# ``cache_specs`` (per kind of layer a pool, or a pool pair:
+# ``cache_spec.CacheSpec``), ``param_shapes`` and ``build_serve_step``.
+DECODER_LMS = ("mimo_v2_flash", "deepseek_v3")
 
 
 def decoder_lm(model_type: str):

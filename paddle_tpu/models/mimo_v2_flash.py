@@ -31,6 +31,7 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 from .. import fluid
 from ..fluid import layers
 from ..fluid.param_attr import ParamAttr
+from .cache_spec import CacheSpec
 
 __all__ = ["LMConfig", "CacheSpec", "config_from_dict", "cache_specs",
            "param_shapes", "build_serve_step", "GLOBAL", "WINDOW"]
@@ -105,21 +106,6 @@ class LMConfig(NamedTuple):
 # ``build_serve_step``; of the configuration object the engine reads
 # ``vocab_size`` only.
 config_from_dict = LMConfig.from_dict
-
-
-class CacheSpec(NamedTuple):
-    """What one KIND of layer keeps of a token, and for how long."""
-    kind: str
-    layers: Tuple[int, ...]         # the model's layers of this kind
-    q_heads: int
-    kv_heads: int
-    d_key: int
-    d_value: int
-    window: Optional[int]           # None: every position is kept
-
-    def token_bytes(self, itemsize: int) -> int:
-        return len(self.layers) * self.kv_heads \
-            * (self.d_key + self.d_value) * itemsize
 
 
 def cache_specs(c: LMConfig) -> Dict[str, CacheSpec]:

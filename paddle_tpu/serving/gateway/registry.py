@@ -265,7 +265,7 @@ class ModelRegistry:
             return int(plan.peak_bytes), dict(plan.components)
         if kind == "lm_generator":
             # the decoder-only generator: parameters in the type they
-            # are resident in, a pool pair per kind of layer
+            # are resident in, a pool or a pool pair per kind of layer
             plan = estimate_lm_hbm(config)
             return int(plan.peak_bytes), dict(plan.components)
         if kind == "engine" and dirname:

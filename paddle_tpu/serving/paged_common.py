@@ -2,7 +2,8 @@
 program variable and as a device array, a page table turned into a step's
 write targets, and an artifact's tensors put into a scope.
 ``PagedTransformerGenerator`` (encoder-decoder, one pool for every layer)
-and ``PagedLMGenerator`` (decoder-only, a pool pair per kind of layer)
+and ``PagedLMGenerator`` (decoder-only, a pool or a pool pair per kind of
+layer)
 both call these."""
 
 from __future__ import annotations

@@ -10,8 +10,11 @@ sliding-window layers the last ``window``.  The model is a BUILDER the
 generator is given or finds by the published ``model_type``
 (``models.decoder_lm``): ``config_from_dict``, ``cache_specs``,
 ``param_shapes``, ``build_serve_step``; no model is named here.  So a request holds
-pages of two GROUPS, each with its own pool pair, allocator, table and
-accounting (``paging.PageGroup``):
+pages of one GROUP a kind, each with its own pool or pool pair, allocator,
+table and accounting (``paging.PageGroup``).  A kind whose values are the
+leading columns of its key row (latent attention: ``CacheSpec.latent``)
+has ONE pool, allocated, donated, counted and reported as one; every
+other kind a key pool and a value pool.
 
 * **global** pages grow with the context: table slot = position // page;
 * **window** pages are a RING: slot = (position // page) % ring width,
@@ -19,7 +22,7 @@ accounting (``paging.PageGroup``):
   behind ``position - window`` — a 1000-token generation holds a bounded
   number of them.
 
-Admission reserves a request's worst case in BOTH groups, so a running
+Admission reserves a request's worst case in EVERY group, so a running
 request never waits for a page.
 
 One compiled dispatch a step, over a FLAT batch of tokens: one decode
@@ -47,7 +50,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from .. import fluid
-from ..kernels.flash_attention import split_query_tile
+from ..kernels.flash_attention import latent_row_width, split_query_tile
 from ..models import decoder_lm
 from ..observability import tracing as _obs_tracing
 from .paged_common import ceil_div, token_slots, zero_pool
@@ -75,7 +78,8 @@ def _refuse(what: str) -> "NotImplementedError":
 def lm_pool_layout(config: Dict, builder=None) -> Dict:
     """Everything static about the pools a manifest ``config`` describes:
     the model, and per kind of layer the page size, the table's width, the
-    number of pages and the pool pair's names and shapes.  Shared by the
+    number of pages and the names and shapes of its pool (``k`` alone, a
+    latent kind) or pool pair (``k`` and ``v``).  Shared by the
     generator's constructor and the registry's HBM estimate, so the two
     cannot disagree.  ``builder`` (default: the one the model's
     ``model_type`` names) is the model, as the module docstring says."""
@@ -115,20 +119,26 @@ def lm_pool_layout(config: Dict, builder=None) -> Dict:
             pages = config.get("window_pages")
             pages = lanes * table + 1 if pages is None else int(pages)
         rows = pages * len(spec.layers)
+        # a latent row is allocated in whole lane tiles
+        k_width = latent_row_width(spec.d_key) if spec.latent \
+            else spec.kv_heads * spec.d_key
         groups[kind] = {
             "spec": spec, "page_size": ps, "table": table,
             "num_pages": pages, "decode_pages": decode_pages,
             "dtype": kv_dtype,
             "k": f"{prefix}@kv_pool.{kind}.k",
-            "v": f"{prefix}@kv_pool.{kind}.v",
-            "k_shape": [rows, ps, spec.kv_heads * spec.d_key],
-            "v_shape": [rows, ps, spec.kv_heads * spec.d_value]}
+            "k_shape": [rows, ps, k_width]}
+        if not spec.latent:
+            groups[kind].update(
+                v=f"{prefix}@kv_pool.{kind}.v",
+                v_shape=[rows, ps, spec.kv_heads * spec.d_value])
     # prefill attention runs in tiles of queries, each a lane of the
     # ragged kernel: as many as the kernel's fast memory takes, of the
     # kind of layer that takes fewest
     tile = min(split_query_tile(
-        chunk, g["spec"].q_heads, g["spec"].kv_heads, g["spec"].d_key,
-        g["spec"].d_value, g["page_size"], _KV_ITEMSIZE[kv_dtype])
+        chunk, g["spec"].q_heads, g["spec"].kv_heads,
+        g["k_shape"][2] // g["spec"].kv_heads, g["spec"].d_value,
+        g["page_size"], _KV_ITEMSIZE[kv_dtype], latent=g["spec"].latent)
         for g in groups.values())
     return {"builder": builder, "model": c, "prefix": prefix,
             "src_len": src_len, "max_out_len": max_out, "lanes": lanes,
@@ -136,6 +146,12 @@ def lm_pool_layout(config: Dict, builder=None) -> Dict:
             "dtype": str(config.get("dtype", "float32")),
             "prefill_slots": int(config.get("prefill_slots", 1)),
             "tile": tile, "impl": config.get("attn_impl")}
+
+
+def _pools(group: Dict):
+    """(name, shape) of a kind's pool, or of each of its pool pair."""
+    return [(group[p], group[f"{p}_shape"]) for p in ("k", "v")
+            if p in group]
 
 
 def _build(layout: Dict, n_prefill: int):
@@ -149,8 +165,8 @@ def _build(layout: Dict, n_prefill: int):
 def estimate_lm_hbm(config: Dict, builder=None):
     """Static peak-HBM plan of the largest serve step a manifest config
     describes (every prefill slot full), from its DESC: parameters in the
-    type they are resident in, both pool pairs, and the step's
-    activations.  No device allocation."""
+    type they are resident in, every kind's pool or pool pair, and the
+    step's activations.  No device allocation."""
     from ..fluid.analysis.cost import plan_program
 
     if config.get("mesh_axes"):
@@ -233,6 +249,11 @@ class PagedLMGenerator:
         self._steps = 0
         self._pairs = 0
         self._touched = 0       # (step, layer, expert) with a pair or more
+        # layers that keep a latent row a token, and the rows written
+        self._latent_layers = sum(len(g["spec"].layers)
+                                  for g in lay["groups"].values()
+                                  if g["spec"].latent)
+        self._latent_rows = 0
         loads = self._steps_built[0][4]     # [expert layers, held] or None
         self._load = np.zeros([int(n) for n in loads.shape]
                               if loads is not None else (0, 0), np.int64)
@@ -241,8 +262,8 @@ class PagedLMGenerator:
     # -- pools ---------------------------------------------------------------
     def _reset_pools(self) -> None:
         for g in self.layout["groups"].values():
-            zero_pool(self.scope, g["k"], g["k_shape"], g["dtype"])
-            zero_pool(self.scope, g["v"], g["v_shape"], g["dtype"])
+            for name, shape in _pools(g):
+                zero_pool(self.scope, name, shape, g["dtype"])
 
     def param_dtypes(self) -> Dict[str, str]:
         """name -> the type the step program declares each parameter in:
@@ -399,7 +420,9 @@ class PagedLMGenerator:
             # the rows that are a request's tokens: the others route to
             # no expert
             feed["live"] = np.zeros(T, np.int32)
-        for k in ("len", "base", "top"):
+        # ``top`` (a lane's newest window page) where a kind is a ring
+        ring = any(g["decode_pages"] is not None for g in kinds.values())
+        for k in ("len", "base") + (("top",) if ring else ()):
             feed[f"dec_{k}"] = np.zeros(B, np.int32)
             if n_pf:
                 feed[f"pf_{k}"] = np.zeros(S, np.int32)
@@ -421,7 +444,8 @@ class PagedLMGenerator:
             if "live" in feed:
                 feed["live"][slot] = 1
             feed["dec_len"][slot], feed["dec_base"][slot] = t + 1, t
-            feed["dec_top"][slot] = t // ring_ps
+            if ring:
+                feed["dec_top"][slot] = t // ring_ps
             for kind, g in kinds.items():
                 ps = g["page_size"]
                 if t % ps == 0 or t // ps not in lane.pages[kind]:
@@ -445,7 +469,8 @@ class PagedLMGenerator:
             base = done + tq * np.arange(tiles.stop - tiles.start)
             feed["pf_base"][tiles] = base
             feed["pf_len"][tiles] = np.minimum(done + m, base + tq)
-            feed["pf_top"][tiles] = (done + m - 1) // ring_ps
+            if ring:
+                feed["pf_top"][tiles] = (done + m - 1) // ring_ps
             for kind, g in kinds.items():
                 ps, held = g["page_size"], lane.pages[kind]
                 self._pages_for(slot, lane, kind, done, done + m - 1)
@@ -497,6 +522,7 @@ class PagedLMGenerator:
                 self._load += load
                 self._pairs += int(load.sum())
                 self._touched += int(np.count_nonzero(load))
+            written = len(decoding)
             for slot in decoding:
                 lane = self._lanes[slot]
                 lane.pos += 1
@@ -507,12 +533,14 @@ class PagedLMGenerator:
                 tr.instant("lane/prefill_chunk", cat="serving", slot=slot,
                            tokens=lane.chunk, done=lane.done + lane.chunk,
                            total=len(lane.prompt), **who)
+                written += lane.chunk
                 lane.done += lane.chunk
                 lane.pos = lane.done
                 lane.chunk = 0
                 if lane.done >= len(lane.prompt):
                     self._finish_prefill(slot, lane)
                     rows[slot] = self.lanes + s
+            self._latent_rows += written * self._latent_layers
             emitted = {}
             for slot, row in rows.items():
                 self._lanes[slot].cur = emitted[slot] = int(ids[row])
@@ -554,11 +582,13 @@ class PagedLMGenerator:
                              return_numpy=False, mode="infer")
 
     def kv_bytes_per_token(self) -> int:
-        """Bytes a cached token costs in the layers that keep it for good
-        (the global group); a window layer's cost does not grow with the
+        """Bytes a cached token costs, as allocated, in the layers that
+        keep it for good (the global group; a pool's or a pool pair's row
+        width a layer); a window layer's cost does not grow with the
         context."""
         item = _KV_ITEMSIZE[self.kv_dtype]
-        return sum(g["spec"].token_bytes(item)
+        return sum(len(g["spec"].layers) * item
+                   * sum(shape[2] for _, shape in _pools(g))
                    for g in self.layout["groups"].values()
                    if g["decode_pages"] is None)
 
@@ -573,7 +603,11 @@ class PagedLMGenerator:
         the benchmark's per-layer readers."""
         out = {"steps": self._steps, "moe_pairs_here": self._pairs,
                "experts_touched": self._touched,
-               "expert_load": self._load.tolist()}
+               "expert_load": self._load.tolist(),
+               "kv_bytes_per_token": self.kv_bytes_per_token()}
+        if self._latent_layers:
+            # (token, layer) rows scattered into a latent kind's one pool
+            out["latent_rows_written"] = self._latent_rows
         for kind, group in self.groups.items():
             st = group.stats()
             out[f"{kind}_pages_in_use"] = st["in_use"]
@@ -584,7 +618,7 @@ class PagedLMGenerator:
     def cache_stats(self) -> Dict[str, object]:
         item = _KV_ITEMSIZE[self.kv_dtype]
         pool_bytes = {
-            kind: int(np.prod(g["k_shape"]) + np.prod(g["v_shape"])) * item
+            kind: sum(int(np.prod(shape)) for _, shape in _pools(g)) * item
             for kind, g in self.layout["groups"].items()}
         return {"executable": self.exe.cache_stats()["executable"],
                 "pages": {k: g.stats() for k, g in self.groups.items()},

@@ -552,8 +552,9 @@ class PageAllocator:
 # ---------------------------------------------------------------------------
 # A model whose layers differ in what they keep of a token (every position
 # in a global-attention layer, the last ``window`` in a sliding-window one)
-# has one split pool pair per KIND of layer, and a request holds pages of
-# each.  A ``PageGroup`` is one kind's allocator: a free list over its own
+# has a pool, or a pool pair, per KIND of layer (a pair of key and value
+# pools; ONE pool where a latent row holds both), and a request holds pages
+# of each.  A ``PageGroup`` is one kind's allocator: a free list over its own
 # pool and an account of what each holder may still take.  Admission
 # RESERVES a holder's worst case in every group, so a page taken inside a
 # reservation never fails and a step never has to preempt; the pages

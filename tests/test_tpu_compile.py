@@ -217,3 +217,94 @@ def test_training_step_calls_the_flash_forward_once_an_op_on_v5e(
     compact = re.findall(rf"= f32\[{b * heads},{seq}\]\S* fusion\(", entry)
     assert broadcast.get("broadcast", 0) == 3 * backward_kernels
     assert len(compact) == 3 * backward_kernels
+
+
+@pytest.mark.parametrize("b, c", [(128, 1), (8, 64)],
+                         ids=["decode", "prefill-tile"])
+def test_latent_kernel_compiles_for_v5e(b, c, one_chip):
+    """The latent form of the split kernel (ISSUE 32) at the published
+    widths, in bfloat16: 16 query heads on ONE 576-wide row a token whose
+    leading 512 columns are the values, the pool in whole lane tiles
+    (640), for 128 lanes' single query and for a prefill tile of 64.  The
+    pool goes to the kernel as it lies (no copy, no temporary of its
+    size: a 576-wide pool is laid out page-dimension-minor by the TPU and
+    transposed whole before every call)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels.flash_attention import ragged_decode_attention
+
+    def call(q, pool, tbl, lengths, base):
+        return ragged_decode_attention(
+            q, pool, tbl, lengths, base, layer=1, n_layer=5, impl="pallas",
+            latent_values=512, sm_scale=192 ** -0.5,
+            kernel_name="paged_attn_latent")
+
+    ints = np.zeros(b, np.int32)
+    args = (jnp.zeros((b, c, 16, 576), jnp.bfloat16),
+            jnp.zeros((65 * 5, 256, 640), jnp.bfloat16),
+            np.zeros((b, 32), np.int32), ints, ints)
+    compiled = jax.jit(call).lower(*_shapes(args, one_chip)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_attn_latent" in hlo
+    pool_bytes = 65 * 5 * 256 * 640 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
+
+
+def test_latent_serve_step_compiles_for_v5e(one_chip):
+    """The donated serve step of ``moonlight-16b-a3b-l5`` (ISSUE 32) at
+    its published widths, 128 lanes and two chunks of 256, compiled by
+    the chip's compiler from shapes alone: 10 latent attention calls and
+    12 grouped expert products are Mosaic calls, the one pool is aliased
+    to its output and updated in place (five row scatters, no copy or
+    transpose of its size), and the step's temporaries are a fraction of
+    it."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.fluid.lowering import build_step_fn
+    from paddle_tpu.serving import PagedLMGenerator
+    from perfbench.families import deepseek_v3 as fam
+
+    with open("perfbench/configs/moonlight-16b-a3b-l5.json",
+              encoding="utf-8") as f:
+        cfg = json.load(f)
+    conf = dict(fam.serving(cfg)["manifest"]["config"], attn_impl="pallas",
+                num_pages=161)
+    gen = PagedLMGenerator(executor=fluid.Executor(fluid.CPUPlace()), **conf)
+    gen.open_slots(conf["lanes"])
+    prog, _, next_ids, _, loads = gen._steps_built[2]
+    feed, _ = gen._feed([], 2)
+    fetch = [next_ids.name, loads.name]
+    _, _, _, state_in, state_out = gen.exe._classified(
+        gen.exe._program_key(prog), feed, fetch, prog.desc.global_block())
+    step = build_step_fn(prog.desc, 0, list(feed), state_in, state_out,
+                         fetch, "infer")
+    shapes = gen.builder.param_shapes(gen.model, gen.prefix)
+    dtypes = gen.param_dtypes()
+    pool = gen.layout["groups"]["global"]
+    assert set(state_in) == set(shapes) | {pool["k"]}
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    state = {n: described(shapes[n], dtypes[n]) for n in shapes}
+    state[pool["k"]] = described(pool["k_shape"], pool["dtype"])
+    compiled = gen.exe._jit_step(step).lower(
+        _shapes(feed, one_chip), state,
+        described((2,), np.int32)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 5 * 2 + 4 * 3
+    assert hlo.count("paged_attn_latent") >= 10
+    assert "input_output_alias" in hlo.splitlines()[0]
+    assert ("attn_latent", {"form": "absorbed", "tile": 64, "row": 640,
+                            "values": 512}) in step.noted
+    n_elems = int(np.prod(pool["k_shape"]))    # 161 pages: no other match
+    kinds = hlo_results_of_size(hlo[hlo.index("ENTRY "):], n_elems)
+    assert kinds.pop("fusion") == 5, kinds              # the row scatters
+    assert set(kinds) <= {"parameter", "bitcast"}, kinds
+    assert compiled.memory_analysis().temp_size_in_bytes < n_elems * 2 / 4
