@@ -22,7 +22,7 @@ of 4):
   profiler's clock, beside the device's own lines (inert without a
   session);
 * ``instant(name, **args)`` — zero-duration "i" event (lifecycle marks:
-  submitted / admitted / token / retired);
+  submitted / admitted / token / retired), now or ``at`` a recorded mark;
 * ``complete(name, start, end, **args)`` — an X event from timestamps
   recorded elsewhere (the scheduler builds the whole-request span from
   the Request's own submitted/finished marks).
@@ -73,7 +73,9 @@ class Tracer:
 
     def __init__(self, capacity: int = 65536, enabled: bool = True):
         # innermost-but-one rank: emits happen under the scheduler and
-        # router locks (span instants from _retire_locked/_note_token)
+        # router locks (the scheduler's spans and admission instants;
+        # the per-token and retirement instants come from its delivery
+        # thread, under no lock of the scheduler's)
         self._lock = OrderedLock("obs.tracer", RANK_TRACER)
         self._events: deque = deque(maxlen=int(capacity))
         self._ids = itertools.count(1)
@@ -105,10 +107,15 @@ class Tracer:
             ev["args"] = args
         return ev
 
-    def instant(self, name: str, cat: str = "", **args) -> None:
+    def instant(self, name: str, cat: str = "",
+                at: Optional[float] = None, **args) -> None:
+        """``at``: a perf_counter mark recorded elsewhere (the scheduler
+        stamps a step's tokens in its loop and emits their instants from
+        the delivery thread); now where there is none."""
         if not self.enabled:
             return
-        ev = self._base(name, cat, "i", time.perf_counter(), args)
+        ev = self._base(name, cat, "i",
+                        time.perf_counter() if at is None else at, args)
         ev["s"] = "t"               # thread-scoped instant
         self._emit(ev)
 

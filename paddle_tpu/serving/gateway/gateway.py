@@ -78,8 +78,9 @@ class TokenStream:
         self._q: "_queue.Queue" = _queue.Queue()
         self._handed = 0            # tokens handed to the consumer, to 2
 
-    # the scheduler-side callback (runs under the scheduler lock: a
-    # lock-free enqueue is all that happens here)
+    # the scheduler-side callback (runs in the scheduler's delivery
+    # thread, which serves every stream: a lock-free enqueue is all that
+    # happens here)
     def _push(self, req: Request, tok: Optional[int]) -> None:
         self._q.put(self._DONE if tok is None else int(tok))
 
@@ -133,6 +134,19 @@ class TokenStream:
             pass
 
 
+def _check_pages(inst) -> None:
+    """``check_invariants=True``: audit a served instance's page books."""
+    check = getattr(inst, "check_invariants", None)
+    if callable(check):
+        # a speculative pair checks BOTH pools (its .alloc is only the
+        # target's)
+        check()
+    else:
+        alloc = getattr(inst, "alloc", None)
+        if alloc is not None:
+            alloc.check_invariants()
+
+
 class Gateway:
     """Multi-model, multi-tenant serving front door."""
 
@@ -154,8 +168,13 @@ class Gateway:
         self.journal = (RequestJournal(journal_path, fsync=journal_fsync)
                         if journal_path else None)
         # PageAllocator.check_invariants after every retirement — the
-        # steady-state leak tripwire the cancellation tests run under
+        # steady-state leak tripwire the cancellation tests run under.
+        # It runs in the scheduler's retire bookkeeping, on the thread
+        # that freed the pages, not in the completion callback: that is
+        # the delivery thread's, which reads while the step loop writes
         self.check_invariants = bool(check_invariants)
+        if self.check_invariants:
+            self.sched.retire_check = _check_pages
         # ranked BELOW the scheduler: _swap_guard holds it across
         # add/remove_model (which take the scheduler lock); wedged()
         # deliberately reads sched.stats() BEFORE taking it
@@ -374,8 +393,7 @@ class Gateway:
         return self.registry.entries()
 
     # -- request path --------------------------------------------------------
-    def _wrap_on_token(self, jid: Optional[str], slo: str, inst,
-                       user_cb=None):
+    def _wrap_on_token(self, jid: Optional[str], slo: str, user_cb=None):
         """Compose journal completion + gateway metrics + the caller's
         callback into the scheduler's per-token hook."""
 
@@ -417,16 +435,6 @@ class Gateway:
                     self.journal.record_done(
                         jid, ok=ok,
                         error=None if ok else type(req.error).__name__)
-                if self.check_invariants:
-                    check = getattr(inst, "check_invariants", None)
-                    if callable(check):
-                        # a speculative pair checks BOTH pools (its
-                        # .alloc is only the target's)
-                        check()
-                    else:
-                        alloc = getattr(inst, "alloc", None)
-                        if alloc is not None:
-                            alloc.check_invariants()
             if user_cb is not None:
                 user_cb(req, tok)
         return on_token
@@ -549,8 +557,7 @@ class Gateway:
                 req = self.sched.submit(
                     prompt, max_new_tokens=eff_new, model=model,
                     tenant=tenant, decode=decode, session=session,
-                    on_token=self._wrap_on_token(jid, cfg.slo, inst,
-                                                 on_token))
+                    on_token=self._wrap_on_token(jid, cfg.slo, on_token))
             except BaseException as e:
                 # the scheduler refused it (infeasible prompt, too long):
                 # close the journal entry, or a restart would replay a
@@ -641,15 +648,14 @@ class Gateway:
         for entry in self.journal.pending():
             cfg = self.router.tenant(entry["tenant"])
             try:
-                inst = self.registry.instance(entry["model"])
+                self.registry.instance(entry["model"])  # KeyError: gone
                 req = self.sched.submit(
                     np.asarray(entry["prompt"], np.int64),
                     max_new_tokens=entry["max_new"],
                     model=entry["model"], tenant=entry["tenant"],
                     decode=entry.get("decode"),
                     session=entry.get("session"),
-                    on_token=self._wrap_on_token(entry["jid"], cfg.slo,
-                                                 inst))
+                    on_token=self._wrap_on_token(entry["jid"], cfg.slo))
             except Exception as e:
                 # the model is gone, the prompt no longer fits, or the
                 # pool can never hold it in the restarted process:

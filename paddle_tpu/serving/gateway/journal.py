@@ -41,8 +41,10 @@ class RequestJournal:
     ``record_submit`` is synchronous — the durability point is BEFORE
     the request queues.  ``record_done`` is asynchronous (a background
     writer drains a queue): it is called from the scheduler's
-    completion callback, which runs under the scheduler lock, and a
-    file write there would stall admission behind the filesystem (the
+    completion callback, which runs in the scheduler's one delivery
+    thread (under the scheduler lock still for a request that never
+    reached a lane), and a file write there would stall every stream —
+    there, admission — behind the filesystem (the
     PR 9 review bug the ISSUE 13 lint now catches statically).  The
     at-least-once model absorbs the weaker ordering: a done record lost
     to a crash merely replays one already-answered request.  A ``done``

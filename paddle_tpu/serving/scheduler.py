@@ -62,11 +62,23 @@ ISSUE 10 grows the scheduler into the gateway's shared execution core:
   in-flight lanes to completion, joins the thread, and fails any
   still-queued requests with ``SchedulerShutdown`` (returned to the
   caller for journal-driven resubmission).
+
+ISSUE 36 takes delivery off the step loop.  Under the state lock a step
+keeps what the next admission needs (tokens appended, stamps, the
+end/cap test, the lane and its pages) and then hands ONE record to an
+unbounded queue; ONE consumer — the ``serving-delivery`` thread beside
+``serve()``, the loop's driver without one — works the records off in
+order: every ``on_token`` callback, the end-of-stream sentinel and THEN
+``Request._done``, then the step's histograms, counters and trace
+instants.  Every path that retires an admitted request puts its end on
+the same queue under the same lock, so no end overtakes a token;
+``stats()["delivery"]`` reports the backlog, which is not bounded.
 """
 
 from __future__ import annotations
 
 import itertools
+import queue
 import threading
 import time
 import weakref
@@ -77,7 +89,7 @@ import numpy as np
 
 from ..observability import metrics as _obs_metrics
 from ..observability import tracing as _obs_tracing
-from ..utils.sync import (RANK_COLLECTOR_INIT, RANK_SCHEDULER,
+from ..utils.sync import (RANK_COLLECTOR_INIT, RANK_DELIVERY, RANK_SCHEDULER,
                           OrderedCondition, OrderedLock)
 from .paging import PoolCapacityError
 
@@ -138,6 +150,10 @@ def suggest_model_axis(components, available, max_axis=64):
 
 # tokens-per-request is a count histogram, not a latency one
 _TOKEN_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512)
+
+# the shortest sleep there is (the OS makes it some 60 us): how the step
+# loop offers the interpreter to the delivery thread after a hand-off
+_OFFER_S = 1e-6
 
 
 class RequestCancelled(RuntimeError):
@@ -250,8 +266,12 @@ class Request:
         self.route_to: Optional[str] = None
         self.tenant = tenant
         # on_token(req, tok) per decoded token and on_token(req, None)
-        # once at completion — called under the scheduler lock, so it
-        # must be fast and non-blocking (the streaming layer enqueues)
+        # once at completion — called by the delivery thread (or, with
+        # no serve() thread, by whoever drives the loop), in token order
+        # and OFF the scheduler lock.  One thread delivers every
+        # request's tokens, so a slow callback delays the others'
+        # streams (never the step loop): keep it fast and non-blocking
+        # (the streaming layer enqueues)
         self.on_token = on_token
         self.tokens: List[int] = []
         self.error: Optional[BaseException] = None
@@ -379,6 +399,29 @@ class ContinuousBatchingScheduler:
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
         self._draining = False
+        # -- delivery: everything per token or per request that has a
+        # listener (streaming callbacks, the end-of-stream sentinel,
+        # ``Request._done``, histograms, trace instants) leaves the step
+        # loop as ONE record a step on this queue — put as the last act
+        # under the state lock, so the queue's order is the lock's order
+        # whichever thread retires — and ONE consumer works it off in
+        # order: the ``serving-delivery`` thread beside ``serve()``'s
+        # loop, or with no loop thread whoever drives the loop, inline
+        # (``_deliver_inline``).  No end can overtake a token.
+        self._outbox: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._deliverer: Optional[threading.Thread] = None
+        self._deliverer_halt = False
+        # serializes inline consumers (callers' threads); ranked just
+        # under the state lock: held while callbacks run, and callbacks
+        # may take the scheduler's lock but never the reverse
+        self._deliver_lock = OrderedLock("serving.delivery", RANK_DELIVERY)
+        self._delivered = 0         # records worked off, by the consumer
+        self._delivery = {"handoffs": 0, "tokens": 0, "backlog_max": 0,
+                          "inline": 0}
+        # tests' page-leak tripwire (``Gateway(check_invariants=True)``):
+        # retire_check(model) runs in the retire bookkeeping, under the
+        # state lock, on the thread that freed the pages
+        self.retire_check: Optional[Callable] = None
         # alias -> lane-group key, applied at admission time (and for
         # submit-time feasibility checks); identity by default.  The
         # gateway registry swaps versions by flipping what this returns.
@@ -524,11 +567,15 @@ class ContinuousBatchingScheduler:
                 else:
                     time.sleep(0.005)
         with self._lock:
-            for slot, req in list(group.active.items()):
-                req.error = req.error or RuntimeError(
-                    f"model {key!r} unloaded while request in flight")
-                self._retire_locked(group, slot, req)
+            self._retire_lanes_locked(
+                [(group, slot, req) for slot, req in group.active.items()],
+                RuntimeError(f"model {key!r} unloaded while request in "
+                             f"flight"))
             del self._groups[group.key]
+        # the group's last tokens and ends reach their listeners before
+        # the caller unloads the model under them (an allowance of its
+        # own: the lanes' drain may have used the first up)
+        self._flush_deliveries(timeout if drain else 0.0)
 
     def models(self) -> List[str]:
         with self._lock:
@@ -864,8 +911,11 @@ class ContinuousBatchingScheduler:
             admitted += 1
 
     def _retire_locked(self, group: _LaneGroup, slot: int,
-                       req: Request) -> None:
-        # no device work in here (submit() blocks on this lock): the
+                       req: Request, now: float) -> None:
+        # the lane, its pages and the books, which the next admission
+        # needs; what the request's listeners need (the sentinel,
+        # ``_done``, counters, the trace) rides the caller's hand-off.
+        # No device work in here (submit() blocks on this lock): the
         # lane's caches stay stale until the next admit_slot, which
         # re-zeroes them before use — lanes are row-independent, so a
         # stale lane decoding garbage contaminates nothing.  Page-aware
@@ -873,7 +923,7 @@ class ContinuousBatchingScheduler:
         # "retire frees pages immediately" is what lets the very next
         # admission round backfill under page pressure — and what makes
         # cancellation release a mid-prefill lane's pages at once.
-        req.finished = time.perf_counter()
+        req.finished = now
         del group.active[slot]
         if group.page_aware:
             detached = False
@@ -900,8 +950,129 @@ class ContinuousBatchingScheduler:
         group.src_len[slot] = 1
         group.free.append(slot)
         self._finished.append(req)
-        req._emit(None)
-        req._done.set()
+        if self.retire_check is not None:
+            try:
+                self.retire_check(group.model)
+            except BaseException as e:
+                req.error = req.error or e
+
+    def _retire_lanes_locked(self, lanes, error: BaseException) -> None:
+        """Retire ``(group, slot, req)`` lanes that got no token this
+        round (cancelled, failed, unloaded) with ``error``, and hand
+        their ends off in one record: behind every token they were
+        handed before, whichever thread calls."""
+        lanes = list(lanes)
+        if not lanes:
+            return
+        now = time.perf_counter()
+        ended = []
+        for group, slot, req in lanes:
+            req.error = req.error or error
+            self._retire_locked(group, slot, req, now)
+            ended.append((req, (), 0, None, slot))
+        self._hand_off_locked(None, now, ended)
+
+    def _reap_cancelled_locked(self) -> None:
+        """Retire cancelled in-flight requests BEFORE the next dispatch:
+        the lane (and, page-aware, its pages — including a lane still
+        mid-prefill) frees immediately rather than decoding to the cap."""
+        self._retire_lanes_locked(
+            [(group, slot, req) for group in self._groups.values()
+             for slot, req in group.active.items() if req.cancelled],
+            RequestCancelled("cancelled in flight"))
+
+    # -- delivery ------------------------------------------------------------
+    def _take_tokens_locked(self, group: _LaneGroup, slot: int,
+                            req: Request, seq, now: float):
+        """The step loop's part of a lane's tokens: append them, stamp
+        the request, cut at end-of-sequence or the cap and retire there.
+        Returns the lane's entry of the step's record, ``(req, tokens,
+        tokens the request had before, its last stamp before, slot if it
+        ended here else None)``."""
+        had, prev = len(req.tokens), req.last_token
+        end_id, cap = group.model.end_id, req.max_new_tokens
+        got = []
+        ended = None
+        for tok in seq:
+            tok = int(tok)
+            req.tokens.append(tok)
+            got.append(tok)
+            if tok == end_id or len(req.tokens) >= cap:
+                ended = slot
+                break
+        if got:
+            if req.first_token is None:
+                req.first_token = now
+            req.last_token = now
+        if ended is not None:
+            self._retire_locked(group, slot, req, now)
+        return req, got, had, prev, ended
+
+    def _hand_off_locked(self, step: Optional[int], stamp: float,
+                         entries) -> None:
+        """One record onto the delivery queue, as the LAST act under the
+        state lock (the ``put`` neither blocks nor runs Python)."""
+        if not entries:
+            return
+        d = self._delivery
+        d["handoffs"] += 1
+        d["tokens"] += sum(len(e[1]) for e in entries)
+        d["backlog_max"] = max(d["backlog_max"], self._outbox.qsize())
+        self._outbox.put((step, stamp, entries))
+
+    def _offer_interpreter(self) -> None:
+        """After a step's hand-off, off the lock: let the delivery thread
+        have the interpreter now.  The ``put`` woke it, but it cannot run
+        until this thread gives the interpreter up, which it otherwise
+        does only deep in the next launch (or when forced, after the
+        switch interval): a quiet system's tokens would reach their
+        streams a millisecond or two later than the step produced them.
+        An offer, not a wait: it does not depend on what the delivery
+        thread is doing."""
+        if self._deliverer is not None:
+            time.sleep(_OFFER_S)
+
+    def _deliver(self, record) -> None:
+        """Work one record off: every request's tokens to its callback,
+        an ended request's sentinel and THEN its ``_done`` (clients
+        before telemetry); then the step's telemetry in batch, stamped
+        with the step loop's clock, not this thread's."""
+        step, stamp, entries = record
+        tr = self._tracer
+        carried = {} if step is None else {"step": step}
+        with tr.span("scheduler/deliver_out", cat="serving",
+                     **carried) as out:
+            for req, toks, _had, _prev, ended in entries:
+                for tok in toks:
+                    req._emit(tok)
+                if ended is not None:
+                    req._emit(None)
+                    req._done.set()
+            n_tokens = n_ended = 0
+            for req, toks, had, prev, ended in entries:
+                if toks:
+                    n_tokens += len(toks)
+                    # a speculative lane's tokens of one step share its
+                    # stamp: the first carries the gap, the rest none
+                    if had == 0:
+                        self._h_ttft.observe(stamp - req.submitted)
+                    else:
+                        self._h_itl.observe(stamp - prev)
+                    for i in range(1, len(toks) + 1):
+                        if i > 1:
+                            self._h_itl.observe(0.0)
+                        tr.instant("request/token", cat="serving",
+                                   at=stamp, rid=req.rid, index=had + i)
+                if ended is not None:
+                    n_ended += 1
+                    self._note_retired(req, ended)
+            if n_tokens:
+                self._m_tokens.inc(n_tokens)
+            out.update(tokens=n_tokens, requests=len(entries),
+                       finished=n_ended)
+        self._delivered += 1
+
+    def _note_retired(self, req: Request, slot: int) -> None:
         ok = req.error is None
         event = ("finished" if ok else
                  "cancelled" if isinstance(req.error, RequestCancelled)
@@ -911,7 +1082,7 @@ class ContinuousBatchingScheduler:
             self._h_total.observe(req.finished - req.submitted)
             self._h_tokens_per_req.observe(len(req.tokens))
         self._tracer.instant("request/retired", cat="serving",
-                             rid=req.rid, slot=slot,
+                             at=req.finished, rid=req.rid, slot=slot,
                              tokens=len(req.tokens), ok=ok)
         # the whole-request span, stamped from the Request's own marks —
         # one bar per request in the Chrome-trace view, submit to retire
@@ -919,34 +1090,51 @@ class ContinuousBatchingScheduler:
                               cat="serving", rid=req.rid,
                               tokens=len(req.tokens), ok=ok)
 
-    def _reap_cancelled_locked(self) -> None:
-        """Retire cancelled in-flight requests BEFORE the next dispatch:
-        the lane (and, page-aware, its pages — including a lane still
-        mid-prefill) frees immediately rather than decoding to the cap."""
-        for group in self._groups.values():
-            for slot, req in list(group.active.items()):
-                if req.cancelled:
-                    req.error = req.error or RequestCancelled(
-                        "cancelled in flight")
-                    self._retire_locked(group, slot, req)
+    def _delivery_loop(self) -> None:
+        """The ``serving-delivery`` thread: records in order until its
+        stop marker (the thread object itself: an earlier thread's may
+        still lie in the queue), or the record's edge after a halt."""
+        stop = threading.current_thread()
+        while True:
+            record = self._outbox.get()
+            if isinstance(record, tuple):
+                self._deliver(record)
+                # a delivered record must not live on in this frame
+                # while the queue is quiet (its requests' callbacks pin
+                # their model)
+                record = None
+                if self._deliverer_halt:
+                    return
+            elif record is stop:
+                return
 
-    def _note_token(self, req: Request, tok: int) -> None:
-        """Per-token telemetry (called under the lock, right after the
-        token was appended): TTFT on the first token, inter-token gap on
-        the rest, and one ``request/token`` trace instant — token
-        instants per rid reconstruct the exact decode timeline (the
-        test asserts count == len(req.tokens))."""
-        now = time.perf_counter()
-        if req.first_token is None:
-            req.first_token = now
-            self._h_ttft.observe(now - req.submitted)
-        else:
-            self._h_itl.observe(now - req.last_token)
-        req.last_token = now
-        self._m_tokens.inc()
-        req._emit(tok)
-        self._tracer.instant("request/token", cat="serving", rid=req.rid,
-                             index=len(req.tokens))
+    def _deliver_inline(self) -> None:
+        """With no delivery thread, the caller that drove the loop (or
+        retired lanes) works the queue off before it returns: the same
+        ``_deliver``, a second caller."""
+        if self._deliverer is not None:
+            return
+        with self._deliver_lock:
+            while self._deliverer is None:
+                try:
+                    record = self._outbox.get_nowait()
+                except queue.Empty:
+                    return
+                if isinstance(record, tuple):   # else a stale stop marker
+                    self._deliver(record)
+                    self._delivery["inline"] += 1
+
+    def _flush_deliveries(self, timeout: float) -> None:
+        """Return once every record handed off so far is delivered (or,
+        beside a delivery thread, after ``timeout`` seconds)."""
+        self._deliver_inline()
+        if self._deliverer is None:
+            return
+        deadline = time.monotonic() + timeout
+        with self._lock:
+            target = self._delivery["handoffs"]
+        while self._delivered < target and time.monotonic() < deadline:
+            time.sleep(0.001)
 
     def _step_group(self, group: _LaneGroup, snap, step: int) -> None:
         """One lockstep dispatch over ``group``'s lanes + retirement.
@@ -958,12 +1146,13 @@ class ContinuousBatchingScheduler:
             # prefill and decode over every lane; only lanes that
             # actually emitted come back.  A speculative model (ISSUE
             # 15) returns a LIST of tokens per lane — the accepted
-            # draft prefix plus the target's own next token — delivered
-            # one by one so streaming, telemetry, end-of-sequence and
-            # the max_new cap see the exact per-token sequence a plain
-            # model would have produced (tokens past the end/cap in the
-            # same round are dropped, as a plain model would never have
-            # decoded them).
+            # draft prefix plus the target's own next token — taken one
+            # by one and handed off in the list's order, so streaming,
+            # telemetry, end-of-sequence and the max_new cap see the
+            # exact per-token sequence a plain model would have
+            # produced (tokens past the end/cap in the same round are
+            # dropped here, as a plain model would never have decoded
+            # them).
             try:
                 with self._tracer.span("scheduler/step", cat="serving",
                                        managed=True, model=group.key,
@@ -973,23 +1162,23 @@ class ContinuousBatchingScheduler:
                 self._fail_group(group, e)
                 return
             with self._tracer.span("scheduler/deliver", cat="serving",
-                                   step=step), self._lock:
-                self._steps += 1
-                self._m_steps.inc()
-                for slot, toks in emitted.items():
-                    req = group.active.get(slot)
-                    if req is None:
-                        continue
-                    seq = toks if isinstance(toks, (list, tuple,
-                                                    np.ndarray)) \
-                        else [toks]
-                    for tok in seq:
-                        req.tokens.append(int(tok))
-                        self._note_token(req, int(tok))
-                        if int(tok) == group.model.end_id or \
-                                len(req.tokens) >= req.max_new_tokens:
-                            self._retire_locked(group, slot, req)
-                            break
+                                   step=step):
+                with self._lock:
+                    now = time.perf_counter()
+                    self._steps += 1
+                    self._m_steps.inc()
+                    out = []
+                    for slot, toks in emitted.items():
+                        req = group.active.get(slot)
+                        if req is None:
+                            continue
+                        seq = toks if isinstance(toks, (list, tuple,
+                                                        np.ndarray)) \
+                            else (toks,)
+                        out.append(self._take_tokens_locked(
+                            group, slot, req, seq, now))
+                    self._hand_off_locked(step, now, out)
+                self._offer_interpreter()
             return
         tokens, pos, src_len = snap
         try:
@@ -1001,18 +1190,20 @@ class ContinuousBatchingScheduler:
             self._fail_group(group, e)
             return
         with self._tracer.span("scheduler/deliver", cat="serving",
-                               step=step), self._lock:
-            self._steps += 1
-            self._m_steps.inc()
-            for slot, req in list(group.active.items()):
-                tok = int(nxt[slot])
-                req.tokens.append(tok)
-                self._note_token(req, tok)
-                group.tokens[slot] = tok
-                group.pos[slot] += 1
-                if tok == group.model.end_id or \
-                        len(req.tokens) >= req.max_new_tokens:
-                    self._retire_locked(group, slot, req)
+                               step=step):
+            with self._lock:
+                now = time.perf_counter()
+                self._steps += 1
+                self._m_steps.inc()
+                out = []
+                for slot, req in list(group.active.items()):
+                    tok = int(nxt[slot])
+                    group.tokens[slot] = tok
+                    group.pos[slot] += 1
+                    out.append(self._take_tokens_locked(group, slot, req,
+                                                        (tok,), now))
+                self._hand_off_locked(step, now, out)
+            self._offer_interpreter()
 
     def step_once(self) -> bool:
         """Admit what fits, run ONE lockstep decode step per lane group
@@ -1022,8 +1213,15 @@ class ContinuousBatchingScheduler:
         The host's share of a round is spanned phase by phase, each span
         carrying ``step`` (the step count as the round began): ``admit``,
         ``plan`` (this method's locked part), ``step`` (the dispatch),
-        ``deliver`` (tokens out and retirement, locked) and
-        ``maintenance`` (the tier slice)."""
+        ``deliver`` (tokens taken, retirement and the hand-off to
+        delivery, locked) and ``maintenance`` (the tier slice).  With no
+        ``serve()`` thread the round's records are delivered before this
+        returns."""
+        busy = self._round()
+        self._deliver_inline()
+        return busy
+
+    def _round(self) -> bool:
         tr = self._tracer
         step = self._steps
         with tr.span("scheduler/admit", cat="serving", step=step):
@@ -1077,17 +1275,16 @@ class ContinuousBatchingScheduler:
         lane group with the error (their cache lanes are in an unknown
         state), free the slots, and keep the loop alive."""
         with self._lock:
-            for slot, req in list(group.active.items()):
-                req.error = exc
-                self._retire_locked(group, slot, req)
+            self._retire_lanes_locked(
+                [(group, slot, req) for slot, req in group.active.items()],
+                exc)
 
     def _fail_in_flight(self, exc: BaseException) -> None:
         """Fail every in-flight request across all lane groups."""
         with self._lock:
-            for group in self._groups.values():
-                for slot, req in list(group.active.items()):
-                    req.error = exc
-                    self._retire_locked(group, slot, req)
+            self._retire_lanes_locked(
+                [(group, slot, req) for group in self._groups.values()
+                 for slot, req in group.active.items()], exc)
 
     def run_until_idle(self, max_steps: Optional[int] = None) -> int:
         """Drive the loop inline until queue and slots drain; returns the
@@ -1101,10 +1298,20 @@ class ContinuousBatchingScheduler:
 
     # -- threaded serving ----------------------------------------------------
     def serve(self) -> "ContinuousBatchingScheduler":
-        """Start the admit/step loop on a daemon thread; returns self."""
+        """Start the admit/step loop and the delivery thread (daemons);
+        returns self."""
         if self._thread is not None:
             raise RuntimeError("serve() already running")
+        if self._deliverer is not None:
+            raise RuntimeError("serve(): the last delivery thread is "
+                               "still stuck in a callback")
         self._stop.clear()
+        with self._deliver_lock:    # an inline consumer finishes first
+            self._deliverer_halt = False
+            self._deliverer = threading.Thread(
+                target=self._delivery_loop, daemon=True,
+                name="serving-delivery")
+        self._deliverer.start()
 
         def loop():
             while not self._stop.is_set():
@@ -1164,7 +1371,18 @@ class ContinuousBatchingScheduler:
         if self._thread is not None:
             self._thread.join(timeout)
             self._thread = None
+        if self._deliverer is not None:
+            # drain: the marker goes behind every record handed off, so
+            # the join is the wait for them; else the thread stops at the
+            # edge of the record in its hands and the rest stay queued
+            # for the next consumer
+            self._deliverer_halt = not drain
+            self._outbox.put(self._deliverer)
+            self._deliverer.join(timeout)
+            if not self._deliverer.is_alive():
+                self._deliverer = None
         if drain:
+            self._deliver_inline()
             with self._lock:
                 while self._queue:
                     req = self._queue.popleft()
@@ -1204,6 +1422,11 @@ class ContinuousBatchingScheduler:
                 "queued": len(self._queue),
                 "in_flight": in_flight,
                 "peak_in_flight": self._peak_in_flight,
+                # the hand-off to delivery: records and tokens put,
+                # the most records waiting when one was put (0 or 1:
+                # delivery keeps pace with the loop), and the records
+                # delivered by the loop's driver for want of a thread
+                "delivery": dict(self._delivery),
             }
             groups = list(self._groups.values())
         out["failed"] = sum(1 for r in done if r.error is not None)

@@ -79,6 +79,7 @@ RANK_MASTER_SNAP = 20      # master.snapshot       parallel/master_service.py
 RANK_MASTER_QUEUE = 22     # master.queue          parallel/master.py
 RANK_FLEET_ROUTER = 24     # fleet.router          serving/fleet/router.py
 RANK_GATEWAY_WEDGE = 26    # gateway.wedge         serving/gateway/gateway.py
+RANK_DELIVERY = 28         # serving.delivery      serving/scheduler.py
 RANK_SCHEDULER = 30        # serving.scheduler     serving/scheduler.py
 RANK_SESSIONS = 34         # serving.sessions      serving/sessions.py
 RANK_ROUTER = 40           # gateway.router        serving/gateway/router.py
@@ -108,6 +109,7 @@ RANK_TABLE: Dict[str, int] = {
     "master.queue": RANK_MASTER_QUEUE,
     "fleet.router": RANK_FLEET_ROUTER,
     "gateway.wedge": RANK_GATEWAY_WEDGE,
+    "serving.delivery": RANK_DELIVERY,
     "serving.scheduler": RANK_SCHEDULER,
     "serving.sessions": RANK_SESSIONS,
     "gateway.router": RANK_ROUTER,

@@ -309,7 +309,9 @@ def test_real_stack_clean_under_checking(checking, tmp_path):
     # the canonical nestings the migration preserves
     assert ("serving.scheduler", "metrics.child") in edges
     assert ("serving.scheduler", "gateway.registry") in edges
-    assert ("serving.scheduler", "gateway.journal.cv") in edges
+    # completion (the journal's done record) is the delivery thread's
+    # since PR 36: it no longer nests in the scheduler's state lock
+    assert ("serving.scheduler", "gateway.journal.cv") not in edges
     out = tmp_path / "graph.json"
     checking.export_graph(str(out))
     assert json.loads(out.read_text())["edges"]
