@@ -69,9 +69,14 @@ class _OpenSpans(threading.local):
 class Tracer:
     """Bounded in-memory trace sink.  ``capacity`` bounds the ring (old
     events drop, counted in ``dropped``); ``enabled=False`` turns every
-    emit into a cheap no-op (the bench's "bare" leg)."""
+    emit into a cheap no-op (the bench's "bare" leg).
 
-    def __init__(self, capacity: int = 65536, enabled: bool = True):
+    The default holds half a minute of the busiest server there is: 128
+    lanes at a 24 ms step emit 6 000 events a second (an instant a
+    token), and a reader that wants a window whole refuses a ring that
+    has dropped anything.  Full, it is about 150 MB (580 B an event)."""
+
+    def __init__(self, capacity: int = 262144, enabled: bool = True):
         # innermost-but-one rank: emits happen under the scheduler and
         # router locks (the scheduler's spans and admission instants;
         # the per-token and retirement instants come from its delivery

@@ -29,6 +29,7 @@ from __future__ import annotations
 import contextlib
 import os as _os
 import queue as _queue
+import threading
 import time
 from typing import Dict, Iterator, List, Optional
 
@@ -37,7 +38,8 @@ import numpy as np
 from ...observability import metrics as _obs_metrics
 from ...observability.tracing import tracer as _obs_tracer
 from ...resilience.chaos import injector as _chaos_injector
-from ...utils.sync import RANK_GATEWAY_WEDGE, OrderedLock
+from ...utils.sync import (RANK_GATEWAY_STREAM, RANK_GATEWAY_STREAMS,
+                           RANK_GATEWAY_WEDGE, OrderedLock)
 from ..scheduler import (ContinuousBatchingScheduler, Request,
                          RequestCancelled, SchedulerShutdown)
 from .journal import RequestJournal
@@ -63,7 +65,20 @@ class TokenStream:
     the request's error (if it failed) after the last token; supports
     ``close()`` — also triggered by ``with`` exit and generator
     teardown — which CANCELS the request, freeing its lane and pages
-    immediately."""
+    immediately.
+
+    The attached form (the HTTP door): ``attach(sink)`` hands the stream
+    a sink, and from then on ``_push`` — the scheduler's delivery
+    thread, once a token, in order — writes each token to the sink
+    itself; the consumer sleeps in ``park()`` from the attach to the
+    response's end and no thread is woken per token.  A sink is
+    anything with ``write(tok, direct) -> bool`` that never blocks
+    (``tok`` None: the end): True once it has taken the whole item,
+    False if it could not (it keeps what is left over itself), an
+    exception if nobody can be written to any more.  On False the sink
+    is detached and the consumer wakes: this one stream is an iterator
+    again, every later token goes through the queue, and the others'
+    never waited.  On an exception the request is cancelled."""
 
     _DONE = object()
 
@@ -77,12 +92,84 @@ class TokenStream:
         self.timeout = float(timeout)
         self._q: "_queue.Queue" = _queue.Queue()
         self._handed = 0            # tokens handed to the consumer, to 2
+        # chooses between queue and sink: ``_push`` and ``attach`` both
+        # hold it, so a token queued before the attach is written by the
+        # attach's drain and one pushed after it by ``_push``, never
+        # both, never neither.  Nothing blocks under it.
+        self._lock = OrderedLock("gateway.stream", RANK_GATEWAY_STREAM)
+        self._sink = None
+        self._released = threading.Event()  # the parked consumer's
+        self._pushed = 0            # items through _push (park's clock)
+        self._ended = False         # the sentinel has been pushed
+        self.failed = False         # a sink's write raised: no listener
 
     # the scheduler-side callback (runs in the scheduler's delivery
-    # thread, which serves every stream: a lock-free enqueue is all that
-    # happens here)
+    # thread, which serves every stream: an enqueue, or with a sink one
+    # write that cannot block, is all that happens here)
     def _push(self, req: Request, tok: Optional[int]) -> None:
-        self._q.put(self._DONE if tok is None else int(tok))
+        with self._lock:
+            self._pushed += 1
+            if tok is None:
+                self._ended = True
+            if self._sink is not None:
+                self._write_locked(tok, direct=True)
+            elif not self.failed:
+                self._q.put(self._DONE if tok is None else int(tok))
+
+    def _write_locked(self, tok: Optional[int], direct: bool) -> None:
+        try:
+            whole = self._sink.write(tok, direct)
+        except Exception:
+            # the reader is gone (or the sink is broken: either way a
+            # token that cannot be written must not vanish quietly).
+            # The request stops burning its lane for an audience of zero
+            self.failed = True
+            self._detach_locked()
+            self.close()
+            return
+        if not whole or tok is None:
+            self._detach_locked()
+        if whole and tok is not None and not self._handed:
+            # the first token's chunk is out (the send has returned).
+            # Once per request, nothing per token.
+            self._handed = 2
+            _obs_tracer().instant("gateway/first_chunk", cat="gateway",
+                                  rid=self.request.rid)
+
+    def _detach_locked(self) -> None:
+        self._sink = None
+        self._released.set()
+
+    def attach(self, sink) -> None:
+        """Give the stream its sink.  What was queued before is written
+        here, in order, by the calling thread; the sink stays attached
+        unless one of those writes already could not be taken whole (the
+        rest then stays queued, behind it) or was the end."""
+        with self._lock:
+            self._sink = sink
+            while self._sink is not None:
+                try:
+                    item = self._q.get_nowait()
+                except _queue.Empty:
+                    break
+                self._write_locked(None if item is self._DONE else item,
+                                   direct=False)
+
+    def park(self) -> None:
+        """Sleep until the sink has been detached: it wrote the
+        response's end, could not take an item whole, or failed.  One
+        wait on one event; it is looked at again only every ``timeout``
+        seconds, and a stream that was pushed nothing in between gets
+        the iterator's ``TimeoutError`` (the sink is detached first)."""
+        seen = None
+        while not self._released.wait(self.timeout):
+            with self._lock:
+                if self._sink is not None and self._pushed == seen:
+                    self._detach_locked()
+                    raise TimeoutError(
+                        f"stream: no token for {self.timeout}s "
+                        f"(rid {self.request.rid})")
+                seen = self._pushed
 
     def __iter__(self) -> Iterator[int]:
         return self
@@ -118,7 +205,7 @@ class TokenStream:
     def close(self) -> None:
         """Cancel the request if it is still running (client went away:
         its lane and pages must not keep decoding for nobody)."""
-        if not self.request.done:
+        if not self._ended and not self.request.done:
             self.request.cancel()
 
     def __enter__(self) -> "TokenStream":
@@ -132,6 +219,29 @@ class TokenStream:
             self.close()
         except Exception:
             pass
+
+
+class _StreamCounts:
+    """What the HTTP door did with its streaming responses, for
+    ``Gateway.stats()["streams"]``.  ``opened - done_lines -
+    send_failed`` is the number of responses that are open, or ended
+    with neither a ``done`` line nor a failed send: 0 on a quiet
+    gateway."""
+
+    NAMES = ("opened", "attached", "chunks_direct", "chunks_by_handler",
+             "handed_back", "send_failed", "done_lines", "non_200")
+
+    def __init__(self):
+        self._lock = OrderedLock("gateway.streams", RANK_GATEWAY_STREAMS)
+        self._n = dict.fromkeys(self.NAMES, 0)
+
+    def add(self, name: str) -> None:
+        with self._lock:
+            self._n[name] += 1
+
+    def snapshot(self) -> Dict[str, int]:
+        with self._lock:
+            return dict(self._n)
 
 
 def _check_pages(inst) -> None:
@@ -191,6 +301,9 @@ class Gateway:
         # refuses with GatewayDraining while it is up, and /readyz
         # reports not-ready — the fleet router's rotation signal.
         self._draining = False
+        # the HTTP door's account of its streaming responses (the
+        # server's handlers and sinks count, ``stats()`` reports)
+        self.streams = _StreamCounts()
         reg = _obs_metrics.registry()
         self._m_requests = reg.counter(
             "paddle_gateway_requests_total",
@@ -787,6 +900,7 @@ class Gateway:
             "pid": _os.getpid(),
             "draining": self._draining,
             "drained": self.drained,
+            "streams": self.streams.snapshot(),
         }
         if self.journal is not None:
             out["journal"] = self.journal.stats()

@@ -15,9 +15,12 @@ JSON bodies, port 0 = pick-a-port.  Routes:
   Blocking by default (one JSON response with
   the full token list); ``"stream": true`` switches to chunked
   transfer, one JSON line per token as the decode step retires it, with
-  a final ``{"done": ...}`` line.  A client that disconnects mid-stream
-  cancels the request — its lane and pages free at the next step
-  boundary.
+  a final ``{"done": ...}`` line.  The scheduler's delivery thread
+  writes those chunks to the socket itself (``_ChunkSink``); the
+  request's handler thread sleeps from the response's headers to its
+  end, and writes only for a reader that stopped reading.  A client
+  that disconnects mid-stream cancels the request — its lane and pages
+  free at the next step boundary.
 * ``GET /v1/models`` — registry rollup (loaded versions, aliases, HBM
   budget); ``POST /v1/models`` with ``{"action": "load"|"swap"|
   "unload", "model", "version", ...}`` drives the lifecycle — the
@@ -38,16 +41,91 @@ naming the error, so a tenant can tell "slow down" from "gone"."""
 from __future__ import annotations
 
 import json
+import socket
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
 from ..paging import PoolCapacityError
-from ..scheduler import SchedulerShutdown
+from ..scheduler import RequestCancelled, SchedulerShutdown
 from .gateway import Gateway, GatewayDraining
 from .router import RateLimited
 
 __all__ = ["GatewayServer"]
+
+
+def _chunk(data: bytes) -> bytes:
+    return b"%x\r\n%b\r\n" % (len(data), data)
+
+
+class _ChunkSink:
+    """One streaming response on its connection: a chunk a token (one
+    JSON line), then the ``done`` line and the terminating chunk.
+
+    ``write`` is the ``TokenStream`` sink: ONE ``send`` an item, which
+    never blocks (``MSG_DONTWAIT``: the socket's mode stays what the
+    handler's reads need), called by whoever holds the stream's lock —
+    the delivery thread (``direct``), or the handler while it attaches.
+    What a send leaves over (a reader that stopped reading filled the
+    socket's buffer) is kept in ``unsent`` for the handler, whose
+    ``flush`` and ``write_blocking`` do block, on its own thread."""
+
+    def __init__(self, sock, stream, session: Optional[str], counts):
+        self.sock, self.stream, self.session = sock, stream, session
+        self.counts = counts
+        self.n = 0                  # tokens on the wire
+        self.ended = False          # so is the done line
+        self.unsent = b""           # left over by a send, and for
+        self._owed = None           # which item
+
+    def _bytes(self, tok: Optional[int], error=None) -> bytes:
+        if tok is not None:
+            return _chunk(b'{"token": %d}\n' % tok)
+        req = self.stream.request
+        if error is None and not isinstance(req.error, RequestCancelled):
+            error = req.error
+        if error is not None:
+            line = {"done": True, "tokens": self.n,
+                    "error": f"{type(error).__name__}: {error}"}
+        else:
+            line = {"done": True, "tokens": self.n, "rid": req.rid,
+                    "jid": req.jid,
+                    "version": (req.group or "@?").split("@", 1)[-1]}
+            if self.session is not None:
+                line["session"] = self.session
+                line["resumed"] = bool(req.resumed)
+        return _chunk(json.dumps(line).encode() + b"\n") + _chunk(b"")
+
+    def _sent(self, tok: Optional[int], by: str) -> None:
+        if tok is None:
+            self.ended = True
+            self.counts.add("done_lines")
+        else:
+            self.n += 1
+            self.counts.add(by)
+
+    def write(self, tok: Optional[int], direct: bool) -> bool:
+        data = self._bytes(tok)
+        try:
+            sent = self.sock.send(data, socket.MSG_DONTWAIT)
+        except BlockingIOError:
+            sent = 0
+        if sent < len(data):
+            self.unsent, self._owed = data[sent:], tok
+            self.counts.add("handed_back")
+            return False
+        self._sent(tok, "chunks_direct" if direct else "chunks_by_handler")
+        return True
+
+    def flush(self) -> None:
+        if self.unsent:
+            self.sock.sendall(self.unsent)
+            self.unsent = b""
+            self._sent(self._owed, "chunks_by_handler")
+
+    def write_blocking(self, tok: Optional[int], error=None) -> None:
+        self.unsent, self._owed = self._bytes(tok, error), tok
+        self.flush()
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -56,6 +134,10 @@ class _Handler(BaseHTTPRequestHandler):
 
     def log_message(self, *a):   # quiet
         pass
+
+    def send_response(self, code, message=None):
+        self._status = code
+        super().send_response(code, message)
 
     # -- plumbing ------------------------------------------------------------
     def _send_json(self, obj, code: int = 200) -> None:
@@ -71,9 +153,6 @@ class _Handler(BaseHTTPRequestHandler):
         if n <= 0:
             return {}
         return json.loads(self.rfile.read(n).decode() or "{}")
-
-    def _chunk(self, data: bytes) -> None:
-        self.wfile.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
 
     # -- routes --------------------------------------------------------------
     def do_GET(self):
@@ -108,6 +187,14 @@ class _Handler(BaseHTTPRequestHandler):
 
     def do_POST(self):
         path = self.path.split("?", 1)[0].rstrip("/")
+        self._status = None
+        try:
+            return self._post(path)
+        finally:
+            if path == "/v1/generate" and self._status != 200:
+                self.server_ref.gateway.streams.add("non_200")
+
+    def _post(self, path: str):
         try:
             body = self._read_json()
         except Exception as e:
@@ -188,46 +275,58 @@ class _Handler(BaseHTTPRequestHandler):
                               tag=tag, session=session)
             return self._send_json(out)
         # chunked streaming: one JSON line per token, then a done line.
-        # BrokenPipe (client went away) cancels the request so the lane
-        # and its pages stop burning on an audience of zero.
+        # A send that fails (client went away) cancels the request so
+        # the lane and its pages stop burning on an audience of zero.
         stream = gw.submit_stream(model, prompt, tenant=tenant,
                                   max_new=max_new,
                                   timeout=self.server_ref.request_timeout,
                                   draft_model=draft_model,
                                   constraint=constraint,
                                   speculate=speculate, session=session)
-        self.send_response(200)
-        self.send_header("Content-Type", "application/jsonl")
-        self.send_header("Transfer-Encoding", "chunked")
-        self.end_headers()
-        n = 0
+        counts = gw.streams
+        counts.add("opened")
+        sink = _ChunkSink(self.connection, stream, session, counts)
         try:
-            for tok in stream:
-                self._chunk(json.dumps({"token": int(tok)}).encode()
-                            + b"\n")
-                self.wfile.flush()
-                n += 1
-            req = stream.request
-            done_line = {"done": True, "tokens": n, "rid": req.rid,
-                         "jid": req.jid,
-                         "version": (req.group or "@?").split("@", 1)[-1]}
-            if session is not None:
-                done_line["session"] = session
-                done_line["resumed"] = bool(req.resumed)
-            self._chunk(json.dumps(done_line).encode() + b"\n")
-            self._chunk(b"")
+            self.send_response(200)
+            self.send_header("Content-Type", "application/jsonl")
+            self.send_header("Transfer-Encoding", "chunked")
+            self.end_headers()
+            # wfile is unbuffered: nothing of the headers is left behind.
+            # From here to the response's end the delivery thread writes
+            # to the socket and this thread sleeps; it touches the socket
+            # again (the next request's read) only after the sink wrote
+            # the terminating chunk and let it go
+            counts.add("attached")
+            stream.attach(sink)
+            stream.park()
+            if stream.failed:
+                return self._drop(stream)
+            # still here for a reader that stopped reading (or an end
+            # that came before the attach did): what the sink could not
+            # send, and every later token, from this thread, blocking
+            sink.flush()
+            if not sink.ended:
+                for tok in stream:
+                    sink.write_blocking(tok)
+                sink.write_blocking(None)
         except (BrokenPipeError, ConnectionResetError):
-            stream.close()
+            self._drop(stream)
         except BaseException as e:
             stream.close()
+            if sink.unsent:             # mid-chunk: no line can follow
+                return self._drop(stream)
             try:
-                self._chunk(json.dumps(
-                    {"done": True, "tokens": n,
-                     "error": f"{type(e).__name__}: {e}"}).encode()
-                    + b"\n")
-                self._chunk(b"")
+                if not sink.ended:
+                    sink.write_blocking(None, error=e)
             except OSError:
-                pass
+                self._drop(stream)
+
+    def _drop(self, stream) -> None:
+        """A streaming response nobody reads any more: cancel what is
+        left of the request and let the connection go."""
+        stream.close()
+        self.server_ref.gateway.streams.add("send_failed")
+        self.close_connection = True
 
     def _models(self, body: dict):
         gw = self.server_ref.gateway
@@ -299,6 +398,14 @@ class _Handler(BaseHTTPRequestHandler):
                          "(drain/compact_journal)")
 
 
+class _HTTPServer(ThreadingHTTPServer):
+    # the stdlib listens with a backlog of 5: a load generator's 192
+    # keep-alive clients connecting in one instant had 5 to 79 of their
+    # connections reset before a handler ever saw them.  The kernel
+    # cuts this to its own limit (somaxconn)
+    request_queue_size = 1024
+
+
 class GatewayServer:
     """Serve a ``Gateway`` over HTTP on a background thread."""
 
@@ -307,7 +414,7 @@ class GatewayServer:
         self.gateway = gateway
         self.request_timeout = float(request_timeout)
         handler = type("BoundHandler", (_Handler,), {"server_ref": self})
-        self._httpd = ThreadingHTTPServer((host, port), handler)
+        self._httpd = _HTTPServer((host, port), handler)
         self._thread: Optional[threading.Thread] = None
         self._closed = False
 

@@ -82,6 +82,8 @@ RANK_GATEWAY_WEDGE = 26    # gateway.wedge         serving/gateway/gateway.py
 RANK_DELIVERY = 28         # serving.delivery      serving/scheduler.py
 RANK_SCHEDULER = 30        # serving.scheduler     serving/scheduler.py
 RANK_SESSIONS = 34         # serving.sessions      serving/sessions.py
+RANK_GATEWAY_STREAM = 36   # gateway.stream        serving/gateway/gateway.py
+RANK_GATEWAY_STREAMS = 38  # gateway.streams       serving/gateway/gateway.py
 RANK_ROUTER = 40           # gateway.router        serving/gateway/router.py
 RANK_CANARY = 42           # lifecycle.canary      lifecycle/canary.py
 RANK_MODEL_REGISTRY = 44   # gateway.registry      serving/gateway/registry.py
@@ -112,6 +114,8 @@ RANK_TABLE: Dict[str, int] = {
     "serving.delivery": RANK_DELIVERY,
     "serving.scheduler": RANK_SCHEDULER,
     "serving.sessions": RANK_SESSIONS,
+    "gateway.stream": RANK_GATEWAY_STREAM,
+    "gateway.streams": RANK_GATEWAY_STREAMS,
     "gateway.router": RANK_ROUTER,
     "lifecycle.canary": RANK_CANARY,
     "gateway.registry": RANK_MODEL_REGISTRY,

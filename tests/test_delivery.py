@@ -410,23 +410,70 @@ def test_a_delivered_record_pins_no_listener():
         sched.shutdown(drain=True)
 
 
-# -- the soak: 640 stream ends over keep-alive connections ---------------------
+# -- the soak: every stream end over keep-alive connections, whole ------------
 
-@limited(20)
-def test_soak_every_stream_over_keep_alive_ends_whole():
-    gw = Gateway(n_slots=16, max_new_tokens=16)
-    gw.load_model("m", "1", instance=LaneModel(), warm=False)
-    srv = GatewayServer(gw, request_timeout=15.0)
+class GatedLanes(LaneModel):
+    """Emits for a lane only once its request's stream has its sink, so
+    every stream is attached before its first token."""
+
+    def __init__(self, attached):
+        super().__init__()
+        self.attached, self.rids = attached, {}
+
+    def tag_slot(self, slot, rid):
+        self.rids[slot] = rid
+
+    def clear_slot(self, slot):
+        super().clear_slot(slot)
+        self.rids.pop(slot, None)
+
+    def lane_step(self):
+        out = {}
+        for slot in sorted(self.lanes):
+            if self.rids.get(slot) in self.attached:
+                out[slot] = self.lanes[slot]
+                self.lanes[slot] += 1
+        if not out:
+            time.sleep(0.0005)
+        return out
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["free", "gated"])
+@pytest.mark.parametrize("clients,each,lanes,most", [
+    (32, 20, 16, 16),           # 640 ends; what found PR 35's short streams
+    (192, 4, 128, 24),          # the cell's shape: 192 connections, 128 lanes
+], ids=["32x20", "192x4"])
+@limited(60)
+def test_soak_every_stream_over_keep_alive_ends_whole(
+        monkeypatch, clients, each, lanes, most, gated):
+    """Through the real ``GatewayServer``: every response is read to its
+    terminating chunk, is the scheduler's own ``req.tokens`` token for
+    token, says ``tokens == max_new`` in its ``done`` line, and the next
+    request goes out on the same connection at once.  A tree that lets
+    the handler go before the response's last byte, or sets ``_done``
+    before the sentinel, fails here (PERF.md section 6, PR 39)."""
+    attached = set()
+    if gated:
+        real_attach = TokenStream.attach
+
+        def attach(self, sink):
+            real_attach(self, sink)
+            attached.add(self.request.rid)
+
+        monkeypatch.setattr(TokenStream, "attach", attach)
+    gw = Gateway(n_slots=lanes, max_new_tokens=most)
+    gw.load_model("m", "1", warm=False,
+                  instance=GatedLanes(attached) if gated else LaneModel())
+    srv = GatewayServer(gw, request_timeout=30.0)
     host, port = srv.start().split(":")
-    clients, each = 32, 20
     faults, ends = [], []
 
     def client(k):
         rng = np.random.RandomState(k)
-        conn = http.client.HTTPConnection(host, int(port), timeout=15)
+        conn = http.client.HTTPConnection(host, int(port), timeout=30)
         try:
             for i in range(each):
-                want = int(rng.randint(4, 17))
+                want = int(rng.randint(4, most + 1))
                 body = json.dumps({"model": "m", "prompt": [2 + k],
                                    "max_new": want, "stream": True})
                 conn.request("POST", "/v1/generate", body,
@@ -441,7 +488,7 @@ def test_soak_every_stream_over_keep_alive_ends_whole():
                         or "error" in done or done["tokens"] != want \
                         or toks != list(range(first, first + want)):
                     faults.append((k, i, want, resp.status, lines[-3:]))
-                ends.append(want)
+                ends.append((done.get("rid"), toks))
         except Exception as e:                  # a fault, not a crash
             faults.append((k, repr(e)))
         finally:
@@ -453,13 +500,22 @@ def test_soak_every_stream_over_keep_alive_ends_whole():
         for t in threads:
             t.start()
         for t in threads:
-            t.join(18)
+            t.join(50)
         assert not any(t.is_alive() for t in threads)
         assert not faults, faults[:5]
         assert len(ends) == clients * each
+        own = {r.rid: r.tokens for r in gw.sched.finished_requests()}
+        assert all(own[rid] == toks for rid, toks in ends)
+        total = sum(len(toks) for _, toks in ends)
         stats = gw.sched.stats()
         assert stats["failed"] == 0 and stats["finished"] == len(ends)
-        assert stats["delivery"]["tokens"] == sum(ends)
+        assert stats["delivery"]["tokens"] == total
         assert stats["delivery"]["inline"] == 0
+        c = gw.stats()["streams"]
+        assert c["opened"] == c["attached"] == c["done_lines"] == len(ends)
+        assert c["handed_back"] == c["send_failed"] == c["non_200"] == 0
+        assert c["chunks_direct"] + c["chunks_by_handler"] == total
+        if gated:       # attached before its first token: all by the writer
+            assert c["chunks_by_handler"] == 0
     finally:
         srv.stop(drain=True)
