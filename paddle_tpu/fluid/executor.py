@@ -962,8 +962,8 @@ class Executor:
         from ..utils.flags import FLAGS
 
         tr = _obs_tracer()
-        # four spans a step: prepare, (compile on a miss,) the launch
-        # (executor_step/<mode>, below) and writeback
+        # four spans a step, five on a miss: prepare, (compile,) the
+        # launch (executor_step/<mode>, below), writeback and release
         with tr.span("executor/prepare", cat="executor", mode=mode):
             policy = None
             if guard is not None:
@@ -1144,8 +1144,15 @@ class Executor:
                 self._run_host_op(op, scope)
 
             if return_numpy:
-                return [_to_numpy(f) for f in fetches]
-            return list(fetches)
+                out = [_to_numpy(f) for f in fetches]
+            else:
+                out = list(fetches)
+        with tr.span("executor/release", cat="executor", mode=mode):
+            # what this frame alone still holds dies here, under a name,
+            # and not unseen as the frame unwinds: the state that went
+            # in, and the signature key over every variable of it
+            del state_vals, new_state, feed, fetches, key
+        return out
 
     # -- pipelined dispatch --------------------------------------------------
     def run_pipeline(self, program: Optional[Program] = None,

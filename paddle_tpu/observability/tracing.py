@@ -10,13 +10,27 @@ its tokens come out?  That timeline is what TTFT and inter-token
 latency are made of, and no whole-step table can reconstruct it.
 
 ``Tracer`` keeps a bounded ring of event dicts (append under one lock —
-O(1); measured on the host of a TPU v5e, PERF.md section 6: 4.4-5.0 us a
-span with both sinks on, 1.9 us an instant, 1.5 us a disabled span, which
-is 0.03 % of a serving step of 12 spans and 0.006 % of a training step
-of 4):
+O(1); measured on the host of a TPU v5e, PERF.md section 6, PR 40: in a
+loop 4.5 us a span with both sinks on, 1.9 us an instant, 1.6 us a
+disabled span, and 6.2-6.7 us for each read of the thread's clock, which
+on that sandboxed kernel is a system call (0.3-0.4 us on a plain Linux
+host).  End to end, in the busiest serving cell, a step of 15 ms and 14
+spans: the spans it gained cost 0.7 % of the step, the thread's clock 1.7
+%, read 6 times a step or 28; a training step of 264 ms has 4 spans):
 
 * ``span(name, **args)`` — context manager emitting a Chrome "X"
-  (complete) event with microsecond ``ts``/``dur``.  The same ``with``
+  (complete) event with microsecond ``ts``/``dur`` and ``tdur``, the
+  thread's CPU time inside the span (``time.thread_time()``; Chrome's
+  own name for an X event's thread-clock duration), so ``dur - tdur`` is
+  how long the thread stood off the CPU: waiting for the interpreter,
+  asleep, or blocked in a transfer.  Exact where the kernel counts a
+  thread's time as it runs.  Where it counts in ticks (that sandboxed
+  kernel: 10 ms) one span's ``tdur`` is a sample, 0 or a whole tick and
+  so over ``dur`` as often as under, and only sums over many spans read
+  true; there the clock is not read again within a twentieth of its
+  tick (``_thread_clock_tick``, found once by reading until it moves):
+  at most one time in twenty a tick is charged to the span after the
+  one it fell in.  The same ``with``
   enters a ``jax.profiler.TraceAnnotation(name)``, so the span also
   lands on the host plane of whatever profiler session is open, on the
   profiler's clock, beside the device's own lines (inert without a
@@ -25,7 +39,8 @@ of 4):
   submitted / admitted / token / retired), now or ``at`` a recorded mark;
 * ``complete(name, start, end, **args)`` — an X event from timestamps
   recorded elsewhere (the scheduler builds the whole-request span from
-  the Request's own submitted/finished marks).
+  the Request's own submitted/finished marks; no ``tdur``: it has no
+  thread of its own).
 
 Ids are *seeded*: a process-local monotonic counter (a span takes its
 id when it opens), so two runs that do the same work emit the same id
@@ -41,6 +56,7 @@ load directly.
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 import json
 import os
@@ -59,11 +75,28 @@ __all__ = ["Tracer", "tracer", "span", "instant"]
 _CARRIED = ("rid", "step")
 
 
+@functools.cache
+def _thread_clock_tick() -> float:
+    """Seconds between two values of ``time.thread_time()`` on this host,
+    found once by reading until it moves: the cost of a read where the
+    kernel counts a thread's time as it runs (under a microsecond), the
+    tick where it counts in ticks (a sandboxed kernel: 10 ms)."""
+    c0 = time.thread_time()
+    while True:
+        c = time.thread_time()
+        if c != c0:
+            return c - c0
+
+
 class _OpenSpans(threading.local):
-    """Per thread: the open spans, innermost last, as (id, carried args)."""
+    """Per thread: the open spans, innermost last, as (id, carried args);
+    and the thread's CPU clock as last read, with the wall-clock mark of
+    that read."""
 
     def __init__(self):
         self.stack: List[tuple] = []
+        self.cpu = 0.0
+        self.cpu_read_at = float("-inf")
 
 
 class Tracer:
@@ -88,6 +121,10 @@ class Tracer:
         self.dropped = 0
         self._pid = os.getpid()
         self._open = _OpenSpans()
+        # a clock is not read again within a twentieth of its own tick:
+        # never, where it runs fine; where it ticks in 10 ms and a read
+        # is a system call of 6 us, a serve step's 28 reads become 5-7
+        self._cpu_reread_s = _thread_clock_tick() / 20.0
 
     # -- emit ----------------------------------------------------------------
     def _emit(self, ev: Dict[str, object]) -> None:
@@ -111,6 +148,14 @@ class Tracer:
         if args:
             ev["args"] = args
         return ev
+
+    def _thread_cpu(self, now: float) -> float:
+        """This thread's CPU clock at ``now``, a perf_counter mark just
+        taken: as last read, if that was within ``_cpu_reread_s``."""
+        mine = self._open
+        if now - mine.cpu_read_at >= self._cpu_reread_s:
+            mine.cpu, mine.cpu_read_at = time.thread_time(), now
+        return mine.cpu
 
     def instant(self, name: str, cat: str = "",
                 at: Optional[float] = None, **args) -> None:
@@ -148,12 +193,17 @@ class Tracer:
         stack.append((ev["id"], {k: args[k] for k in _CARRIED
                                  if k in args}))
         extra: Dict[str, object] = {}
+        # the thread's clock is read inside the wall clock's two reads,
+        # so what it counts lies within ``dur``
+        c0 = self._thread_cpu(t0)
         try:
             with TraceAnnotation(name):
                 yield extra
         finally:
+            cpu = self._thread_cpu(time.perf_counter()) - c0
             stack.pop()
             ev["dur"] = (time.perf_counter() - t0) * 1e6
+            ev["tdur"] = cpu * 1e6
             if extra:
                 ev["args"] = {**args, **extra}
             self._emit(ev)

@@ -1210,13 +1210,14 @@ class ContinuousBatchingScheduler:
         with active lanes, retire finished lanes.  Returns False when
         there was nothing to do.
 
-        The host's share of a round is spanned phase by phase, each span
-        carrying ``step`` (the step count as the round began): ``admit``,
-        ``plan`` (this method's locked part), ``step`` (the dispatch),
-        ``deliver`` (tokens taken, retirement and the hand-off to
-        delivery, locked) and ``maintenance`` (the tier slice).  With no
-        ``serve()`` thread the round's records are delivered before this
-        returns."""
+        The host's share of a round is spanned phase by phase under one
+        ``scheduler/round``, each span carrying ``step`` (the step count
+        as the round began): ``admit``, ``plan`` (this method's locked
+        part), ``step`` (the dispatch), ``deliver`` (tokens taken,
+        retirement and the hand-off to delivery, locked) and
+        ``maintenance`` (the tier slice).  With no ``serve()`` thread the
+        round's records are delivered before this returns, outside the
+        round's span."""
         busy = self._round()
         self._deliver_inline()
         return busy
@@ -1224,51 +1225,56 @@ class ContinuousBatchingScheduler:
     def _round(self) -> bool:
         tr = self._tracer
         step = self._steps
-        with tr.span("scheduler/admit", cat="serving", step=step):
-            self._admit_pending()
-        with tr.span("scheduler/plan", cat="serving", step=step), \
-                self._lock:
-            self._reap_cancelled_locked()
-            work = []
-            maint = []
-            for group in self._groups.values():
-                if group.managed and callable(
-                        getattr(group.model, "tier_maintenance", None)):
-                    # snapshot the next queued prompt bound for this
-                    # group so the maintenance slice (outside the lock)
-                    # can prefetch its demoted prefix chunks back to HBM
-                    # during the admission gap
-                    pre = None
-                    for req in self._queue:
-                        if not req.cancelled and self._group_for(
-                                req.route_to or req.model) is group:
-                            pre = req.src
-                            break
-                    maint.append((group, pre))
-                if not group.active:
-                    continue
-                snap = None if group.managed else (
-                    group.tokens.copy(), group.pos.copy(),
-                    group.src_len.copy())
-                work.append((group, snap))
-            if not work and not maint:
-                return False
-        busy = bool(work)
-        for group, snap in work:
-            self._step_group(group, snap, step)
-        # the off-lock tier slice, AFTER stepping: pending suspends
-        # spill to host/disk, queued-prompt chunks prefetch back, free
-        # pages top up to the demote watermark.  Counted as progress so
-        # the loop (and drain) keeps running until suspends complete.
-        if maint:
-            with tr.span("scheduler/maintenance", cat="serving", step=step):
-                for group, pre in maint:
-                    try:
-                        if group.model.tier_maintenance(prefetch=pre):
-                            busy = True
-                    except BaseException:   # pragma: no cover - belt and
-                        pass                # braces; never kill the loop
-        return busy
+        # one span over the round: every span this thread opens in it
+        # reaches this one by ``parent``, so the round's time is its
+        # leaves' plus the self times above them, with nothing outside
+        with tr.span("scheduler/round", cat="serving", step=step):
+            with tr.span("scheduler/admit", cat="serving", step=step):
+                self._admit_pending()
+            with tr.span("scheduler/plan", cat="serving", step=step), \
+                    self._lock:
+                self._reap_cancelled_locked()
+                work = []
+                maint = []
+                for group in self._groups.values():
+                    if group.managed and callable(
+                            getattr(group.model, "tier_maintenance", None)):
+                        # snapshot the next queued prompt bound for
+                        # this group so the maintenance slice (outside the
+                        # lock) can prefetch its demoted prefix chunks back
+                        # to HBM during the admission gap
+                        pre = None
+                        for req in self._queue:
+                            if not req.cancelled and self._group_for(
+                                    req.route_to or req.model) is group:
+                                pre = req.src
+                                break
+                        maint.append((group, pre))
+                    if not group.active:
+                        continue
+                    snap = None if group.managed else (
+                        group.tokens.copy(), group.pos.copy(),
+                        group.src_len.copy())
+                    work.append((group, snap))
+                if not work and not maint:
+                    return False
+            busy = bool(work)
+            for group, snap in work:
+                self._step_group(group, snap, step)
+            # the off-lock tier slice, AFTER stepping: pending suspends
+            # spill to host/disk, queued-prompt chunks prefetch back, free
+            # pages top up to the demote watermark.  Counted as progress so
+            # the loop (and drain) keeps running until suspends complete.
+            if maint:
+                with tr.span("scheduler/maintenance", cat="serving",
+                             step=step):
+                    for group, pre in maint:
+                        try:
+                            if group.model.tier_maintenance(prefetch=pre):
+                                busy = True
+                        except BaseException:   # pragma: no cover - belt and
+                            pass                # braces; never kill the loop
+            return busy
 
     def _fail_group(self, group: _LaneGroup, exc: BaseException) -> None:
         """A step dispatch failed: fail every in-flight request of that

@@ -715,11 +715,12 @@ def test_serve_step_spans_share_step_and_only_nest(tiny_paged):
     assert len(stepped) >= 4                    # 2 prefill chunks + decode
     for step, evs in stepped.items():
         names = [e["name"] for e in evs]
-        for want in ("scheduler/admit", "scheduler/plan", "scheduler/step",
-                     "scheduler/deliver", "scheduler/maintenance",
-                     "engine/feed_build", "engine/dispatch", "engine/fetch",
+        for want in ("scheduler/round", "scheduler/admit", "scheduler/plan",
+                     "scheduler/step", "scheduler/deliver",
+                     "scheduler/maintenance", "engine/feed_build", "engine/dispatch", "engine/fetch",
                      "engine/absorb", "executor/prepare",
-                     "executor_step/infer", "executor/writeback"):
+                     "executor_step/infer", "executor/writeback",
+                     "executor/release"):
             assert names.count(want) == 1, (step, want, names)
         assert "executor/compile" not in names or step == min(stepped)
         ids = {e["id"]: e for e in evs}
@@ -738,6 +739,9 @@ def test_serve_step_spans_share_step_and_only_nest(tiny_paged):
                     assert up is e, (e["name"], o["name"])
         under = {e["name"]: ids[e["parent"]]["name"] for e in evs
                  if e.get("parent") in ids}
+        assert "scheduler/round" not in under      # the step's top span
+        for phase in ("admit", "plan", "step", "deliver", "maintenance"):
+            assert under["scheduler/" + phase] == "scheduler/round"
         assert under["engine/feed_build"] == "scheduler/step"
         assert under["executor/prepare"] == "engine/dispatch"
         assert under["executor_step/infer"] == "engine/dispatch"
@@ -813,3 +817,243 @@ def test_ring_and_harness_clocks_are_one():
         c = time.monotonic()
         gaps.append(abs(b - 0.5 * (a + c)))
     assert min(gaps) < 1e-3
+
+
+# -- the books of a serve round: thread time on every span, one span over
+# -- the round (ISSUE 40) ------------------------------------------------------
+
+def _spin_cpu(seconds):
+    """Burn ``seconds`` of THIS thread's CPU time, however long that takes
+    on a busy host."""
+    import time
+
+    until = time.thread_time() + seconds
+    while time.thread_time() < until:
+        pass
+
+
+def test_a_sleeping_span_reads_little_thread_time_a_spinning_one_its_own():
+    import time
+
+    tr = Tracer()
+    with tr.span("asleep"):
+        time.sleep(0.05)
+    with tr.span("spinning"):
+        _spin_cpu(0.03)
+    with tr.span("empty"):
+        pass
+    tr.instant("mark")
+    tr.complete("elsewhere", 1.0, 2.0)
+    by = {e["name"]: e for e in tr.events()}
+    assert by["asleep"]["dur"] >= 50e3
+    assert by["asleep"]["tdur"] < 0.2 * by["asleep"]["dur"]
+    assert by["spinning"]["tdur"] >= 29e3           # microseconds, as dur
+    # the thread's clock is read inside the wall clock's two reads: never
+    # over dur by more than the two clocks' own skew (a host whose kernel
+    # counts thread time in ticks is another matter: PERF.md section 6)
+    for name in ("asleep", "spinning", "empty"):
+        assert 0.0 <= by[name]["tdur"] <= by[name]["dur"] + 1.0, name
+    # an instant has no duration and a complete() no thread of its own
+    assert "tdur" not in by["mark"] and "tdur" not in by["elsewhere"]
+    # Chrome's trace format, as exported: tdur beside dur on the X event
+    exported = {e["name"]: e for e in tr.chrome_trace()["traceEvents"]}
+    assert exported["spinning"]["tdur"] == by["spinning"]["tdur"]
+
+
+def test_a_disabled_tracer_emits_nothing_and_reads_no_clock(monkeypatch):
+    import time
+
+    tr = Tracer(enabled=False)
+    monkeypatch.setattr(time, "thread_time", None)  # a call would raise
+    with tr.span("off") as filled:
+        filled["n"] = 1
+    tr.instant("off")
+    tr.complete("off", 1.0, 2.0)
+    assert tr.events() == [] and tr.dropped == 0
+
+
+def test_a_clock_that_ticks_is_not_read_faster_than_it_ticks(monkeypatch):
+    """The chip's host: a sandboxed kernel counts a thread's time in ticks
+    of 10 ms and a read is a system call.  The tracer finds the tick once
+    and does not read the clock again within a twentieth of it; the spans
+    still sum to the ticks the thread was charged."""
+    import time
+
+    from paddle_tpu.observability import tracing
+
+    clock = {"wall": 100.0, "reads": 0}
+
+    def ticking():                      # 70 % on the CPU, in whole ticks
+        clock["reads"] += 1
+        clock["wall"] += 6e-6           # a read is a system call
+        return int(0.7 * clock["wall"] / 0.01) * 0.01
+
+    def wall():
+        clock["wall"] += 1e-6           # a microsecond a look
+        return clock["wall"]
+
+    tracing._thread_clock_tick.cache_clear()
+    monkeypatch.setattr(time, "thread_time", ticking)
+    monkeypatch.setattr(time, "perf_counter", wall)
+    try:
+        assert tracing._thread_clock_tick() == pytest.approx(0.01)
+        tr = Tracer()
+        before = clock["reads"]
+        with tr.span("round"):
+            for _ in range(20):         # a step of 10 us spans: one read
+                with tr.span("leaf"):
+                    clock["wall"] += 10e-6
+        assert clock["reads"] - before == 1
+        with tr.span("long"):           # past the twentieth: read again
+            clock["wall"] += 0.1
+        assert clock["reads"] - before == 2
+        (long,) = tr.events("long")
+        assert long["tdur"] == pytest.approx(70e3, abs=10e3)
+        assert all(e["tdur"] == 0.0 for e in tr.events("leaf"))
+    finally:
+        monkeypatch.undo()
+        tracing._thread_clock_tick.cache_clear()
+    # this host's own clock runs fine: every boundary reads it
+    assert Tracer()._cpu_reread_s < 1e-6
+
+
+def test_off_cpu_time_shows_beside_a_busy_python_thread():
+    """``dur - tdur`` tells waiting from working: the same pure-Python
+    span waits for the interpreter beside a thread that wants it too, and
+    does not alone.  Shares of the span, so a starved host moves both."""
+    def off_cpu_share(busy):
+        tr = Tracer()
+        stop = threading.Event()
+
+        def rival():
+            while not stop.is_set():
+                sum(range(200))
+
+        other = threading.Thread(target=rival, daemon=True)
+        if busy:
+            other.start()
+        try:
+            with tr.span("work"):
+                _spin_cpu(0.08)
+        finally:
+            stop.set()
+            if busy:
+                other.join()
+        (ev,) = tr.events("work")
+        return (ev["dur"] - ev["tdur"]) / ev["dur"]
+
+    for _attempt in range(3):           # the host's own scheduler is noise
+        alone, beside = off_cpu_share(False), off_cpu_share(True)
+        if beside > 0.25 and beside > alone + 0.15:
+            break
+    assert beside > 0.25 and beside > alone + 0.15, (alone, beside)
+
+
+class FakeLanes:
+    """Self-managed fake: every admitted lane emits token 5 a step."""
+
+    start_id, end_id = 0, 1
+
+    def __init__(self):
+        self.live = set()
+
+    def open_slots(self, n):
+        self.n = n
+
+    def admit_slot(self, slot, prompt, **_):
+        self.live.add(slot)
+        return len(prompt)
+
+    def clear_slot(self, slot):
+        self.live.discard(slot)
+
+    def lane_step(self):
+        with tracer().span("engine/fake", cat="serving"):
+            return {slot: 5 for slot in sorted(self.live)}
+
+
+@pytest.fixture(scope="module")
+def tiny_paged_lm():
+    from paddle_tpu.serving import PagedLMGenerator
+    from perfbench import weights
+    from perfbench.families import mimo_v2_flash as fam
+
+    with open("perfbench/configs/mimo-v2-flash-ep32.json",
+              encoding="utf-8") as f:
+        cfg = {**json.load(f), **fam.REHEARSAL["serve"]["cfg"]}
+    conf = fam.serving(cfg)["manifest"]["config"]
+    gen = PagedLMGenerator(**conf)
+    gen.load_weights(weights.make(
+        fam.param_shapes(cfg, cfg["param_prefix"]), 40, kind_of=fam.leaf_kind))
+    return gen, conf["lanes"]
+
+
+def _rounds_of(kind, request):
+    """Drive a few requests through one scheduler; -> the ring's events."""
+    tr = tracer()
+    tr.clear()
+    if kind in ("step_slots", "lane_step"):
+        # the fakes under serve(): a loop thread beside a delivery thread
+        model = FakeModel() if kind == "step_slots" else FakeLanes()
+        sched = ContinuousBatchingScheduler(model, n_slots=2).serve()
+        try:
+            reqs = [sched.submit([2, 3], max_new_tokens=4) for _ in range(3)]
+            assert all(r.wait(10) for r in reqs)
+        finally:
+            sched.shutdown(drain=True)
+    elif kind == "paged_decoder":
+        _serve_one(request.getfixturevalue("tiny_paged"), max_new=4)
+    else:
+        gen, lanes = request.getfixturevalue("tiny_paged_lm")
+        sched = ContinuousBatchingScheduler(gen, n_slots=lanes)
+        reqs = [sched.submit(np.arange(3, 3 + n), max_new_tokens=3)
+                for n in (5, 9)]
+        sched.run_until_idle()
+        assert all(r.done and r.error is None for r in reqs)
+    return tr.events()
+
+
+@pytest.mark.parametrize("kind", ["step_slots", "lane_step", "paged_decoder",
+                                  "paged_lm"])
+def test_every_span_of_a_round_reaches_scheduler_round(kind, request):
+    """Both stepping modes on fake lanes, both engines at a tiny size: in
+    a dispatched round every span of the loop's thread lies under ONE
+    ``scheduler/round``, carries its ``step``, and no span's children
+    outlast it: the round's time is a sum with nothing outside."""
+    evs = _rounds_of(kind, request)
+    spans = {e["id"]: e for e in evs if e["ph"] == "X"}
+    rounds = [e for e in spans.values() if e["name"] == "scheduler/round"]
+    assert rounds and all("parent" not in r for r in rounds)
+    dispatched = 0
+    for r in rounds:
+        inside = [e for e in spans.values() if e is not r
+                  and e["tid"] == r["tid"]
+                  and r["ts"] <= e["ts"] <= r["ts"] + r["dur"]]
+        if not any(e["name"] == "scheduler/deliver" for e in inside):
+            continue                    # found nothing to do: left out
+        dispatched += 1
+        covered = {}
+        for e in inside:
+            top = e
+            while top.get("parent") in spans:
+                top = spans[top["parent"]]
+            assert top is r, (kind, e["name"], top["name"])
+            assert e["args"]["step"] == r["args"]["step"], e["name"]
+            assert e["tdur"] <= e["dur"] + 1.0
+            covered[e["parent"]] = covered.get(e["parent"], 0.0) + e["dur"]
+        for parent, total in covered.items():
+            assert total <= spans[parent]["dur"] + 1e-3, spans[parent]["name"]
+        names = {e["name"] for e in inside}
+        assert {"scheduler/admit", "scheduler/plan", "scheduler/step",
+                "scheduler/deliver"} <= names
+        want = {"step_slots": set(), "lane_step": {"engine/fake"}}.get(
+            kind, {"engine/feed_build", "engine/dispatch", "engine/fetch",
+                   "engine/absorb", "executor/prepare",
+                   "executor_step/infer", "executor/writeback",
+                   "executor/release"})
+        assert want <= names, (kind, names)
+    assert dispatched >= 3
+    # what runs between rounds stays outside them
+    for e in spans.values():
+        if e["name"] in ("scheduler/wait", "scheduler/deliver_out"):
+            assert "parent" not in e, e["name"]
