@@ -46,6 +46,7 @@ from .. import fluid
 from ..fluid import layers
 from ..fluid.param_attr import ParamAttr
 from .cache_spec import CacheSpec
+from .step_tokens import emitted_ids, fed_tokens
 
 __all__ = ["LMConfig", "CacheSpec", "config_from_dict", "cache_specs",
            "param_shapes", "build_serve_step", "GLOBAL"]
@@ -182,8 +183,9 @@ def _linear(x, size: int, name: str):
 
 
 def build_serve_step(c: LMConfig, *, prefix: str, pools: Dict[str, Dict],
-                     n_lanes: int, n_prefill: int, chunk: int, tile: int,
-                     dtype: str = "bfloat16", impl: Optional[str] = None):
+                     n_lanes: int, n_prefill: int, prefill_slots: int,
+                     chunk: int, tile: int, dtype: str = "bfloat16",
+                     impl: Optional[str] = None):
     """The serve step over ``n_lanes`` decode tokens and ``n_prefill``
     chunks of ``chunk`` prompt tokens, as a program DESC: the arguments,
     feeds and results of ``mimo_v2_flash.build_serve_step`` (which
@@ -198,6 +200,7 @@ def build_serve_step(c: LMConfig, *, prefix: str, pools: Dict[str, Dict],
     decl = pools[GLOBAL]
     b, s_pf = int(n_lanes), int(n_prefill) * int(chunk) // int(tile)
     t = b + int(n_prefill) * int(chunk)
+    n_ids = b + int(prefill_slots)      # every variant's ids, one length
     h, d, r = c.num_attention_heads, c.hidden_size, c.kv_lora_rank
     nope, rope, dv = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
     nl = c.n_layer
@@ -209,7 +212,7 @@ def build_serve_step(c: LMConfig, *, prefix: str, pools: Dict[str, Dict],
         def feed(name, shape, dt="int32"):
             return layers.data(name, shape, dt, append_batch_size=False)
 
-        tok, pos = feed("tok", [t], "int64"), feed("pos", [t])
+        tok, pos = fed_tokens(t, n_ids), feed("pos", [t])
         dec = {k: feed(f"dec_{k}", [b]) for k in ("len", "base")}
         pf = {k: feed(f"pf_{k}", [s_pf]) for k in ("len", "base")} \
             if s_pf else None
@@ -294,7 +297,8 @@ def build_serve_step(c: LMConfig, *, prefix: str, pools: Dict[str, Dict],
                                out_dtype=dtype)
         logits = layers.vocab_logits(last, c.vocab_size,
                                      _w(f"{prefix}.head.w"))
-        next_ids = layers.argmax(logits, axis=-1)
+        next_ids = emitted_ids(layers.argmax(logits, axis=-1),
+                               b + int(n_prefill), n_ids)
         loads = layers.reshape(layers.concat(loads, axis=0),
                                [len(loads), c.experts_held]) \
             if loads else None

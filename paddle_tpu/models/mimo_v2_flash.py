@@ -32,6 +32,7 @@ from .. import fluid
 from ..fluid import layers
 from ..fluid.param_attr import ParamAttr
 from .cache_spec import CacheSpec
+from .step_tokens import emitted_ids, fed_tokens
 
 __all__ = ["LMConfig", "CacheSpec", "config_from_dict", "cache_specs",
            "param_shapes", "build_serve_step", "GLOBAL", "WINDOW"]
@@ -162,8 +163,9 @@ def _linear(x, size: int, name: str):
 
 
 def build_serve_step(c: LMConfig, *, prefix: str, pools: Dict[str, Dict],
-                     n_lanes: int, n_prefill: int, chunk: int, tile: int,
-                     dtype: str = "bfloat16", impl: Optional[str] = None):
+                     n_lanes: int, n_prefill: int, prefill_slots: int,
+                     chunk: int, tile: int, dtype: str = "bfloat16",
+                     impl: Optional[str] = None):
     """The serve step over ``n_lanes`` decode tokens and ``n_prefill``
     chunks of ``chunk`` prompt tokens (0: a decode-only step), as a
     program DESC.  ``pools[kind]`` declares a kind's cache: ``k`` / ``v``
@@ -173,7 +175,9 @@ def build_serve_step(c: LMConfig, *, prefix: str, pools: Dict[str, Dict],
     the ragged kernel with its own base.
 
     Feeds (T = n_lanes + n_prefill * chunk; S = n_prefill * chunk / tile):
-    ``tok`` [T] int64, ``pos`` [T] int32; per kind ``<kind>_pages`` /
+    ``tok`` [T] int64 beside ``prev_ids`` and ``tok_src`` (a row's input
+    token, from the host or from the last step's ids on the device:
+    ``step_tokens.fed_tokens``), ``pos`` [T] int32; per kind ``<kind>_pages`` /
     ``<kind>_offs`` [T] int32 (where each token's row goes; page 0 is the
     trash page), ``dec_<kind>_table`` [n_lanes, table], ``pf_<kind>_table``
     [S, table]; ``dec_len`` / ``dec_base`` / ``dec_top`` [n_lanes] and
@@ -188,12 +192,15 @@ def build_serve_step(c: LMConfig, *, prefix: str, pools: Dict[str, Dict],
     bias float32): a loader casts to what the program declares.
 
     Returns ``(program, startup, next_ids, logits, loads)``: the next
-    token and float32 logits of ``out_rows``, and the (token, expert)
+    token of ``out_rows`` at the generator's one length (``n_lanes +
+    prefill_slots``, zeros behind: the next step's ``prev_ids``, whichever
+    variant that is), their float32 logits, and the (token, expert)
     pairs each held expert got, layer by layer ([n_moe, held] int32; None
     for a model without expert layers)."""
     specs = cache_specs(c)
     b, s_pf = int(n_lanes), int(n_prefill) * int(chunk) // int(tile)
     t = b + int(n_prefill) * int(chunk)
+    n_ids = b + int(prefill_slots)      # every variant's ids, one length
     h, dk, dv, d = (c.num_attention_heads, c.head_dim, c.v_head_dim,
                     c.hidden_size)
     prog, startup = fluid.Program(), fluid.Program()
@@ -203,7 +210,7 @@ def build_serve_step(c: LMConfig, *, prefix: str, pools: Dict[str, Dict],
         def feed(name, shape, dt="int32"):
             return layers.data(name, shape, dt, append_batch_size=False)
 
-        tok, pos = feed("tok", [t], "int64"), feed("pos", [t])
+        tok, pos = fed_tokens(t, n_ids), feed("pos", [t])
         dec = {k: feed(f"dec_{k}", [b]) for k in ("len", "base", "top")}
         pf = {k: feed(f"pf_{k}", [s_pf]) for k in ("len", "base", "top")} \
             if s_pf else None
@@ -295,7 +302,8 @@ def build_serve_step(c: LMConfig, *, prefix: str, pools: Dict[str, Dict],
                                c.layernorm_epsilon, out_dtype=dtype)
         logits = layers.vocab_logits(last, c.vocab_size,
                                      _w(f"{prefix}.head.w"))
-        next_ids = layers.argmax(logits, axis=-1)
+        next_ids = emitted_ids(layers.argmax(logits, axis=-1),
+                               b + int(n_prefill), n_ids)
         loads = layers.reshape(layers.concat(loads, axis=0),
                                [len(loads), c.experts_held]) \
             if loads else None

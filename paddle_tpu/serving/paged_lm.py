@@ -40,12 +40,26 @@ is the prompt cap), and ``lane_step`` opens the same spans.  Refused, with
 an error that says so: prefix sharing (a window layer's pages are gone when
 a second request could share them), beam search, speculative decoding,
 session suspend / resume, int8 pools and a ``mesh_axes`` artifact.
+
+**One step of lookahead.**  A step is two halves: ``_launch`` (the feed,
+the dispatch, and everything the engine books that does not depend on a
+token's VALUE: positions, prompt progress, which row of the step's ids is
+which lane's) and ``_collect`` (the fetch, which waits for the device, the
+counters that read it, the tokens).  ``lane_step`` is the two in a row.
+``lane_step_ahead`` is what a scheduler's loop calls instead: launch step
+n+1, THEN collect step n, so the host's part of a step runs while the
+device runs the step before.  What makes that sound here is what the
+generator refuses: nothing reads a token on the host between two steps but
+the next step's input, and a decode row whose token is still in flight
+reads it on the device, from the last step's ids (``models.step_tokens``).
+The caller sees a lane's tokens one call late; a lane cleared in between
+has its token dropped here (``clear_slot`` moves the lane's epoch on).
 """
 
 from __future__ import annotations
 
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -158,7 +172,8 @@ def _build(layout: Dict, n_prefill: int):
     return layout["builder"].build_serve_step(
         layout["model"], prefix=layout["prefix"], pools=layout["groups"],
         n_lanes=layout["lanes"], n_prefill=n_prefill,
-        chunk=layout["chunk"], tile=layout["tile"], dtype=layout["dtype"],
+        prefill_slots=layout["prefill_slots"], chunk=layout["chunk"],
+        tile=layout["tile"], dtype=layout["dtype"],
         impl=layout["impl"])
 
 
@@ -177,10 +192,11 @@ def estimate_lm_hbm(config: Dict, builder=None):
 
 
 class _Lane:
-    __slots__ = ("phase", "prompt", "done", "pos", "cur", "rid", "pages",
-                 "tables", "chunk")
+    __slots__ = ("phase", "prompt", "done", "pos", "cur", "ahead", "left",
+                 "epoch", "rid", "pages", "tables", "chunk")
 
     def __init__(self):
+        self.epoch = 0                  # requests this lane has dropped
         self.reset()
 
     def reset(self):
@@ -188,11 +204,23 @@ class _Lane:
         self.prompt = None
         self.done = 0                   # prompt tokens in the cache
         self.pos = 0                    # tokens in the cache
-        self.cur = 0                    # the next decode step's input
+        self.cur = 0                    # the next decode step's input ...
+        # ... unless it is still in flight: (step, row of that step's ids)
+        self.ahead = None
+        self.left = 0                   # tokens it may yet be launched for
         self.rid = None
         self.pages: Dict[str, Dict[int, int]] = {}  # kind -> logical -> page
         self.tables: Dict[str, np.ndarray] = {}     # kind -> its feed row
         self.chunk = 0                  # prompt tokens in flight
+
+
+class _Flight(NamedTuple):
+    """A launched step nobody has fetched yet."""
+    step: int
+    ids: object                 # [lanes + prefill_slots], on the device
+    load: object                # [expert layers, held] on the device, None
+    logits: object              # on the device; None unless asked for
+    rows: Dict[int, Tuple[int, int]]    # slot -> (row of ids, lane's epoch)
 
 
 class PagedLMGenerator:
@@ -254,6 +282,16 @@ class PagedLMGenerator:
                                   for g in lay["groups"].values()
                                   if g["spec"].latent)
         self._latent_rows = 0
+        # launched and not fetched yet, oldest first: none between two
+        # ``lane_step`` calls, at most one between two ``lane_step_ahead``
+        self._in_flight: deque = deque()
+        self._steps_ahead = 0       # launched while one was in flight
+        self._fed_on_device = 0     # decode rows whose input was
+        self._strays = 0            # tokens of lanes cleared in flight
+        import jax.numpy as jnp
+
+        # the newest launch's ids, which every step is fed
+        self._ids = jnp.zeros(self.lanes + self.prefill_slots, "int32")
         loads = self._steps_built[0][4]     # [expert layers, held] or None
         self._load = np.zeros([int(n) for n in loads.shape]
                               if loads is not None else (0, 0), np.int64)
@@ -325,6 +363,7 @@ class PagedLMGenerator:
         self._slots = self.lanes
         self._lanes = [_Lane() for _ in range(self.lanes)]
         self._queue.clear()
+        self._in_flight.clear()
 
     def admit_slot(self, slot: int, src_tokens_1d,
                    max_new: Optional[int] = None) -> int:
@@ -346,6 +385,7 @@ class PagedLMGenerator:
         for kind, n in need.items():
             self.groups[kind].reserve(slot, n)
         lane.phase, lane.prompt = "prefill", src
+        lane.left = self._resolve_max_new(max_new)
         lane.pages = {kind: {} for kind in self.groups}
         self._queue.append(slot)
         return len(src)
@@ -362,6 +402,12 @@ class PagedLMGenerator:
         if slot in self._queue:
             self._queue.remove(slot)
         lane.reset()
+        lane.epoch += 1         # its tokens in flight are nobody's now
+        if self._in_flight and all(ln.phase == "idle"
+                                   for ln in self._lanes):
+            # nobody is left to take a token of the steps in flight
+            self._strays += sum(len(f.rows) for f in self._in_flight)
+            self._in_flight.clear()
 
     # -- refused -------------------------------------------------------------
     def resume_slot(self, slot, session_id, max_new=None):
@@ -415,6 +461,8 @@ class PagedLMGenerator:
         T, S = B + n_pf * C, n_pf * C // tq
         kinds = self.layout["groups"]
         feed = {"tok": np.zeros(T, np.int64), "pos": np.zeros(T, np.int32),
+                "prev_ids": self._ids,
+                "tok_src": np.arange(T, dtype=np.int32),
                 "out_rows": np.arange(B + n_pf, dtype=np.int32)}
         if self._load.size:
             # the rows that are a request's tokens: the others route to
@@ -436,11 +484,25 @@ class PagedLMGenerator:
         ring_ps = self._ring_ps
         decoding = []
         for slot, lane in enumerate(self._lanes):
-            if lane.phase != "decode":
+            # a lane is launched for ``max_new`` tokens and no more: the
+            # last position it writes is prompt + max_new - 2, inside the
+            # prompt + max_new positions admission reserved.  THIS rule is
+            # what keeps every fed page inside a lane's reservation, a
+            # step ahead too: the one row launched after a token that
+            # turns out to be the end of the sequence is inside the cap
+            # like any other (and a page a cleared lane gave back is
+            # written by its next holder in a LATER launch, which the
+            # device runs after)
+            if lane.phase != "decode" or not lane.left:
                 continue
             t = lane.pos
             decoding.append(slot)
-            feed["tok"][slot], feed["pos"][slot] = lane.cur, t
+            feed["pos"][slot] = t
+            if lane.ahead is None:
+                feed["tok"][slot] = lane.cur
+            else:
+                feed["tok_src"][slot] = T + lane.ahead[1]
+                self._fed_on_device += 1
             if "live" in feed:
                 feed["live"][slot] = 1
             feed["dec_len"][slot], feed["dec_base"][slot] = t + 1, t
@@ -495,7 +557,49 @@ class PagedLMGenerator:
         emitted token: ({slot: token}, {slot: [vocab]}).  For tests."""
         return self._step(True)
 
+    def lane_step_ahead(self) -> Dict[int, object]:
+        """``lane_step`` one step ahead: launch the NEXT step, then fetch
+        the one launched a call ago, so the feed, the dispatch and whatever
+        the caller does between two calls run while the device does.
+        Returns that step's {slot: token}: a lane's tokens come one call
+        late, in order, the same tokens.  From an idle engine two steps
+        are launched before the first is fetched; a launch after which no
+        lane is left to launch has nothing to hide its wait behind and is
+        fetched in the same call, so a lane may get its last two tokens at
+        once, as a list, and an engine whose lanes ran to their caps is
+        left with nothing in flight."""
+        if not self._in_flight:
+            self._launch()
+        if self._can_launch():
+            self._launch()
+        emitted: Dict[int, object] = self._collect()
+        if self._in_flight and not self._can_launch():
+            for slot, tok in self._collect().items():
+                emitted[slot] = [emitted[slot], tok] if slot in emitted \
+                    else tok
+        return emitted
+
     def _step(self, want_logits: bool):
+        if self._in_flight:
+            raise RuntimeError(
+                "a step launched by lane_step_ahead() is in flight: this "
+                "call would fetch out of turn")
+        self._launch(want_logits)
+        flight = self._in_flight[0]
+        emitted = self._collect()
+        if not want_logits:
+            return emitted, None
+        lg = np.asarray(flight.logits)
+        return emitted, {slot: lg[flight.rows[slot][0]] for slot in emitted}
+
+    def _can_launch(self) -> bool:
+        """Would a step carry a lane's row?"""
+        return bool(self._queue) or any(
+            lane.phase == "decode" and lane.left for lane in self._lanes)
+
+    def _launch(self, want_logits: bool = False) -> None:
+        """Feed and dispatch a step, and book everything about it that is
+        known without its tokens.  Nothing here waits for the device."""
         if self._slots == 0:
             raise RuntimeError("open_slots() before lane_step()")
         tr = self._tracer
@@ -510,22 +614,14 @@ class PagedLMGenerator:
                 fluid.scope_guard(self.scope):
             out = self.exe.run(prog, feed=feed, fetch_list=fetch,
                                return_numpy=False, mode="infer")
-        with tr.span("engine/fetch", cat="serving"):
-            # the host blocks here until the device has finished the step
-            ids = np.asarray(out[0]).reshape(-1)
-            load = np.asarray(out[1]).reshape(self._load.shape) \
-                if loads is not None else None
-        self._steps += 1
-        rows: Dict[int, int] = {}
         with tr.span("engine/absorb", cat="serving"):
-            if load is not None:
-                self._load += load
-                self._pairs += int(load.sum())
-                self._touched += int(np.count_nonzero(load))
+            self._steps += 1
+            self._steps_ahead += bool(self._in_flight)
+            self._ids = out[0]
+            rows: Dict[int, int] = {}
             written = len(decoding)
             for slot in decoding:
-                lane = self._lanes[slot]
-                lane.pos += 1
+                self._lanes[slot].pos += 1
                 rows[slot] = slot
             for s, slot in enumerate(chosen):
                 lane = self._lanes[slot]
@@ -541,13 +637,42 @@ class PagedLMGenerator:
                     self._finish_prefill(slot, lane)
                     rows[slot] = self.lanes + s
             self._latent_rows += written * self._latent_layers
-            emitted = {}
             for slot, row in rows.items():
-                self._lanes[slot].cur = emitted[slot] = int(ids[row])
-        if not want_logits:
-            return emitted, None
-        lg = np.asarray(out[-1])
-        return emitted, {slot: lg[row] for slot, row in rows.items()}
+                lane = self._lanes[slot]
+                lane.left -= 1
+                lane.ahead = (self._steps, row)
+            self._in_flight.append(_Flight(
+                self._steps, out[0], out[1] if loads is not None else None,
+                out[-1] if want_logits else None,
+                {slot: (row, self._lanes[slot].epoch)
+                 for slot, row in rows.items()}))
+
+    def _collect(self) -> Dict[int, int]:
+        """Fetch the oldest step in flight: {slot: token} of the lanes that
+        emitted in it and are still the request they were."""
+        flight = self._in_flight.popleft()
+        tr = self._tracer
+        with tr.span("engine/fetch", cat="serving"):
+            # the host blocks here until the device has finished the step
+            ids = np.asarray(flight.ids).reshape(-1)
+            load = None if flight.load is None else \
+                np.asarray(flight.load).reshape(self._load.shape)
+        emitted: Dict[int, int] = {}
+        with tr.span("engine/absorb", cat="serving"):
+            if load is not None:
+                self._load += load
+                self._pairs += int(load.sum())
+                self._touched += int(np.count_nonzero(load))
+            for slot, (row, epoch) in flight.rows.items():
+                lane = self._lanes[slot]
+                if lane.epoch != epoch:
+                    self._strays += 1       # cleared since the launch
+                    continue
+                emitted[slot] = tok = int(ids[row])
+                if lane.ahead == (flight.step, row):
+                    # no later launch has taken it from the device
+                    lane.cur, lane.ahead = tok, None
+        return emitted
 
     def _finish_prefill(self, slot: int, lane: _Lane) -> None:
         lane.phase = "decode"
@@ -578,8 +703,10 @@ class PagedLMGenerator:
             feed, _ = self._feed([], n_pf)
             fetch = [next_ids] + ([loads] if loads is not None else [])
             with fluid.scope_guard(self.scope):
-                self.exe.run(prog, feed=feed, fetch_list=fetch,
-                             return_numpy=False, mode="infer")
+                # each is fed the ids of the one before, as in service
+                self._ids = self.exe.run(
+                    prog, feed=feed, fetch_list=fetch, return_numpy=False,
+                    mode="infer")[0]
 
     def kv_bytes_per_token(self) -> int:
         """Bytes a cached token costs, as allocated, in the layers that
@@ -601,7 +728,10 @@ class PagedLMGenerator:
     def counters(self) -> Dict[str, object]:
         """What the step has done since load, for ``sched.stats()`` and
         the benchmark's per-layer readers."""
-        out = {"steps": self._steps, "moe_pairs_here": self._pairs,
+        out = {"steps": self._steps, "steps_ahead": self._steps_ahead,
+               "tokens_fed_on_device": self._fed_on_device,
+               "stray_tokens_dropped": self._strays,
+               "moe_pairs_here": self._pairs,
                "experts_touched": self._touched,
                "expert_load": self._load.tolist(),
                "kv_bytes_per_token": self.kv_bytes_per_token()}

@@ -31,7 +31,12 @@ extend the protocol two ways:
 * **self-managed stepping**: the model exposes ``lane_step()`` → one
   dispatch over every lane (chunked prefill interleaved with decode)
   returning ``{slot: token}`` for the lanes that actually emitted; the
-  scheduler keeps only the request bookkeeping.
+  scheduler keeps only the request bookkeeping.  Where the model also
+  exposes ``lane_step_ahead()`` (the same result one call late: the
+  engine launches the next step before it fetches this one) the loop
+  calls that instead: a request stays active until its last token has
+  been seen here, and a token of a lane retired in between never leaves
+  the engine.
 
 ISSUE 10 grows the scheduler into the gateway's shared execution core:
 
@@ -1154,10 +1159,18 @@ class ContinuousBatchingScheduler:
             # dropped here, as a plain model would never have decoded
             # them).
             try:
+                # an engine that can run one step ahead of its own fetch
+                # (``lane_step_ahead``: this round's tokens are the step
+                # before's, which nothing here needs to know) is stepped
+                # that way; an instance whose ``lane_step`` was wrapped is
+                # stepped through the wrapper
+                model = group.model
+                ahead = None if "lane_step" in getattr(model, "__dict__", ()) \
+                    else getattr(model, "lane_step_ahead", None)
                 with self._tracer.span("scheduler/step", cat="serving",
                                        managed=True, model=group.key,
                                        step=step):
-                    emitted = group.model.lane_step()
+                    emitted = (ahead or model.lane_step)()
             except BaseException as e:
                 self._fail_group(group, e)
                 return

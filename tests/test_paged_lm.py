@@ -256,6 +256,151 @@ def test_residency_is_what_the_step_program_declares(tmp_path):
     assert given.param_dtypes() == kept
 
 
+def _both_engines(which):
+    """A tiny generator of the model with a window ring (``mimo``) or of
+    the one with a latent pool (``moonlight``), and a maker of another
+    with pools of a given size."""
+    from perfbench.families import deepseek_v3
+
+    family, file = {"window-ring": (fam, "mimo-v2-flash-ep32"),
+                    "latent-pool": (deepseek_v3, "moonlight-16b-a3b-l5")
+                    }[which]
+    with open(f"perfbench/configs/{file}.json", encoding="utf-8") as f:
+        cfg = {**json.load(f), **family.REHEARSAL["serve"]["cfg"]}
+    conf = family.serving(cfg)["manifest"]["config"]
+    shapes = family.param_shapes(cfg, cfg["param_prefix"])
+
+    def make(**over):
+        gen = PagedLMGenerator(**dict(conf, **over))
+        gen.load_weights(weights.make(shapes, SEED,
+                                      kind_of=family.leaf_kind))
+        gen.open_slots(conf["lanes"])
+        return gen
+
+    return make
+
+
+# (name, slot, prompt length, max_new): A, B, C enter at once; D takes A's
+# slot and E takes C's in the very call after those let go
+_SCRIPT = (("A", 0, 5, 6), ("B", 1, 19, 12), ("C", 2, 9, 10),
+           ("D", 0, 5, 6), ("E", 2, 9, 10))
+
+
+def _drive(gen, step, limit=200):
+    """What a scheduler does with the script: A and D and E run to their
+    caps; B's fourth token is its end of sequence and C is cancelled once
+    3 of its tokens were seen (to the engine the same: a lane cleared
+    short of its cap).  A slot that lets go is admitted again before the
+    next call.  Returns each request's tokens as the caller saw them, the
+    calls made, and what was left unreserved with A, B and C in."""
+    rng = np.random.default_rng(41)
+    prompts = {name: rng.integers(2, 64, n).tolist()
+               for name, _, n, _ in _SCRIPT}
+    spec = {name: (slot, cap) for name, slot, _, cap in _SCRIPT}
+    seen = {name: [] for name in spec}
+    holder, waiting = {}, {"A": "D", "C": "E"}
+
+    def admit(name):
+        slot, cap = spec[name]
+        gen.admit_slot(slot, prompts[name], max_new=cap)
+        holder[slot] = name
+
+    def let_go(slot):
+        name = holder.pop(slot)
+        gen.clear_slot(slot)
+        if name in waiting:
+            admit(waiting[name])
+
+    for name in "ABC":
+        admit(name)
+    reserved = {k: g.unreserved() for k, g in gen.groups.items()}
+    calls = 0
+    while holder and calls < limit:
+        calls += 1
+        for slot, toks in step().items():
+            name = holder.get(slot)
+            for tok in toks if isinstance(toks, list) else [toks]:
+                if holder.get(slot) != name or name is None:
+                    break       # past its end: what a scheduler drops
+                seen[name].append(tok)
+                if len(seen[name]) == {"B": 4, "C": 3}.get(
+                        name, spec[name][1]):
+                    let_go(slot)
+        for slot, name in holder.items():
+            # never a position past what admission reserved
+            lane = gen._lanes[slot]
+            assert lane.pos <= len(prompts[name]) + spec[name][1] - 1
+    assert not holder, f"still running after {calls} calls: {holder}"
+    return seen, calls, reserved
+
+
+@pytest.mark.parametrize("which", ["window-ring", "latent-pool"])
+def test_a_step_ahead_gives_token_for_token_what_lane_step_gives(which):
+    """``lane_step_ahead`` against ``lane_step`` over one script: a prompt
+    whose prefill ends in the step in flight (every one), a request told
+    its end of sequence mid-stream, requests ending at their caps, a
+    cancel with a step in flight, slots admitted again in the very next
+    call; pools reserved to the last page.  Each request receives the
+    tokens it received before, none of the request that held its slot; the
+    counters read what the script implies."""
+    make = _both_engines(which)
+    probe = make()
+    need = {name: probe.pages_needed(np.zeros(n), cap)
+            for name, _, n, cap in _SCRIPT}
+    pools = {kind: 1 + sum(need[name][kind] for name in "ABC")
+             for kind in probe.groups}
+    sized = dict(num_pages=pools["global"])
+    if "window" in pools:
+        sized["window_pages"] = pools["window"]
+    gen = make(**sized)
+    plain, calls, reserved = _drive(gen, gen.lane_step)
+    assert set(reserved.values()) == {0}, reserved
+    assert [len(plain[n]) for n in "ABCDE"] == [6, 4, 3, 6, 10]
+    counted = gen.counters()
+    assert counted["steps"] == calls
+    assert counted["steps_ahead"] == counted["tokens_fed_on_device"] \
+        == counted["stray_tokens_dropped"] == 0
+
+    gen = make(**sized)
+    ahead, calls_ahead, reserved = _drive(gen, gen.lane_step_ahead)
+    assert ahead == plain
+    assert set(reserved.values()) == {0}, reserved
+    counted = gen.counters()
+    # two rows were launched for a lane that was gone when they came
+    # back: B's after its end of sequence, C's after its cancel; the
+    # requests that ran to their caps cost none
+    assert counted["stray_tokens_dropped"] == 2
+    # every step but the first was launched with one in flight, and every
+    # decode row took its input there: the 29 tokens seen and the 2
+    # strays, less the 5 that came out of a prompt's last chunk
+    assert counted["steps_ahead"] == counted["steps"] - 1
+    assert counted["tokens_fed_on_device"] == 29 + 2 - 5
+    assert calls_ahead <= calls
+    assert not gen._in_flight
+    for group in gen.groups.values():
+        assert group.in_use() == 0 and group.stats()["holders"] == 0
+
+
+def test_the_two_calls_after_each_other_and_what_is_refused():
+    """A lane whose token the host has (a ``lane_step`` before) is fed
+    from the host by the next ``lane_step_ahead``; a step in flight is
+    never fetched out of turn; ``open_slots`` drops it."""
+    gen = make_generator(tiny_cfg())
+    prompt = [5, 9, 11]
+    gen.admit_slot(0, prompt, max_new=8)
+    first = gen.lane_step()[0]
+    got = [first] + [gen.lane_step_ahead().get(0) for _ in range(3)]
+    assert gen.counters()["tokens_fed_on_device"] == 3    # all but one row
+    assert len(gen._in_flight) == 1
+    for call in (gen.lane_step, gen.step_logits):
+        with pytest.raises(RuntimeError, match="in flight"):
+            call()
+    gen.open_slots(4)
+    assert not gen._in_flight
+    gen.admit_slot(0, prompt, max_new=8)
+    assert [gen.lane_step()[0] for _ in range(4)] == got
+
+
 def test_page_group_accounting():
     g = PageGroup("window", 6, 4)
     g.reserve("a", 3)
