@@ -23,6 +23,7 @@ __all__ = [
     "nce", "im2sequence", "beam_search", "beam_search_decode", "batch_gather",
     "gather", "expand", "multiplex", "fused_attention", "decode_attention",
     "ragged_decode_attention", "rms_norm", "rotary_embedding", "swiglu",
+    "sigmoid_gate",
     "routed_experts", "gated_ffn", "latent_absorb", "vocab_logits",
     "quantize", "dequantize", "quantized_mul",
     "quantized_matmul", "quantized_conv2d",
@@ -948,10 +949,13 @@ def vocab_logits(x, size, param_attr=None, name=None):
     return out
 
 
-def rms_norm(x, param_attr=None, epsilon=1e-5, out_dtype=None, name=None):
+def rms_norm(x, param_attr=None, epsilon=1e-5, out_dtype=None, name=None,
+             scope=None):
     """RMS normalisation over the last axis with a learned scale
     (ops/llm_ops.rms_norm), computed in float32; ``out_dtype`` is what the
-    next product reads."""
+    next product reads.  x [T, H, D] normalises every head by itself with
+    one [D] scale (QK-norm); ``scope`` names its device operations in a
+    trace."""
     helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
     from ..initializer import ConstantInitializer
 
@@ -963,6 +967,8 @@ def rms_norm(x, param_attr=None, epsilon=1e-5, out_dtype=None, name=None):
     attrs = {"epsilon": float(epsilon)}
     if out_dtype is not None:
         attrs["out_dtype"] = str(out_dtype)
+    if scope is not None:
+        attrs["scope"] = str(scope)
     helper.append_op("rms_norm", {"X": x, "Scale": scale}, {"Out": out},
                      attrs)
     return out
@@ -984,6 +990,17 @@ def swiglu(gate, up, name=None):
     helper = LayerHelper("swiglu", name=name)
     out = helper.create_tmp_variable(gate.dtype, stop_gradient=True)
     helper.append_op("swiglu", {"Gate": gate, "Up": up}, {"Out": out}, {})
+    return out
+
+
+def sigmoid_gate(x, gate, scope=None, name=None):
+    """``x * sigmoid(gate)`` in float32, back in x's type
+    (ops/llm_ops.sigmoid_gate): the gate on an attention output; its
+    device operations carry ``scope`` in a trace."""
+    helper = LayerHelper("sigmoid_gate", name=name)
+    out = helper.create_tmp_variable(x.dtype, stop_gradient=True)
+    helper.append_op("sigmoid_gate", {"X": x, "Gate": gate}, {"Out": out},
+                     {} if scope is None else {"scope": str(scope)})
     return out
 
 

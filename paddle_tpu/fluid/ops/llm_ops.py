@@ -1,6 +1,8 @@
 """Ops of a decoder-only language-model block as today's open models build
-it: RMS normalisation, partial rotary position embedding, the SiLU-gated
-feed-forward (its gate alone, and whole), a routed-expert layer that
+it: RMS normalisation (of the residual stream, or of every head of the
+queries and keys: QK-norm), partial rotary position embedding, the
+SiLU-gated feed-forward (its gate alone, and whole), the sigmoid gate on an
+attention output, a routed-expert layer that
 computes ITS OWN experts' part of the result, latent attention's
 up-projection absorbed into queries and outputs, and the row write of a
 paged KV pool (one of a split pair, or a latent kind's only one).
@@ -13,6 +15,8 @@ are float32.
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
@@ -24,12 +28,17 @@ from .cache_ops import _scatter_tokens
 def rms_norm(ctx, x, scale):
     """``x * rsqrt(mean(x^2) + epsilon) * scale`` over the last axis, in
     float32; attr ``out_dtype`` (default: x's) is what the next product
-    reads."""
+    reads.  X of any rank: [T, H, D] with Scale [D] normalises every head
+    by itself with one scale for all heads (QK-norm).  Attr ``scope``
+    (optional) names its device operations in a trace."""
     eps = float(ctx.attr("epsilon", 1e-5))
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    y = y * scale.astype(jnp.float32)
-    return y.astype(ctx.attr("out_dtype", None) or x.dtype)
+    scope = ctx.attr("scope", None)
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        xf = x.astype(jnp.float32)
+        y = xf * jax.lax.rsqrt(
+            jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+        y = y * scale.astype(jnp.float32)
+        return y.astype(ctx.attr("out_dtype", None) or x.dtype)
 
 
 @primitive("rotary_embedding", inputs=["X", "Pos"], outputs=["Out"],
@@ -58,6 +67,17 @@ def swiglu(ctx, gate, up):
     """``silu(gate) * up``, in float32, back in the inputs' type."""
     g = gate.astype(jnp.float32)
     return (jax.nn.silu(g) * up.astype(jnp.float32)).astype(gate.dtype)
+
+
+@primitive("sigmoid_gate", inputs=["X", "Gate"], outputs=["Out"],
+           no_grad=True)
+def sigmoid_gate(ctx, x, gate):
+    """``x * sigmoid(gate)`` elementwise, in float32, back in X's type: the
+    gate a block puts on its attention's output before the output
+    projection.  Attr ``scope`` names its device operations in a trace."""
+    with jax.named_scope(ctx.attr("scope", None) or "gate"):
+        return (x.astype(jnp.float32)
+                * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(x.dtype)
 
 
 @primitive("paged_row_write", inputs=["Pool", "Value", "Pages", "Offsets"],

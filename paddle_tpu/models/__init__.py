@@ -24,7 +24,13 @@ from . import (  # noqa: F401
 # ``model_type`` of the published configuration gives ``config_from_dict``,
 # ``cache_specs`` (per kind of layer a pool, or a pool pair:
 # ``cache_spec.CacheSpec``), ``param_shapes`` and ``build_serve_step``.
-DECODER_LMS = ("mimo_v2_flash", "deepseek_v3")
+# What the engine shares is the cache and the flat batch; everything
+# between a layer's input and its cache rows is the builder's, kind by
+# kind: rotary over part of a head, all of it or none (``afmoe``'s global
+# layers carry no position), a norm on every head of the queries and keys
+# before they are cached, a gate on the attention's output, norms before
+# and after a sub-block.
+DECODER_LMS = ("mimo_v2_flash", "deepseek_v3", "afmoe")
 
 
 def decoder_lm(model_type: str):

@@ -9,7 +9,13 @@ generation share one context and the layers come in KINDS (the model's
 sliding-window layers the last ``window``.  The model is a BUILDER the
 generator is given or finds by the published ``model_type``
 (``models.decoder_lm``): ``config_from_dict``, ``cache_specs``,
-``param_shapes``, ``build_serve_step``; no model is named here.  So a request holds
+``param_shapes``, ``build_serve_step``; no model is named here.  What a
+builder may vary per kind of layer without the engine knowing: the
+rotary embedding (part of a head, all of it, or none: a global layer
+without positions), a norm on every head of the queries and keys before
+the key row is written (QK-norm), a gate on the attention's output, norms
+around a sub-block; the engine sees each kind's pools, tables and lengths
+(``models.cache_spec``).  So a request holds
 pages of one GROUP a kind, each with its own pool or pool pair, allocator,
 table and accounting (``paging.PageGroup``).  A kind whose values are the
 leading columns of its key row (latent attention: ``CacheSpec.latent``)
@@ -282,6 +288,10 @@ class PagedLMGenerator:
                                   for g in lay["groups"].values()
                                   if g["spec"].latent)
         self._latent_rows = 0
+        # what the steps held of prefill: prompt tokens, and the chunks
+        # that carried them
+        self._prompt_tokens = 0
+        self._chunks = 0
         # launched and not fetched yet, oldest first: none between two
         # ``lane_step`` calls, at most one between two ``lane_step_ahead``
         self._in_flight: deque = deque()
@@ -630,12 +640,14 @@ class PagedLMGenerator:
                            tokens=lane.chunk, done=lane.done + lane.chunk,
                            total=len(lane.prompt), **who)
                 written += lane.chunk
+                self._prompt_tokens += lane.chunk
                 lane.done += lane.chunk
                 lane.pos = lane.done
                 lane.chunk = 0
                 if lane.done >= len(lane.prompt):
                     self._finish_prefill(slot, lane)
                     rows[slot] = self.lanes + s
+            self._chunks += len(chosen)
             self._latent_rows += written * self._latent_layers
             for slot, row in rows.items():
                 lane = self._lanes[slot]
@@ -731,6 +743,8 @@ class PagedLMGenerator:
         out = {"steps": self._steps, "steps_ahead": self._steps_ahead,
                "tokens_fed_on_device": self._fed_on_device,
                "stray_tokens_dropped": self._strays,
+               "prompt_tokens_prefilled": self._prompt_tokens,
+               "prefill_chunks": self._chunks,
                "moe_pairs_here": self._pairs,
                "experts_touched": self._touched,
                "expert_load": self._load.tolist(),

@@ -308,3 +308,70 @@ def test_latent_serve_step_compiles_for_v5e(one_chip):
     assert kinds.pop("fusion") == 5, kinds              # the row scatters
     assert set(kinds) <= {"parameter", "bitcast"}, kinds
     assert compiled.memory_analysis().temp_size_in_bytes < n_elems * 2 / 4
+
+
+def test_afmoe_serve_step_compiles_for_v5e(one_chip):
+    """The donated serve step of ``trinity-mini-ep8-l8`` (ISSUE 42) at its
+    published widths, 64 lanes and a chunk of 256 in tiles of 64 queries,
+    compiled by the chip's compiler from shapes alone: 16 paged-attention
+    calls (32 query heads on 4 KV heads, keys and values 128 wide; a
+    global table of 66 slots, a window ring of 10 over a window of 2048)
+    and 18 grouped expert products are Mosaic calls, QK-norm and the
+    output gate are in the step under their names, both pool pairs are
+    aliased to their outputs, and the step's temporaries are a fraction of
+    the pools."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.fluid.lowering import build_step_fn
+    from paddle_tpu.serving import PagedLMGenerator
+    from perfbench.families import afmoe as fam
+
+    with open("perfbench/configs/trinity-mini-ep8-l8.json",
+              encoding="utf-8") as f:
+        cfg = json.load(f)
+    conf = dict(fam.serving(cfg)["manifest"]["config"], attn_impl="pallas",
+                num_pages=67, window_pages=21)
+    gen = PagedLMGenerator(executor=fluid.Executor(fluid.CPUPlace()), **conf)
+    gen.open_slots(conf["lanes"])
+    groups = gen.layout["groups"]
+    assert gen.tile == 64
+    assert (groups["global"]["table"], groups["window"]["table"],
+            groups["window"]["decode_pages"]) == (66, 10, 9)
+    prog, _, next_ids, _, loads = gen._steps_built[1]
+    feed, _ = gen._feed([], 1)
+    fetch = [next_ids.name, loads.name]
+    _, _, _, state_in, state_out = gen.exe._classified(
+        gen.exe._program_key(prog), feed, fetch, prog.desc.global_block())
+    step = build_step_fn(prog.desc, 0, list(feed), state_in, state_out,
+                         fetch, "infer")
+    shapes = gen.builder.param_shapes(gen.model, gen.prefix)
+    dtypes = gen.param_dtypes()
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    state = {n: described(shapes[n], dtypes[n]) for n in shapes}
+    # the pools at the rows the configuration gives them
+    served = fam.pool_shapes(cfg)
+    for kind, g in groups.items():
+        for p in ("k", "v"):
+            state[g[p]] = described(served[kind], g["dtype"])
+    assert set(state_in) == set(state)
+    compiled = gen.exe._jit_step(step).lower(
+        _shapes(feed, one_chip), state,
+        described((2,), np.int32)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 8 * 2 + 6 * 3
+    for name in ("paged_attn_global", "paged_attn_window", "attn/qk_norm",
+                 "attn/gate", "ffn/shared", "ffn/dense", "moe/route",
+                 "moe/experts"):
+        assert name in hlo, name
+    assert "input_output_alias" in hlo.splitlines()[0]
+    pool_bytes = 2 * 2 * sum(int(np.prod(s)) for s in served.values())
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes / 16
