@@ -186,8 +186,10 @@ def ragged_decode_attention(ctx, q, pool, page_table, lengths, q_base,
     an active mesh (tensor-parallel serving) the kernel maps over the
     mesh's batch and head axes."""
     from ...kernels.flash_attention import (
+        default_impl as _default_impl,
         ragged_decode_attention as _ra,
-        ragged_decode_attention_sharded as _ra_sharded)
+        ragged_decode_attention_sharded as _ra_sharded,
+        split_walk as _split_walk)
     from ...parallel import mesh as _pmesh
 
     kw = dict(layer=int(ctx.attr("layer", 0)),
@@ -208,16 +210,24 @@ def ragged_decode_attention(ctx, q, pool, page_table, lengths, q_base,
                 "ragged_decode_attention: split pools are not mapped over "
                 "a mesh")
         scope = ctx.attr("scope", None) or "attn/paged"
+        kernel = "paged_" + scope.replace("/", "_")
         if latent is not None:
             # the absorbed form: queries and outputs carry the latent's
             # up-projection, attention runs against the rows themselves
             ctx.note("attn_latent", form="absorbed", tile=int(q.shape[1]),
                      row=int(pool.shape[2]), values=int(latent))
+        if (kw["impl"] or _default_impl()) != "xla":
+            # the page walk the kernel's grid makes of these shapes (the
+            # gather form has no grid)
+            group, grid = _split_walk(q, pool, v_pool, page_table, latent)
+            ctx.note("attn_split", kernel=kernel, queries=int(q.shape[1]),
+                     slots=int(page_table.shape[1]), slots_per_step=group,
+                     grid_steps=grid[0] * grid[1])
         with jax.named_scope(scope):
             out = _ra(q, pool, page_table, lengths, q_base, v_pool=v_pool,
                       window=ctx.attr("window", None), sink=sink,
                       ring_top=ring_top, latent_values=latent,
-                      kernel_name="paged_" + scope.replace("/", "_"), **kw)
+                      kernel_name=kernel, **kw)
             scale = ctx.attr("out_scale", None)
             return out if scale is None else \
                 (out.astype(jnp.float32) * float(scale)).astype(out.dtype)

@@ -383,6 +383,12 @@ def _ragged_pallas(q, pool, page_table, lengths, q_base, layer, n_layer,
 # page congruent to i, so the walk is as long as the ring, never as the
 # context; ``ring_top`` [B] is the logical page of each lane's newest
 # written token, from which a slot's key positions follow.
+# The kernel's grid is (lane, GROUP of consecutive table slots): how many
+# slots a grid step follows from the call's shapes (``split_slot_group``:
+# several for a decode row, one for a prefill tile).  The pools stay in
+# HBM and the kernel copies the live slots' pages itself, the next live
+# group's while it works on this one's: a dead slot costs a scalar test,
+# a dead group one empty grid step, and neither reads a byte.
 
 
 def split_kv_rows(page_table, layer: int, n_layer: int):
@@ -476,18 +482,96 @@ def _head_slices(n_head: int, d: int):
     return starts, width, [j * d - starts[j] for j in range(n_head)]
 
 
-def _split_kernel(rows_ref, meta_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, k_starts, k_width, v_starts,
-                  v_width, c, ps, n_pages, window, ring, sm_scale):
-    """grid (B, P) like ``_ragged_kernel``; q rides [Hkv, G*C, k_width]:
-    KV head j's rows stack the C queries of each of its G query heads,
-    zero outside the head's own lanes of its slice.  ``v_ref`` None (the
-    latent form): the values are columns of the key block already here."""
+def _split_kernel(rows_ref, meta_ref, q_ref, k_hbm, v_hbm, sink_ref, o_ref,
+                  m_scr, l_scr, acc_scr, k_buf, v_buf, sems, walk, *,
+                  k_starts, k_width, v_starts, v_width, c, ps, n_pages,
+                  group, window, ring, sm_scale):
+    """grid (B, ceil(P / group)): a grid step is a GROUP of consecutive
+    table slots of one lane.  q rides [Hkv, G*C, k_width]: KV head j's
+    rows stack the C queries of each of its G query heads, zero outside
+    the head's own lanes of its slice.  ``v_hbm`` None (the latent form):
+    the values are columns of the key page already here.
+
+    The pools stay in HBM.  A live slot's page is copied into its place
+    of ``k_buf`` / ``v_buf`` [2, group, page, width]; a dead slot starts
+    no copy.  A live group starts the copies of the NEXT live group in
+    walk order (this lane's or a later lane's) while it works on its
+    own, slot by slot, so the dead groups between two live ones cost a
+    grid step each and hide no transfer.  ``walk`` (SMEM) carries that
+    next group (lane, group, its half of the buffers) from step to step:
+    the grid runs in order, on one core.  A group's slots are folded in
+    slot order by one loop, each with the update a grid step made when
+    it held one page."""
     b = pl.program_id(0)
-    p = pl.program_id(1)
+    gi = pl.program_id(1)
+    n_b = pl.num_programs(0)
+    n_g = pl.num_programs(1)
     hkv = len(k_starts)
 
-    @pl.when(p == 0)
+    def slot_of(lane, slot):
+        """(live, first key position) of a lane's table slot."""
+        length = meta_ref[0, lane]
+        base = meta_ref[1, lane]
+        page = _ring_page(slot, meta_ref[2, lane], n_pages) if ring \
+            else slot
+        p0 = page * ps
+        # a slot past the table's width pads the last group: a ring would
+        # alias it onto a live page
+        live = jnp.logical_and(slot < n_pages, p0 >= 0)
+        live = jnp.logical_and(live, p0 < length)
+        live = jnp.logical_and(live, p0 <= base + (c - 1))
+        if window is not None:
+            live = jnp.logical_and(live, p0 + ps > base - (window - 1))
+        return live, p0
+
+    def copies(lane, slot, half, i):
+        row = rows_ref[lane, slot]
+        pools = [(k_hbm, k_buf)] if v_hbm is None \
+            else [(k_hbm, k_buf), (v_hbm, v_buf)]
+        return [pltpu.make_async_copy(hbm.at[row], buf.at[half, i],
+                                      sems.at[n, half, i])
+                for n, (hbm, buf) in enumerate(pools)]
+
+    def may_be_live(lane, grp):
+        """False only where no slot of the group is live (a group taken
+        for live that holds none costs its empty grid step, as a dead
+        one does).  Without a ring slot i holds page i and the live
+        slots are ONE run; a ring's lie anywhere in it."""
+        base = meta_ref[1, lane]
+        end = jnp.minimum(meta_ref[0, lane], base + c)
+        if ring:
+            return end > 0
+        live = grp * group * ps < end
+        if window is not None:
+            last = jnp.minimum((grp + 1) * group, n_pages)
+            live = jnp.logical_and(live, last * ps > base - (window - 1))
+        return live
+
+    def next_live(lane, grp):
+        """The first group that may hold a live slot after (lane, grp) in
+        walk order; lane == B where there is none."""
+        def step(lane, grp):
+            last = grp + 1 == n_g
+            return jnp.where(last, lane + 1, lane), jnp.where(last, 0,
+                                                              grp + 1)
+
+        return jax.lax.while_loop(
+            lambda s: jnp.logical_and(
+                s[0] < n_b, jnp.logical_not(may_be_live(
+                    jnp.minimum(s[0], n_b - 1), s[1]))),
+            lambda s: step(*s), step(lane, grp))
+
+    first = jnp.logical_and(b == 0, gi == 0)
+
+    @pl.when(first)
+    def _first():
+        # the call's first group is taken for live, and starts its own
+        # copies: nobody came before it
+        walk[0] = 0
+        walk[1] = 0
+        walk[2] = 0
+
+    @pl.when(gi == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, -1e30)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -495,56 +579,83 @@ def _split_kernel(rows_ref, meta_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
 
     length = meta_ref[0, b]
     base = meta_ref[1, b]
-    if ring:
-        p0 = _ring_page(p, meta_ref[2, b], n_pages) * ps
-    else:
-        p0 = p * ps
-    live = jnp.logical_and(p0 >= 0, p0 < length)
-    live = jnp.logical_and(live, p0 <= base + (c - 1))
-    if window is not None:
-        live = jnp.logical_and(live, p0 + ps > base - (window - 1))
 
-    @pl.when(live)
-    def _page():
-        k = k_ref[0]                       # [ps, Hkv*Dk]
-        v = k if v_ref is None else v_ref[0]               # [ps, Hkv*Dv]
-        for j in range(hkv):               # static KV-head loop
-            q = q_ref[0, j]                # [G*C, k_width]
-            kj = k[:, k_starts[j]:k_starts[j] + k_width]
-            vj = v[:, v_starts[j]:v_starts[j] + v_width]
-            if kj.dtype != q.dtype:
-                kj = kj.astype(q.dtype)
-                vj = vj.astype(q.dtype)
-            s = jax.lax.dot_general(q, kj, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            s = s * sm_scale
-            cols = p0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            if c > 1:
-                qpos = jax.lax.rem(qpos, jnp.int32(c))
-            else:
-                qpos = jnp.zeros_like(qpos)
-            qpos = qpos + base
-            keep = jnp.logical_and(cols < length, cols <= qpos)
-            if window is not None:
-                keep = jnp.logical_and(keep, cols > qpos - window)
-            s = jnp.where(keep, s, -1e30)
-            m_prev = m_scr[j]                              # [rows, LANES]
-            l_prev = l_scr[j]
-            m_cur = jnp.max(s, axis=1)[:, None]
-            m_new = jnp.maximum(m_prev,
-                                jnp.broadcast_to(m_cur, m_prev.shape))
-            alpha = jnp.exp(m_prev - m_new)
-            pr = jnp.where(keep, jnp.exp(s - m_new[:, :1]), 0.0)
-            l_scr[j] = alpha * l_prev + jnp.broadcast_to(
-                jnp.sum(pr, axis=1)[:, None], l_prev.shape)
-            m_scr[j] = m_new
-            pv = jax.lax.dot_general(pr.astype(vj.dtype), vj,
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            acc_scr[j] = acc_scr[j] * alpha[:, :1] + pv
+    @pl.when(jnp.logical_and(walk[0] == b, walk[1] == gi))
+    def _group():
+        half = walk[2]
+        nxt_b, nxt_g = next_live(b, gi)
+        walk[0] = nxt_b
+        walk[1] = nxt_g
+        walk[2] = 1 - half
+        nxt_lane = jnp.minimum(nxt_b, n_b - 1)
 
-    @pl.when(p == n_pages - 1)
+        def slot(i, carry):
+            ahead = nxt_g * group + i
+
+            @pl.when(jnp.logical_and(nxt_b < n_b,
+                                     slot_of(nxt_lane, ahead)[0]))
+            def _ahead():
+                for cp in copies(nxt_lane, ahead, 1 - half, i):
+                    cp.start()
+
+            here = gi * group + i
+            live, p0 = slot_of(b, here)
+
+            @pl.when(live)
+            def _page():
+                own = copies(b, here, half, i)
+
+                @pl.when(first)
+                def _cold():
+                    for cp in own:
+                        cp.start()
+
+                for cp in own:
+                    cp.wait()
+                k = k_buf[half, i]                  # [ps, Hkv*Dk]
+                v = k if v_hbm is None else v_buf[half, i]  # [ps, Hkv*Dv]
+                for j in range(hkv):               # static KV-head loop
+                    q = q_ref[0, j]                # [G*C, k_width]
+                    kj = k[:, k_starts[j]:k_starts[j] + k_width]
+                    vj = v[:, v_starts[j]:v_starts[j] + v_width]
+                    if kj.dtype != q.dtype:
+                        kj = kj.astype(q.dtype)
+                        vj = vj.astype(q.dtype)
+                    s = jax.lax.dot_general(
+                        q, kj, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    s = s * sm_scale
+                    cols = p0 + jax.lax.broadcasted_iota(jnp.int32,
+                                                         s.shape, 1)
+                    qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                    if c > 1:
+                        qpos = jax.lax.rem(qpos, jnp.int32(c))
+                    else:
+                        qpos = jnp.zeros_like(qpos)
+                    qpos = qpos + base
+                    keep = jnp.logical_and(cols < length, cols <= qpos)
+                    if window is not None:
+                        keep = jnp.logical_and(keep, cols > qpos - window)
+                    s = jnp.where(keep, s, -1e30)
+                    m_prev = m_scr[j]                      # [rows, LANES]
+                    l_prev = l_scr[j]
+                    m_cur = jnp.max(s, axis=1)[:, None]
+                    m_new = jnp.maximum(
+                        m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
+                    alpha = jnp.exp(m_prev - m_new)
+                    pr = jnp.where(keep, jnp.exp(s - m_new[:, :1]), 0.0)
+                    l_scr[j] = alpha * l_prev + jnp.broadcast_to(
+                        jnp.sum(pr, axis=1)[:, None], l_prev.shape)
+                    m_scr[j] = m_new
+                    pv = jax.lax.dot_general(
+                        pr.astype(vj.dtype), vj, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    acc_scr[j] = acc_scr[j] * alpha[:, :1] + pv
+            return carry
+
+        jax.lax.fori_loop(0, group, slot, 0)
+
+    @pl.when(gi == n_g - 1)
     def _finalize():
         l_fin = l_scr[...]
         dead = l_fin == 0.0                # no key of the lane was live
@@ -556,9 +667,31 @@ def _split_kernel(rows_ref, meta_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
         o_ref[0] = out.astype(o_ref.dtype)
 
 
+def split_walk(q, k_pool, v_pool, page_table, latent_values=None):
+    """(slots a grid step, grid) of the split kernel on these arrays:
+    ``split_slot_group`` of their shapes, lanes x groups of slots."""
+    b, c, h, dk = q.shape
+    _r, ps, kw = k_pool.shape
+    latent = v_pool is None
+    hkv = 1 if latent else kw // dk
+    dv = int(latent_values) if latent else v_pool.shape[2] // hkv
+    n_pages = page_table.shape[1]
+    group = split_slot_group(c, h, hkv, kw // hkv, dv, ps,
+                             jnp.dtype(k_pool.dtype).itemsize, n_pages,
+                             latent=latent)
+    return group, (b, -(-n_pages // group))
+
+
+# One trace for every layer of a kind: ``layer`` is data, and a model's
+# builder emits this call once a layer, a program variant and a shape
+# inference (dozens a build, each a trace of the kernel's body without
+# this)
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "n_layer", "sm_scale", "window", "interpret", "name", "latent_values",
+    "group"))
 def _split_pallas(q, k_pool, v_pool, page_table, lengths, q_base, ring_top,
-                  layer, n_layer, sm_scale, window, sink, interpret,
-                  name="ragged_paged_attn_gqa", latent_values=None):
+                  layer, sink, *, n_layer, sm_scale, window, interpret,
+                  name, latent_values, group):
     b, c, h, dk = q.shape
     _r, ps, kw = k_pool.shape
     latent = v_pool is None
@@ -566,7 +699,11 @@ def _split_pallas(q, k_pool, v_pool, page_table, lengths, q_base, ring_top,
     dv = int(latent_values) if latent else v_pool.shape[2] // hkv
     g = h // hkv
     n_pages = page_table.shape[1]
-    rows = split_kv_rows(page_table, layer, n_layer)
+    n_groups = -(-n_pages // group)
+    # the last group's slots past the table: the trash page's row, dead
+    rows = split_kv_rows(jnp.pad(
+        page_table, ((0, 0), (0, n_groups * group - n_pages))), layer,
+        n_layer)
     ring = ring_top is not None
     top = jnp.asarray(ring_top, jnp.int32).reshape(b) if ring \
         else jnp.zeros(b, jnp.int32)
@@ -590,15 +727,21 @@ def _split_pallas(q, k_pool, v_pool, page_table, lengths, q_base, ring_top,
     def q_map(bi, pi, rw, mt):
         return (bi, 0, 0, 0)
 
-    def kv_map(bi, pi, rw, mt):
-        return (rw[bi, pi], 0, 0)
-
+    # the pools are not blocked: the kernel copies a live page itself
     in_specs = [pl.BlockSpec((1, hkv, g * c, k_width), q_map),
-                pl.BlockSpec((1, ps, kw), kv_map)]
+                pl.BlockSpec(memory_space=pl.ANY)]
     args = [qk, k_pool]
+    scratch = [pltpu.VMEM((hkv, g * c, LANES), jnp.float32),
+               pltpu.VMEM((hkv, g * c, LANES), jnp.float32),
+               pltpu.VMEM((hkv, g * c, v_width), jnp.float32),
+               pltpu.VMEM((2, group, ps, kw), k_pool.dtype)]
     if not latent:
-        in_specs.append(pl.BlockSpec((1, ps, v_pool.shape[2]), kv_map))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         args.append(v_pool)
+        scratch.append(pltpu.VMEM((2, group, ps, v_pool.shape[2]),
+                                  v_pool.dtype))
+    scratch += [pltpu.SemaphoreType.DMA((1 if latent else 2, 2, group)),
+                pltpu.SMEM((3,), jnp.int32)]
     if have_sink:
         sk = jnp.broadcast_to(
             jnp.asarray(sink, jnp.float32).reshape(hkv, g, 1, 1),
@@ -608,33 +751,34 @@ def _split_pallas(q, k_pool, v_pool, page_table, lengths, q_base, ring_top,
         args.append(sk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n_pages),
+        grid=(b, n_groups),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hkv, g * c, v_width), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((hkv, g * c, LANES), jnp.float32),
-            pltpu.VMEM((hkv, g * c, LANES), jnp.float32),
-            pltpu.VMEM((hkv, g * c, v_width), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
     base = functools.partial(
         _split_kernel, k_starts=tuple(k_starts), k_width=k_width,
         v_starts=tuple(v_starts), v_width=v_width, c=c, ps=ps,
-        n_pages=n_pages, window=None if window is None else int(window),
-        ring=ring, sm_scale=sm_scale)
+        n_pages=n_pages, group=group, window=window, ring=ring,
+        sm_scale=sm_scale)
 
-    def kernel(rows_ref, meta_ref, q_ref, k_ref, *rest):
+    def kernel(rows_ref, meta_ref, q_ref, k_hbm, *rest):
         rest = list(rest)
-        v_ref = None if latent else rest.pop(0)
+        v_hbm = None if latent else rest.pop(0)
         sink_ref = rest.pop(0) if have_sink else None
-        return base(rows_ref, meta_ref, q_ref, k_ref, v_ref, sink_ref, *rest)
+        o_ref, m_scr, l_scr, acc_scr, k_buf, *rest = rest
+        v_buf = None if latent else rest.pop(0)
+        return base(rows_ref, meta_ref, q_ref, k_hbm, v_hbm, sink_ref, o_ref,
+                    m_scr, l_scr, acc_scr, k_buf, v_buf, *rest)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g * c, v_width), q.dtype),
+        # in order, on one core: a step starts the next live group's
+        # copies, of this lane or of the next
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name=name,
     )(rows, meta, *args)
@@ -648,34 +792,67 @@ def _split_pallas(q, k_pool, v_pool, page_table, lengths, q_base, ring_top,
 SPLIT_VMEM_BYTES = 16 * 1024 * 1024
 
 
-def split_query_tile(chunk: int, n_head: int, kv_heads: int, d_key: int,
-                     d_value: int, page_size: int, itemsize: int,
-                     vmem_bytes: Optional[int] = None,
-                     latent: bool = False) -> int:
-    """How many queries of a prompt chunk one lane of the split kernel
-    takes: the largest of chunk, chunk / 2, chunk / 4 ... whose blocks fit
-    ``vmem_bytes`` (default ``SPLIT_VMEM_BYTES``).  A lane holds, per query and query head: q and the
-    output (double-buffered blocks), the running max, sum and float32
-    accumulator, the sink's block; besides a page of keys and values
-    (double-buffered; ``latent``: ONE row holds both, so a page of key
-    rows alone) and one KV head's scores, mask and probabilities."""
+def _split_vmem_need(queries: int, group: int, n_head: int, kv_heads: int,
+                     d_key: int, d_value: int, page_size: int,
+                     itemsize: int, latent: bool) -> int:
+    """Bytes of fast memory one grid step of the split kernel holds: per
+    query and query head, q and the output (double-buffered blocks), the
+    running max, sum and float32 accumulator, the sink's block; besides
+    ``group`` pages of keys and values (double-buffered; ``latent``: ONE
+    row holds both, so pages of key rows alone) and one KV head's scores,
+    mask and probabilities against a page."""
     _, k_width, _ = _head_slices(kv_heads, d_key)
     _, v_width, _ = _head_slices(kv_heads, d_value)
     per_row = 2 * (k_width + v_width) * itemsize \
         + (2 * LANES + v_width) * 4 + 2 * LANES * 4
     pages = 2 * page_size * kv_heads \
         * (d_key + (0 if latent else d_value)) * itemsize
+    return n_head * queries * per_row + group * pages \
+        + 3 * (n_head // kv_heads) * queries * page_size * 4
 
-    def need(c):
-        return n_head * c * per_row + pages \
-            + 3 * (n_head // kv_heads) * c * page_size * 4
 
+def split_query_tile(chunk: int, n_head: int, kv_heads: int, d_key: int,
+                     d_value: int, page_size: int, itemsize: int,
+                     vmem_bytes: Optional[int] = None,
+                     latent: bool = False) -> int:
+    """How many queries of a prompt chunk one lane of the split kernel
+    takes: the largest of chunk, chunk / 2, chunk / 4 ... whose blocks fit
+    ``vmem_bytes`` (default ``SPLIT_VMEM_BYTES``) beside one page
+    (``_split_vmem_need``)."""
     if vmem_bytes is None:
         vmem_bytes = SPLIT_VMEM_BYTES
     c = int(chunk)
-    while c % 2 == 0 and need(c) > vmem_bytes:
+    while c % 2 == 0 and _split_vmem_need(
+            c, 1, n_head, kv_heads, d_key, d_value, page_size, itemsize,
+            latent) > vmem_bytes:
         c //= 2
     return c
+
+
+def split_slot_group(queries: int, n_head: int, kv_heads: int, d_key: int,
+                     d_value: int, page_size: int, itemsize: int,
+                     slots: int, vmem_bytes: Optional[int] = None,
+                     latent: bool = False) -> int:
+    """How many consecutive table slots one grid step of the split kernel
+    walks: the largest power of two, at most the table's ``slots``, at
+    which the lane's blocks and the group's pages (``_split_vmem_need``)
+    take at most HALF of ``vmem_bytes`` (default ``SPLIT_VMEM_BYTES``).
+    The half tells the two kinds of call apart by what they hold.  A
+    decode row's blocks are small and its step is bound by the step
+    itself and its transfers: it takes 4 to 8 slots (on the chip those
+    read within 2 % of the best of 1 .. 16 for every served model's
+    decode call: PERF.md section 6, PR 44).  A prefill tile
+    (``split_query_tile``) holds more than half already and is bound by
+    its products: it takes one slot a step, the grid of a page a step
+    (where 2 or 4 slots fit at all they took 1 to 5 % less time)."""
+    if vmem_bytes is None:
+        vmem_bytes = SPLIT_VMEM_BYTES
+    k = 1
+    while 2 * k <= int(slots) and 2 * _split_vmem_need(
+            queries, 2 * k, n_head, kv_heads, d_key, d_value, page_size,
+            itemsize, latent) <= vmem_bytes:
+        k *= 2
+    return k
 
 
 def _resolve_q_base(q, q_base, causal: bool):
@@ -742,14 +919,19 @@ def ragged_decode_attention(q, pool, page_table, lengths, q_base=None,
                 "ragged_decode_attention: latent_values is the width of "
                 "the values inside ONE pool's rows (no v_pool), which "
                 "are at least as wide as the queries")
-        args = (q, pool, v_pool, page_table, lengths, q_base, ring_top,
-                layer, n_layer, float(sm_scale), window, sink)
         if impl in ("pallas", "pallas_interpret"):
             return _split_pallas(
-                *args, interpret=(impl == "pallas_interpret"),
+                q, pool, v_pool, page_table, lengths, q_base, ring_top,
+                layer, sink, n_layer=n_layer, sm_scale=float(sm_scale),
+                window=None if window is None else int(window),
+                interpret=(impl == "pallas_interpret"),
                 name=kernel_name or "ragged_paged_attn_gqa",
-                latent_values=latent_values)
-        return _split_xla(*args, latent_values=latent_values)
+                latent_values=latent_values,
+                group=split_walk(q, pool, v_pool, page_table,
+                                 latent_values)[0])
+        return _split_xla(q, pool, v_pool, page_table, lengths, q_base,
+                          ring_top, layer, n_layer, float(sm_scale), window,
+                          sink, latent_values=latent_values)
     if window is not None or sink is not None or ring_top is not None:
         raise ValueError("ragged_decode_attention: window, sink and "
                          "ring_top need split pools (v_pool)")

@@ -67,3 +67,24 @@ def hlo_results_of_size(hlo: str, n_elems: int):
                 == n_elems:
             kinds[m.group(2)] = kinds.get(m.group(2), 0) + 1
     return kinds
+
+
+def split_walks(call, group, monkeypatch):
+    """``call()`` (the split paged-attention kernel, interpreted) with
+    ``group`` table slots a grid step and with ONE: ``(got, one)``.
+    ``group`` is a number forced in the rule's place, or ``"derived"``:
+    the rule's own answer, which has to be more than one slot."""
+    import importlib
+
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    rule, seen = fa.split_slot_group, []
+
+    def forced(*a, **k):
+        seen.append(rule(*a, **k) if group == "derived" else group)
+        return seen[-1]
+
+    monkeypatch.setattr(fa, "split_slot_group", forced)
+    got = np.asarray(call())
+    assert seen and (group != "derived" or seen[0] > 1)
+    monkeypatch.setattr(fa, "split_slot_group", lambda *a, **k: 1)
+    return got, np.asarray(call())

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import split_walks
 from paddle_tpu.kernels.flash_attention import (latent_row_width,
                                                 ragged_decode_attention,
                                                 split_query_tile)
@@ -165,6 +166,34 @@ def test_the_latent_kernel_matches_the_gather_form_and_plain_attention(
     assert got.shape == (3, c, h, values)
     np.testing.assert_allclose(xla, want, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(got, xla, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("group", ["derived", 1, 2, 4, 8])
+@pytest.mark.parametrize("contexts, c", [
+    ([256, 1, 8, 64, 129], 1),      # 32 slots: full, one token, the edges
+    ([264, 200, 128, 3], 1),        # 33 slots: no multiple of any group
+    ([30, 17, 32, 9], 1),           # 4 slots: narrower than a group of 8
+    ([264, 64, 20], 4),             # a tile's queries over 33 slots
+], ids=["32-slots", "33-slots", "4-slots", "33-slots-tile"])
+def test_the_latent_kernel_walks_a_group_of_slots_like_a_slot_a_step(
+        contexts, c, group, monkeypatch):
+    """ISSUE 44, the latent form (no value pool: one copy a slot): a
+    group of table slots a grid step gives the result of a slot a step
+    bit for bit, the gather form's within rounding, and nothing for an
+    idle lane."""
+    f, want = _latent_case(4, 24, 16, 8, c, contexts=contexts + [50])
+    f["lengths"][-1] = 0                          # an idle lane
+    args = [jnp.asarray(f[k]) for k in ("q", "pool", "table", "lengths",
+                                        "base")]
+    kw = dict(layer=1, n_layer=2, latent_values=16, sm_scale=24 ** -0.5)
+    got, one = split_walks(
+        lambda: ragged_decode_attention(*args, impl="pallas_interpret",
+                                        **kw), group, monkeypatch)
+    xla = np.asarray(ragged_decode_attention(*args, impl="xla", **kw))
+    assert np.array_equal(got, one)
+    np.testing.assert_allclose(got, xla, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got[:-1], want[:-1], rtol=2e-5, atol=2e-5)
+    assert np.all(got[-1] == 0.0)
 
 
 def test_a_dead_lane_of_the_latent_kernel_reads_nothing():

@@ -10,9 +10,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import split_walks
 from paddle_tpu.fluid.core.registry import EmitCtx, get_op_info
 from paddle_tpu.kernels.flash_attention import (_head_slices,
-                                                ragged_decode_attention)
+                                                ragged_decode_attention,
+                                                split_query_tile,
+                                                split_slot_group)
 from paddle_tpu.kernels.grouped_matmul import grouped_matmul
 from perfbench.reference import mimo_v2_flash as ref
 
@@ -162,6 +165,90 @@ def test_a_dead_lane_reads_nothing_and_the_sink_takes_mass():
         np.testing.assert_allclose(got[0], want[0], rtol=2e-5, atol=2e-5)
         bare = np.asarray(ragged_decode_attention(*args, sink=None, **kw))
         assert np.abs(bare[0] - got[0]).max() > 1e-3    # dropping it shows
+
+
+# id: (window, ring, c, contexts, the table's width): 8-token pages; the
+# width follows from the longest context, or from window and c in a ring
+_WALKS = {
+    # a full table, one token, a page's edge (8), a group of 8's edge (64)
+    "global-32": (None, False, 1, [256, 1, 8, 64, 129], 32),
+    "global-33": (None, False, 1, [264, 200, 128, 3], 33),
+    "global-66": (None, False, 1, [528, 65, 192, 40], 66),
+    "global-33-chunk": (None, False, 4, [264, 64, 20], 33),
+    # a window over whole tables: the live slots are a run in the middle
+    "window-33": (40, False, 1, [264, 100, 30], 33),
+    # a window inside one page: the first lane's only live page is its
+    # table's LAST slot, and the next lane's first group is dead after it
+    "window-33-last-slot": (6, False, 1, [264, 100, 30], 33),
+    # rings: wrapped (300 tokens through 10 slots), exactly full, short
+    "ring-10": (73, True, 1, [300, 80, 9, 72], 10),
+    "ring-4": (25, True, 1, [100, 32, 1, 24], 4),
+    "ring-10-chunk": (66, True, 8, [300, 81, 16], 10),
+}
+
+
+@pytest.mark.parametrize("group", ["derived", 1, 2, 4, 8])
+@pytest.mark.parametrize("walk", list(_WALKS))
+def test_a_group_of_slots_walks_like_a_slot_a_step(walk, group, monkeypatch):
+    """ISSUE 44: a grid step of the split kernel takes ``group`` table
+    slots.  Whatever the group (the rule's own, 1, 2, 4, 8: multiples of
+    the table's width and not, wider than a ring of 4), the result is that
+    of a slot a step BIT FOR BIT (the slots are folded in slot order), and
+    the gather form's and plain attention's within rounding; an idle lane
+    (length 0) reads nothing."""
+    window, ring, c, contexts, width = _WALKS[walk]
+    f, want = _paged_case(4, 2, 24, 16, 8, window, ring, c,
+                          contexts=contexts + [50])
+    assert f["table"].shape[1] == width
+    f["lengths"][-1] = 0                          # an idle lane
+    args = [jnp.asarray(f[k]) for k in ("q", "kp", "table", "lengths",
+                                        "base")]
+    kw = dict(layer=1, n_layer=2, v_pool=jnp.asarray(f["vp"]),
+              window=window,
+              sink=None if f["sink"] is None else jnp.asarray(f["sink"]),
+              ring_top=jnp.asarray(f["top"]) if ring else None)
+    got, one = split_walks(
+        lambda: ragged_decode_attention(*args, impl="pallas_interpret",
+                                        **kw), group, monkeypatch)
+    xla = np.asarray(ragged_decode_attention(*args, impl="xla", **kw))
+    assert np.array_equal(got, one)
+    np.testing.assert_allclose(got, xla, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got[:-1], want[:-1], rtol=2e-5, atol=2e-5)
+    assert np.all(got[-1] == 0.0)
+
+
+# (queries, query heads, KV heads, key width, value width, page, slots,
+#  latent) at the three served models' published widths, bfloat16
+_CALLS = {
+    "moonlight-latent": (16, 1, 640, 512, 256, 32, True, 64),
+    "trinity-global": (32, 4, 128, 128, 256, 66, False, 64),
+    "trinity-ring": (32, 4, 128, 128, 256, 10, False, 64),
+    "mimo-global": (64, 4, 192, 128, 256, 33, False, 32),
+    "mimo-ring": (64, 8, 192, 128, 128, 4, False, 32),
+}
+
+
+@pytest.mark.parametrize("call", list(_CALLS))
+def test_the_slot_group_follows_from_the_calls_shapes(call):
+    """``split_slot_group`` beside ``split_query_tile``: a decode row
+    takes several slots a grid step, the prefill tile that
+    ``split_query_tile`` derives takes one (its grid is a page a step, as
+    before); a power of two, at most the table's width, within the fast
+    memory it is given."""
+    h, hkv, dk, dv, ps, slots, latent, tile = _CALLS[call]
+    assert split_query_tile(256, h, hkv, dk, dv, ps, 2, latent=latent) \
+        == tile
+    decode = split_slot_group(1, h, hkv, dk, dv, ps, 2, slots, latent=latent)
+    assert decode == {"moonlight-latent": 8}.get(call, 4)
+    assert split_slot_group(tile, h, hkv, dk, dv, ps, 2, slots,
+                            latent=latent) == 1
+    # more fast memory, more slots, never more than the table holds
+    more = split_slot_group(1, h, hkv, dk, dv, ps, 2, slots, latent=latent,
+                            vmem_bytes=1 << 40)
+    assert decode <= more <= slots and more & (more - 1) == 0
+    assert 2 * more > slots
+    assert split_slot_group(1, h, hkv, dk, dv, ps, 2, slots, latent=latent,
+                            vmem_bytes=0) == 1
 
 
 def test_head_slices_are_tile_aligned_at_the_published_widths():

@@ -119,6 +119,48 @@ def test_step_logits_equal_the_reference_full_forward(monkeypatch, vmem,
         np.testing.assert_allclose(np.stack(got), want, rtol=1e-4, atol=2e-5)
 
 
+def test_every_split_calls_walk_is_noted_once_a_compile(monkeypatch):
+    """ISSUE 44: ``lowering/attn_split``, an instant under the compile of
+    a step, says of each paged-attention call which kernel it is, its
+    queries a lane, its table's slots, how many of them a grid step takes
+    and the grid's steps: several slots for decode rows, one for the
+    tiles of a chunk where fast memory holds half a chunk at a time;
+    nothing is written by a step that is not compiled."""
+    import importlib
+
+    from paddle_tpu.observability import tracer
+
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    monkeypatch.setattr(fa, "SPLIT_VMEM_BYTES", 50_000)
+    before = len(tracer().events(name="lowering/attn_split"))
+    cfg = tiny_cfg()
+    gen = make_generator(cfg, attn_impl="pallas_interpret")
+    assert gen.tile == 4
+    lanes = gen.layout["lanes"]
+    tables = {f"paged_attn_{kind}": g["table"]
+              for kind, g in gen.layout["groups"].items()}
+    gen.admit_slot(0, list(range(2, 25)), max_new=8)
+    gen.admit_slot(1, list(range(2, 9)), max_new=8)
+    for _ in range(3):
+        gen.lane_step()
+    notes = [e["args"] for e in
+             tracer().events(name="lowering/attn_split")[before:]]
+    assert {n["kernel"] for n in notes} == set(tables)
+    for n in notes:
+        assert n["slots"] == tables[n["kernel"]]
+        k = n["slots_per_step"]
+        assert k == (4 if n["queries"] == 1 else 1)
+        kernel_lanes = lanes if n["queries"] == 1 \
+            else n["grid_steps"] // n["slots"]
+        assert kernel_lanes in (lanes, 2, 4)          # 1 or 2 chunks' tiles
+        assert n["grid_steps"] == kernel_lanes * -(-n["slots"] // k)
+    assert {n["queries"] for n in notes} == {1, 4}
+    gen.lane_step()                        # decode rows alone: compiled
+    seen = len(tracer().events(name="lowering/attn_split"))
+    gen.lane_step()                        # the same program: no note
+    assert len(tracer().events(name="lowering/attn_split")) == seen
+
+
 def test_a_thousand_token_generation_holds_a_bounded_ring_of_window_pages():
     """Window pages are handed out as a ring and given back behind
     position t - window: a 1000-token generation never holds more than the

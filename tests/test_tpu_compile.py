@@ -252,6 +252,87 @@ def test_latent_kernel_compiles_for_v5e(b, c, one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
 
 
+# the split kernel's calls at the three served models' published shapes
+# (ISSUE 44): (lanes, queries, query heads, key width, pool rows' width,
+# value pool's width, page, table slots, layers, latent values, window,
+# sink) -> slots a grid step
+_SPLIT_CALLS = {
+    "moonlight-latent-decode": ((128, 1, 16, 576, 640, None, 256, 32, 5,
+                                 512, None, False), 8),
+    "trinity-global-decode": ((64, 1, 32, 128, 512, 512, 256, 66, 2, None,
+                               None, False), 4),
+    "trinity-ring-decode": ((64, 1, 32, 128, 512, 512, 256, 10, 6, None,
+                             2048, False), 4),
+    "mimo-global-decode": ((64, 1, 64, 192, 768, 512, 256, 33, 2, None,
+                            None, True), 4),
+    "mimo-ring-decode": ((64, 1, 64, 192, 1536, 1024, 128, 4, 5, None,
+                          128, True), 4),
+    "trinity-global-tile": ((16, 64, 32, 128, 512, 512, 256, 66, 2, None,
+                             None, False), 1),
+    "trinity-ring-tile": ((16, 64, 32, 128, 512, 512, 256, 10, 6, None,
+                           2048, False), 1),
+    "mimo-global-tile": ((16, 32, 64, 192, 768, 512, 256, 33, 2, None,
+                          None, True), 1),
+    "mimo-ring-tile": ((16, 32, 64, 192, 1536, 1024, 128, 4, 5, None,
+                        128, True), 1),
+}
+
+
+def _calls_on_the_pool(hlo, name, *pool_shapes):
+    """How many Mosaic calls of the compiled step are named ``name``,
+    each of which takes the bfloat16 pools of these shapes as they lie
+    among its operands: what the benchmark's readers tell a paged
+    attention call by."""
+    calls = [ln.split("backend_config=")[0] for ln in hlo.splitlines()
+             if "tpu_custom_call" in ln and f"%{name}." in ln.split("=")[0]]
+    for ln in calls:
+        operands = ln.split("operand_layout_constraints=")[1]
+        for shape in pool_shapes:
+            assert "bf16[%d,%d,%d]" % tuple(shape) in operands, (name, shape)
+    return len(calls)
+
+
+@pytest.mark.parametrize("call", list(_SPLIT_CALLS))
+def test_split_kernel_walks_a_group_of_slots_on_the_v5e(call, one_chip):
+    """ISSUE 44: a decode call of each served model takes the derived
+    group of table slots a grid step (its pages copied by the kernel into
+    ``SPLIT_VMEM_BYTES`` of fast memory: Mosaic refuses a kernel that
+    asks for more), a prefill tile one slot; each is ONE Mosaic call that
+    takes the pools as they lie (no copy, no temporary of their size)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels.flash_attention import (ragged_decode_attention,
+                                                    split_walk)
+
+    (b, c, h, dk, kw, vw, ps, slots, nl, latent, window,
+     sink), group = _SPLIT_CALLS[call]
+    rows = 65 * nl
+    ints = np.zeros(b, np.int32)
+    pools = [jnp.zeros((rows, ps, kw), jnp.bfloat16)] + (
+        [] if vw is None else [jnp.zeros((rows, ps, vw), jnp.bfloat16)])
+    args = [jnp.zeros((b, c, h, dk), jnp.bfloat16),
+            np.zeros((b, slots), np.int32), ints, ints, ints,
+            jnp.zeros(h, jnp.float32), *pools]
+    assert split_walk(args[0], pools[0], None if vw is None else pools[1],
+                      args[1], latent) == (group, (b, -(-slots // group)))
+
+    def kernel(q, tbl, lengths, base, top, sk, pool, v_pool=None):
+        return ragged_decode_attention(
+            q, pool, tbl, lengths, base, layer=1, n_layer=nl, impl="pallas",
+            v_pool=v_pool, latent_values=latent, window=window,
+            ring_top=top if window else None, sink=sk if sink else None,
+            kernel_name="paged_attn_x")
+
+    compiled = jax.jit(kernel).lower(*_shapes(args, one_chip)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert _calls_on_the_pool(hlo, "paged_attn_x",
+                              *(p.shape for p in pools)) == 1
+    pool_bytes = rows * ps * kw * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
+
+
 def test_latent_serve_step_compiles_for_v5e(one_chip):
     """The donated serve step of ``moonlight-16b-a3b-l5`` (ISSUE 32) at
     its published widths, 128 lanes and two chunks of 256, compiled by
@@ -303,6 +384,18 @@ def test_latent_serve_step_compiles_for_v5e(one_chip):
     assert "input_output_alias" in hlo.splitlines()[0]
     assert ("attn_latent", {"form": "absorbed", "tile": 64, "row": 640,
                             "values": 512}) in step.noted
+    # ISSUE 44: the walk each of the ten calls makes, once a compile: 128
+    # decode lanes take 8 of their 32 slots a grid step, the two chunks'
+    # 8 tiles of 64 queries one (a page a step, as before)
+    walks = [a for what, a in step.noted if what == "attn_split"]
+    assert walks == 5 * [
+        {"kernel": "paged_attn_latent", "queries": 1, "slots": 32,
+         "slots_per_step": 8, "grid_steps": 128 * 4},
+        {"kernel": "paged_attn_latent", "queries": 64, "slots": 32,
+         "slots_per_step": 1, "grid_steps": 8 * 32}]
+    # each still ONE Mosaic call with the whole pool among its operands
+    assert _calls_on_the_pool(hlo, "paged_attn_latent",
+                              pool["k_shape"]) == 10
     n_elems = int(np.prod(pool["k_shape"]))    # 161 pages: no other match
     kinds = hlo_results_of_size(hlo[hlo.index("ENTRY "):], n_elems)
     assert kinds.pop("fusion") == 5, kinds              # the row scatters
@@ -366,6 +459,20 @@ def test_afmoe_serve_step_compiles_for_v5e(one_chip):
         described((2,), np.int32)).compile()
     hlo = compiled.as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 8 * 2 + 6 * 3
+    # ISSUE 44: decode rows walk 4 slots a grid step (17 groups of the 66
+    # global slots, 3 of the ring's 10), a chunk's 4 tiles a slot a step
+    walks = {(a["kernel"], a["queries"]): a
+             for what, a in step.noted if what == "attn_split"}
+    assert {k: (a["slots"], a["slots_per_step"], a["grid_steps"])
+            for k, a in walks.items()} == {
+        ("paged_attn_global", 1): (66, 4, 64 * 17),
+        ("paged_attn_window", 1): (10, 4, 64 * 3),
+        ("paged_attn_global", 64): (66, 1, 4 * 66),
+        ("paged_attn_window", 64): (10, 1, 4 * 10)}
+    # each still ONE Mosaic call with its group's pool pair as operands
+    for kind, calls in (("global", 2 * 2), ("window", 6 * 2)):
+        assert _calls_on_the_pool(hlo, "paged_attn_" + kind, served[kind],
+                                  served[kind]) == calls
     for name in ("paged_attn_global", "paged_attn_window", "attn/qk_norm",
                  "attn/gate", "ffn/shared", "ffn/dense", "moe/route",
                  "moe/experts"):
